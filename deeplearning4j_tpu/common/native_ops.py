@@ -31,16 +31,29 @@ _build_attempted = False
 _ABI_VERSION = 7  # must match dl4j_abi_version() in dl4j_tpu_native.cpp
 
 
+def build(force=False):
+    """Build the library from the tracked native/dl4j_tpu_native.cpp with
+    `make` (force=True rebuilds even when the .so looks fresh). Returns
+    (ok, detail): the library path, or the toolchain's complaint."""
+    cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
+    try:
+        p = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return False, repr(e)
+    if p.returncode != 0:
+        return False, (p.stderr or p.stdout).strip()[-500:]
+    return True, _SO_PATH
+
+
 def _try_build(force=False):
     global _build_attempted
     if _build_attempted:
         return
     _build_attempted = True
-    try:
-        cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception as e:  # toolchain missing / build failure -> fallback
-        log.debug("native build failed (%s); using python fallbacks", e)
+    ok, detail = build(force)
+    if not ok:  # toolchain missing / build failure -> python paths, loudly
+        log.warning("native build failed; every caller takes its python "
+                    "path: %s", detail)
 
 
 def _load_checked():

@@ -36,15 +36,41 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces (absent on CPU-only builds of pallas)
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
+
+
+def _resolve_interpret(interpret):
+    """`interpret=None` follows the backend: the Mosaic kernel on TPU, the
+    Pallas interpreter on CPU (tests and CPU meshes). Any other backend
+    raises — an interpreter run there would be a silent, wrong-speed
+    stand-in for a kernel that does not exist."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"flash attention has a TPU (Mosaic) kernel and a CPU interpreter "
+        f"mode; backend {backend!r} has neither")
+
+
+def _pallas_env(interpret):
+    """Shared pallas_call scaffolding: (VMEM block-spec kwargs, SMEM spec,
+    compiler-params extras). One definition so every grid pass compiles
+    with identical memory-space and dimension-semantics settings: the
+    two outer grid dims are independent, only the innermost carries
+    accumulator state in VMEM scratch."""
+    kw = {"memory_space": pltpu.VMEM}
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    extra = {}
+    if not interpret:
+        extra["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return kw, smem, extra
 
 
 def _online_softmax_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
@@ -148,25 +174,15 @@ def _flash_fwd_bthd(q, k, v, causal, scale, block_q, block_k, interpret,
     bq = _divisor_block(T, block_q)
     bk = _divisor_block(T, block_k)
     grid = (BH, T // bq, T // bk)
-    kw = {}
-    if _VMEM is not None:
-        kw["memory_space"] = _VMEM
+    kw, _, extra = _pallas_env(interpret)
     q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **kw)
     kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **kw)
     o_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **kw)
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     scratch = [
         pltpu.VMEM((bq, 128), jnp.float32),   # m (col 0 used)
         pltpu.VMEM((bq, 128), jnp.float32),   # l
         pltpu.VMEM((bq, d), jnp.float32),     # acc
     ]
-    extra = {}
-    if not interpret and pltpu is not None:
-        # outer grid dims are independent; only the kv dim carries the
-        # online-softmax accumulation state
-        extra["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     if with_lse:
         lse_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), **kw)
         kernel = functools.partial(_kernel_lse, scale=scale, causal=causal,
@@ -232,25 +248,17 @@ def flash_attention_partial(q, k, v, q_off, k_off, causal=True, scale=None,
     Tk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     bq = _divisor_block(Tq, block_q)
     bk = _divisor_block(Tk, block_k)
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     grid = (BH, Tq // bq, Tk // bk)
-    kw = {"memory_space": _VMEM} if _VMEM is not None else {}
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kw, smem, extra = _pallas_env(interpret)
     q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **kw)
     kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **kw)
     o_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **kw)
     ml_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), **kw)
     kernel = functools.partial(_partial_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk)
-    extra = {}
-    if not interpret:
-        extra["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     acc, m, l = pl.pallas_call(
         kernel,
         grid=grid,
@@ -407,8 +415,6 @@ def _flash_bwd_bthd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     BH, T, d = q.shape
     bq = _divisor_block(T, block_q)
     bk = _divisor_block(T, block_k)
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     -1, keepdims=True)                    # [BH, T, 1]
     zero = jnp.zeros((1,), jnp.int32)
@@ -417,19 +423,6 @@ def _flash_bwd_bthd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     dk, dv = _flash_bwd_dkv_pass(q, k, v, delta, do, lse, zero, zero,
                                  causal, scale, bq, bk, interpret)
     return dq, dk, dv
-
-
-def _pallas_env(interpret):
-    """Shared pallas_call scaffolding: (VMEM block-spec kwargs, SMEM spec,
-    compiler-params extras). One definition so every grid pass compiles
-    with identical memory-space and dimension-semantics settings."""
-    kw = {"memory_space": _VMEM} if _VMEM is not None else {}
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    extra = {}
-    if not interpret:
-        extra["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return kw, smem, extra
 
 
 def _flash_bwd_dq_pass(q, k, v, delta, do, lse, q_off, k_off, causal,
@@ -498,10 +491,7 @@ def flash_attention_bwd_partial(q, k, v, delta, do, lse, q_off, k_off,
     Tk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
+    interpret = _resolve_interpret(interpret)
     bq = _divisor_block(Tq, block_q)
     bk = _divisor_block(Tk, block_k)
     qo = jnp.asarray(q_off, jnp.int32).reshape(1)
@@ -571,8 +561,8 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=1024,
     """Flash attention over [B, T, H, D] (ring_attention layout).
 
     scale defaults to 1/sqrt(D). `interpret=None` auto-selects: real
-    Mosaic kernel on TPU, Pallas interpreter elsewhere (so the same tests
-    run on the CPU mesh)."""
+    Mosaic kernel on TPU, Pallas interpreter on CPU (so the same tests
+    run on the CPU mesh); see `_resolve_interpret`."""
     return _flash_apply(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
@@ -580,11 +570,10 @@ def _flash_apply(q, k, v, causal, scale, block_q, block_k, interpret):
     B, T, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     to_bhtd = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     out = _flash_fwd_bthd(to_bhtd(q), to_bhtd(k), to_bhtd(v), causal,
-                          scale, block_q, block_k, interpret)
+                          scale, block_q, block_k,
+                          _resolve_interpret(interpret))
     return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
@@ -594,11 +583,10 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     backward kernels need, keeping the O(T)-residual-memory contract."""
     B, T, H, D = q.shape
     sc = 1.0 / math.sqrt(D) if scale is None else scale
-    interp = (jax.default_backend() != "tpu" if interpret is None
-              else interpret)
     to_bhtd = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     out, lse = _flash_fwd_bthd(to_bhtd(q), to_bhtd(k), to_bhtd(v), causal,
-                               sc, block_q, block_k, interp, with_lse=True)
+                               sc, block_q, block_k,
+                               _resolve_interpret(interpret), with_lse=True)
     out_bthd = out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     return out_bthd, (q, k, v, out_bthd, lse)
 
@@ -610,8 +598,6 @@ def _bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
     B, T, H, D = q.shape
     sc = 1.0 / math.sqrt(D) if scale is None else scale
-    interp = (jax.default_backend() != "tpu" if interpret is None
-              else interpret)
     to_bhtd = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     # backward blocks: half the forward's (three f32 [bq,bk] panels live),
     # floored at 256 but never above the caller's forward block — a caller
@@ -621,7 +607,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, res, g):
     bwd_bk = min(block_k, max(block_k // 2, 256))
     dq, dk, dv = _flash_bwd_bthd(
         to_bhtd(q), to_bhtd(k), to_bhtd(v), to_bhtd(o), lse, to_bhtd(g),
-        causal, sc, bwd_bq, bwd_bk, interp)
+        causal, sc, bwd_bq, bwd_bk, _resolve_interpret(interpret))
     back = lambda a: a.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     return back(dq), back(dk), back(dv)
 

@@ -59,6 +59,8 @@ def build_parser():
 def run(argv=None):
     args = build_parser().parse_args(argv)
 
+    from ..common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..util.model_guesser import load_model_guess
     from ..util.model_serializer import write_model
     from .parallel_wrapper import ParallelWrapper

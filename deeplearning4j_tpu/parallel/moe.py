@@ -28,7 +28,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..common.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -203,10 +202,10 @@ def moe_mlp_sharded(mesh, axis="expert", capacity=None, k=1,
              "b2": P(axis)}
     batch_spec = (P(axis) if data_axis is None
                   else P((data_axis, axis)))
-    fn = shard_map(spmd, mesh=mesh,
-                   in_specs=(pspec, batch_spec),
-                   out_specs=(batch_spec, P()),
-                   check_vma=False)
+    fn = jax.shard_map(spmd, mesh=mesh,
+                       in_specs=(pspec, batch_spec),
+                       out_specs=(batch_spec, P()),
+                       check_vma=False)
 
     def apply(params, x):
         return fn(params, x)

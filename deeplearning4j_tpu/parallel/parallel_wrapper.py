@@ -480,15 +480,19 @@ class ParallelWrapper:
         self._kstep_emits_health = emit_h
         raw = (net.make_raw_step(emit_health=True) if emit_h
                else net.make_raw_step())
-        from ..common.jax_compat import shard_map
 
         def local_steps(params, ustate, state, batches):
             def body(carry, batch_t):
                 p, u, s = carry
                 p, u, s, score, _, *h = raw(p, u, s, batch_t)
                 return (p, u, s), ((score, h[0]) if emit_h else score)
-            (p, u, s), ys = jax.lax.scan(body, (params, ustate, state),
-                                         batches)
+            # params arrive replicated (unvarying over "data") but every
+            # local step folds in this device's shard, so the carry is
+            # device-varying from step one: say so on the way in, or the
+            # scan's carry-in / carry-out types disagree under check_vma
+            init = jax.lax.pcast((params, ustate, state), "data",
+                                 to="varying")
+            (p, u, s), ys = jax.lax.scan(body, init, batches)
             scores = ys[0] if emit_h else ys
             # the TPU-native averageAndPropagate: pmean over ICI
             p = jax.lax.pmean(p, "data")
@@ -540,9 +544,9 @@ class ParallelWrapper:
             out_specs = (pspec, uspec, sspec, repl)
             if emit_h:
                 out_specs = out_specs + (repl,)   # prefix for the health dict
-            fn = shard_map(local_steps, mesh=mesh,
-                           in_specs=(pspec, uspec, sspec, bspec),
-                           out_specs=out_specs)
+            fn = jax.shard_map(local_steps, mesh=mesh,
+                               in_specs=(pspec, uspec, sspec, bspec),
+                               out_specs=out_specs)
             return jax.jit(fn, donate_argnums=(0, 1, 2))
         return build
 

@@ -30,7 +30,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..common.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -131,9 +130,8 @@ def gpipe(stage_fn, mesh, axis="pipe", data_axis=None, param_specs=None):
     def pipelined(stacked_params, xs):
         pspec = (param_specs if param_specs is not None
                  else jax.tree.map(lambda _: pspec_leaf, stacked_params))
-        fn = shard_map(spmd, mesh=mesh, in_specs=(pspec, xspec),
-                       out_specs=ospec,
-                       check_vma=False)
+        fn = jax.shard_map(spmd, mesh=mesh, in_specs=(pspec, xspec),
+                           out_specs=ospec, check_vma=False)
         return fn(stacked_params, xs)
 
     return pipelined

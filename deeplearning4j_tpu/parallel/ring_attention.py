@@ -28,13 +28,8 @@ NEG_INF = -1e30
 
 def _mark_varying(tree, axis_name):
     """Mark replicated constants as axis-varying under shard_map (loop
-    carries become varying). pcast replaced pvary (deprecated) — support
-    both jax generations; no-op on versions with neither."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(tree, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(tree, (axis_name,))
-    return tree  # pragma: no cover
+    carries become varying)."""
+    return lax.pcast(tree, axis_name, to="varying")
 
 
 def _attend_block(q, k, v, bias):
@@ -224,7 +219,6 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=False,
     use_flash: per-hop compute via the Pallas flash kernel (kv_mask not
     supported on that path)."""
     from jax.sharding import PartitionSpec as P
-    from ..common.jax_compat import shard_map
 
     if use_flash and kv_mask is not None:
         raise ValueError("use_flash does not support kv_mask; pad-free "
@@ -241,7 +235,7 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=False,
             # check for the kernel path (the einsum path keeps it)
             extra["check_vma"] = False
         lse_spec = P(None, axis, None)
-        return shard_map(
+        return jax.shard_map(
             functools.partial(ring_attention_kernel, axis_name=axis,
                               causal=causal, use_flash=flash,
                               return_lse=return_lse),
@@ -271,7 +265,7 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=False,
     def rsa_bwd(res, g):
         q, k, v, out, lse = res
         lse_spec = P(None, axis, None)
-        bwd = shard_map(
+        bwd = jax.shard_map(
             functools.partial(ring_attention_bwd_kernel, axis_name=axis,
                               causal=causal),
             mesh=mesh,

@@ -422,7 +422,7 @@ class ServingMetrics:
             out[key + "_mean"] = (s / total) if total else None
             out[key + "_count"] = total
         # dispatches_per_token = TARGET-model dispatches (decode/verify)
-        # per emitted token — the tunnel-amortization headline for a
+        # per emitted token — the dispatch-amortization headline for a
         # host-side draft; device_dispatches_per_token folds in the draft
         # model's own dispatches (ModelDraft pays ~K-1 per round;
         # NGramDraft pays zero) so a small-model draft cannot

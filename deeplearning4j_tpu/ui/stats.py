@@ -7,9 +7,9 @@ statistics (mean/stdev/mean-magnitude) + histograms of params/gradients/
 updates. The SBE wire encoding is replaced by plain dict reports (JSON-able);
 routing/storage in ui/storage.py.
 
-TPU note: param statistics require device->host transfers, which are
-expensive on remote-attached chips — the collection frequency and the
-histogram toggle exist for exactly that reason (the reference has the same
+TPU note: param statistics require device->host transfers, which stall
+the training stream — the collection frequency and the histogram toggle
+exist for exactly that reason (the reference has the same
 knobs in StatsUpdateConfiguration).
 """
 from __future__ import annotations

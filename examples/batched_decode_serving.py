@@ -1,8 +1,7 @@
 """Batched KV-cache text generation: the WHOLE generation (prompt prefill
 scan + greedy decode scan with on-device argmax) is one jitted program, so
-the host touches the device once per call — the TPU serving pattern (on a
-remote-attached chip the per-token host round trip of naive decoding IS
-the bottleneck).
+the host touches the device once per call — the TPU serving pattern (naive
+decoding pays a host round trip per token).
 
 reference parity: MultiLayerNetwork.rnnTimeStep (O(1)-state streaming
 inference), attention era.

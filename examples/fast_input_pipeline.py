@@ -6,8 +6,8 @@ batch crosses host->HBM as float32. On TPU the affine scale fuses into the
 first convolution for free, so the wire can carry the raw uint8 pixels
 (4x fewer bytes) and bf16 labels (2x fewer) while AsyncDataSetIterator's
 prefetch thread applies the normalizer ON DEVICE, overlapped with the
-training step. Measured on a remote-attached v5e: 22.5 -> 177 img/s on
-ResNet-50 fit() (see PERF.md round 5).
+training step. What the fed path delivers on the chip is not measured yet
+(ROADMAP S4).
 
 reference: datasets/iterator/AsyncDataSetIterator.java:75-76 (device-pinned
 prefetch), ImagePreProcessingScaler.java (host-side transform replaced by

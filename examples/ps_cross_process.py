@@ -6,7 +6,10 @@ Aeron-backed ParameterServerParallelWrapper topology
 
 This example spawns ONE real worker subprocess against an in-process
 server (the 2-process convergence test in tests/test_ps_transport.py runs
-the full two-worker topology).
+the full two-worker topology). The worker is the test suite's
+`tests/ps_remote_worker.py`, which pins itself to the CPU backend: a chip
+belongs to one process at a time, so a worker that wanted this process's
+chip could not have it.
 """
 import _common  # noqa: F401
 

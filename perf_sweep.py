@@ -1,4 +1,5 @@
-"""On-chip perf sweep for the round-4/5 levers (run when the TPU is up).
+"""On-chip perf sweep for the round-4/5 levers (one process: it takes the
+chip; run it through the chip tool).
 
 Interleaved A/B measurements that bench.py's fixed budget doesn't cover:
 
@@ -26,6 +27,8 @@ import time
 
 def main(budget_s=900.0, skip_flash=False):
     t0 = time.perf_counter()
+    from deeplearning4j_tpu.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from deeplearning4j_tpu.common.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.models.zoo.transformer import (
@@ -60,8 +59,8 @@ class TestTensorParallelBlock:
             "mlp": {"w1": P(None, "model"), "b1": P("model"),
                     "w2": P("model", None), "b2": P()},
         }
-        fn = shard_map(block, mesh=mesh, in_specs=(specs, P()),
-                       out_specs=P(), check_vma=False)
+        fn = jax.shard_map(block, mesh=mesh, in_specs=(specs, P()),
+                           out_specs=P(), check_vma=False)
         got = jax.jit(fn)(tp, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5)

@@ -324,9 +324,19 @@ def test_wrapper_rollback_completes_and_is_bit_comparable(tmp_path):
 
     # bit-comparability bar (the PR 1 crash-resume standard): the rollback
     # restored rng AND counters, so the run equals one whose stream simply
-    # never contained the poisoned batch
+    # never contained the poisoned batch. The reference arms the same
+    # watchdog (which never fires on the clean stream): the health-emitting
+    # step is a different XLA program from the plain step, and XLA:CPU under
+    # jax 0.9.0 rounds 4 of the 75 parameters 1 ulp (<= 7.5e-9) apart
+    # between the two with no fault and no restore involved (measured, PR
+    # 21) — holding the program fixed isolates what this test is about, the
+    # orbax restore, which IS bit-exact.
     ref = _reg_net(seed=5)
-    _wrapper(ref).fit(ListDataSetIterator(batches[:5] + batches[6:]))
+    ref_pol = TrainingHealthPolicy(grad_norm_limit=50.0,
+                                   max_consecutive_bad=4)
+    _wrapper(ref, pol=ref_pol).fit(
+        ListDataSetIterator(batches[:5] + batches[6:]))
+    assert ref_pol.counts["spikes"] == 0
     assert ref.conf.iteration_count == net.conf.iteration_count
     np.testing.assert_array_equal(np.asarray(net.params()),
                                   np.asarray(ref.params()))

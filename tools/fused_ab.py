@@ -143,6 +143,8 @@ def main():
     segments = 5
     if "--segments" in sys.argv:
         segments = int(sys.argv[sys.argv.index("--segments") + 1])
+    from deeplearning4j_tpu.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     print(json.dumps({"platform": jax.devices()[0].platform,
                       "fused_steps": K, "segments": segments,

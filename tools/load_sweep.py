@@ -55,6 +55,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from deeplearning4j_tpu.common.compile_cache import (  # noqa: E402
+    enable_compile_cache)
 from deeplearning4j_tpu.obs.registry import fmt  # noqa: E402
 
 KNEE_THRESH = 0.9
@@ -538,6 +540,29 @@ def sweep_fleet_control(rates, n_replicas=2, n_req=64, slo_ms=250.0,
     return body, snaps, merged
 
 
+def _spawn_replica(cmd):
+    """Start one `--replica-serve` child in the parent's own environment.
+
+    A chip belongs to one process at a time, and this parent has already
+    initialised JAX (it builds the reference model and schedules): on an
+    accelerator the children could not take the chip the parent holds, and
+    forcing them onto the CPU would serve the "fleet" from CPUs behind the
+    caller's back. The cross-process fleet is a control-plane harness, so
+    it runs where the parent's backend is the CPU and refuses elsewhere
+    (the on-chip fleet is in-process replicas, one device each)."""
+    import subprocess
+
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"load_sweep: replica child processes need the parent on the "
+            f"CPU backend, but this process holds {backend!r} (one process "
+            f"per chip). Run the cross-process fleet arms with "
+            f"JAX_PLATFORMS=cpu; on a chip use the in-process --fleet N.")
+    return subprocess.Popen(cmd)
+
+
 def _replica_serve_main(argv):
     """Child-process entry for `--fleet-procs` (hidden flag
     `--replica-serve`): build the SAME deterministic model the parent
@@ -561,6 +586,7 @@ def _replica_serve_main(argv):
                          "affinity arm's shared-prefix prompts need "
                          "16,32)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     from deeplearning4j_tpu.obs import Tracer
     from deeplearning4j_tpu.serving import (ContinuousDecodeServer,
                                             ServingMetrics,
@@ -604,7 +630,6 @@ def sweep_fleet_procs(rates, n_replicas=2, n_req=64, slo_ms=250.0,
     PROCESS (distinct pids in Perfetto).
 
     Returns (body, per_instance_snaps, merged_trace_or_None)."""
-    import subprocess
     import tempfile
 
     from deeplearning4j_tpu.common.resilience import (FaultInjector,
@@ -633,8 +658,7 @@ def sweep_fleet_procs(rates, n_replicas=2, n_req=64, slo_ms=250.0,
             cmd.append("--paged")
         if trace_out:
             cmd += ["--trace-out", trace_out]
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        procs[name] = subprocess.Popen(cmd, env=env)
+        procs[name] = _spawn_replica(cmd)
         trace_files[name] = trace_out
         return port_file
 
@@ -822,7 +846,6 @@ def sweep_fleet_affinity(rates, n_replicas=3, n_req=48, slo_ms=250.0,
     parity); the counters are the record. Returns
     (body, per_instance_snaps, None)."""
     import random
-    import subprocess
     import tempfile
 
     from deeplearning4j_tpu.common.resilience import RetryPolicy
@@ -882,8 +905,7 @@ def sweep_fleet_affinity(rates, n_replicas=3, n_req=48, slo_ms=250.0,
                        "--slo-ms", str(slo_ms), "--slots", str(slots),
                        "--paged", "--prompt-buckets",
                        ",".join(str(b) for b in buckets)]
-                env = dict(os.environ, JAX_PLATFORMS="cpu")
-                procs_map[name] = subprocess.Popen(cmd, env=env)
+                procs_map[name] = _spawn_replica(cmd)
                 return port_file
 
             def wait_port(name, port_file, timeout=300.0):
@@ -1175,7 +1197,6 @@ def sweep_fleet_chaos(rates, n_replicas=2, n_req=48, slo_ms=250.0,
 
     Returns (body, per_instance_snaps, merged_trace_or_None)."""
     import concurrent.futures as cf
-    import subprocess
     import tempfile
 
     from deeplearning4j_tpu.common.resilience import (FaultInjector,
@@ -1213,8 +1234,7 @@ def sweep_fleet_chaos(rates, n_replicas=2, n_req=48, slo_ms=250.0,
                "--slo-ms", str(slo_ms), "--slots", str(slots)]
         if trace_out:
             cmd += ["--trace-out", trace_out]
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        procs[name] = subprocess.Popen(cmd, env=env)
+        procs[name] = _spawn_replica(cmd)
         trace_files[name] = trace_out
         return port_file
 
@@ -2039,6 +2059,7 @@ def main():
                          "of traffic (requests scale with rate) so "
                          "goodput is comparable across rungs")
     args = ap.parse_args()
+    enable_compile_cache()
     rates = tuple(float(r) for r in args.rates.split(","))
     t0 = time.perf_counter()
     results = run_sweep(server=args.server, rates=rates,
