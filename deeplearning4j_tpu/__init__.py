@@ -15,6 +15,9 @@ reference's parameter-averaging threads / Spark / Aeron parameter server.
 
 __version__ = "0.1.0"
 
+import jax.profiler
+
+from . import obs
 from .nn.conf.computation_graph_configuration import \
     ComputationGraphConfiguration
 from .nn.conf.input_type import InputType
@@ -22,6 +25,12 @@ from .nn.conf.neural_net_configuration import (MultiLayerConfiguration,
                                                NeuralNetConfiguration)
 from .nn.graph import ComputationGraph
 from .nn.multilayer import MultiLayerNetwork
+
+# Every span of the process-wide tracer is a profiler annotation too (inert
+# while no profiler session runs), so the containers' and the servers' host
+# spans land on the device trace's clock. Installed here, the one module
+# they all import, because obs/ itself imports no jax.
+obs.TRACER.annotate_with(jax.profiler.TraceAnnotation)
 
 __all__ = [
     "ComputationGraph",

@@ -21,6 +21,7 @@ from __future__ import annotations
 import copy
 from dataclasses import dataclass, field, fields
 
+import jax
 import jax.numpy as jnp
 
 from ... import activations as _acts  # noqa: F401  (registry warm)
@@ -37,6 +38,21 @@ GLOBAL_OVERRIDABLE = (
     "lr_policy", "lr_policy_decay_rate", "lr_policy_steps", "lr_policy_power",
     "lr_policy_max_iterations", "lr_schedule",
 )
+
+
+def layer_scope(conf, name):
+    """`jax.named_scope("<kind>.<name>")` for one layer or vertex of a
+    container: `kind` is the registered type tag of its class
+    (`convolution`, `batchnorm`, `elementwise`, ...), `name` the vertex's
+    name or the layer's index. The scope is in the `op_name` metadata of
+    every operation the compiled step traces inside it — forward as
+    `jvp(<kind>.<name>)`, backward as `transpose(jvp(<kind>.<name>))` —
+    which is how device time is attributed to layers
+    (optimize/profiler.py `op_scopes`). Metadata only: same operations."""
+    kind = (getattr(conf, "layer_type", None)
+            or getattr(conf, "vertex_type", None)
+            or type(conf).__name__.lower())
+    return jax.named_scope(f"{kind}.{name}")
 
 
 def register_layer(name):

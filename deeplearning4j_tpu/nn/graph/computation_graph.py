@@ -24,7 +24,7 @@ from ... import obs
 from ...datasets.dataset import DataSet, MultiDataSet
 from ...datasets.iterators import next_processed
 from ..conf.computation_graph_configuration import ComputationGraphConfiguration
-from ..conf.layers.base import LayerConf
+from ..conf.layers.base import LayerConf, layer_scope
 from ..conf.layers.recurrent import BaseRecurrentLayer
 from ..updater import updaters as U
 
@@ -166,27 +166,29 @@ class ComputationGraph:
         """One vertex's forward — the SINGLE dispatch (preprocessor, param
         cast, carry/state/stateless branches) shared by `_apply_graph` and
         the remat segment body, so the two forward paths cannot drift.
-        Returns (out, new_state | None, new_carry | None)."""
-        if spec.is_layer:
-            layer = spec.conf
-            x = in_acts[0]
-            if spec.preprocessor is not None:
-                x = spec.preprocessor.pre_process(x)
-            p = self._cast_params(p)
-            m = in_masks[0]
-            if (isinstance(layer, BaseRecurrentLayer)
-                    and carry_entry is not None):
-                out, c = layer.forward_with_carry(
-                    p, x, carry_entry, train=train, rng=lrng, mask=m)
-                return out, None, c
-            if layer.has_state():
-                out, st = layer.forward_with_state(
-                    p, x, state_entry, train=train, rng=lrng, mask=m)
-                return out, st, None
-            return (layer.forward(p, x, train=train, rng=lrng, mask=m),
-                    None, None)
-        return (spec.conf.forward(in_acts, masks=in_masks, train=train,
-                                  rng=lrng), None, None)
+        Returns (out, new_state | None, new_carry | None). Everything it
+        traces is under the scope `<kind>.<vertex name>`."""
+        with layer_scope(spec.conf, spec.name):
+            if spec.is_layer:
+                layer = spec.conf
+                x = in_acts[0]
+                if spec.preprocessor is not None:
+                    x = spec.preprocessor.pre_process(x)
+                p = self._cast_params(p)
+                m = in_masks[0]
+                if (isinstance(layer, BaseRecurrentLayer)
+                        and carry_entry is not None):
+                    out, c = layer.forward_with_carry(
+                        p, x, carry_entry, train=train, rng=lrng, mask=m)
+                    return out, None, c
+                if layer.has_state():
+                    out, st = layer.forward_with_state(
+                        p, x, state_entry, train=train, rng=lrng, mask=m)
+                    return out, st, None
+                return (layer.forward(p, x, train=train, rng=lrng, mask=m),
+                        None, None)
+            return (spec.conf.forward(in_acts, masks=in_masks, train=train,
+                                      rng=lrng), None, None)
 
     def _remat_plan(self):
         """Segment the topological order at element-wise (residual-add)
@@ -316,20 +318,21 @@ class ComputationGraph:
                 continue  # non-loss output (pure inference head)
             # recompute the head on its pre-head input to attach the loss
             x = acts[spec.inputs[0]]
-            if spec.preprocessor is not None:
-                x = spec.preprocessor.pre_process(x)
-            p = self._cast_params(params[out_name])
             lrng = (jax.random.fold_in(rng, order[out_name])
                     if rng is not None else None)
             lmask = None
             if lmasks:
                 lmask = (lmasks[oi] if isinstance(lmasks, (list, tuple))
                          else lmasks.get(out_name))
-            per_ex = layer.compute_score_per_example(
-                p, x, labels[oi], train=train, rng=lrng, mask=lmask)
-            if per_ex.dtype == jnp.bfloat16:
-                per_ex = per_ex.astype(jnp.float32)
-            total = total + jnp.mean(per_ex)
+            with jax.named_scope(f"loss.{out_name}"):
+                if spec.preprocessor is not None:
+                    x = spec.preprocessor.pre_process(x)
+                p = self._cast_params(params[out_name])
+                per_ex = layer.compute_score_per_example(
+                    p, x, labels[oi], train=train, rng=lrng, mask=lmask)
+                if per_ex.dtype == jnp.bfloat16:
+                    per_ex = per_ex.astype(jnp.float32)
+                total = total + jnp.mean(per_ex)
         reg = 0.0
         for n in self._layer_names():
             reg = reg + self.conf.vertices[n].conf.reg_score(params[n])
@@ -356,6 +359,7 @@ class ComputationGraph:
         updater half of the step (reference ComputationGraphUpdater)."""
         names = self._layer_names()
 
+        @jax.named_scope("update")
         def apply_updates(params, ustate, grads, iteration):
             minimize = self.conf.global_conf.get("minimize", True)
             new_params = dict(params)
@@ -407,14 +411,15 @@ class ComputationGraph:
                                                    batch["iteration"])
             if emit_health:
                 from ...common import health as H
-                health = H.grad_health(grads, score)
-                ok = health["all_finite"]
-                new_params = H.gate_update(ok, new_params, params)
-                new_ustate = H.gate_update(ok, new_ustate, ustate)
-                new_state = H.gate_update(ok, new_state, state)
-                if batch.get("carries") is not None:
-                    new_carries = H.gate_update(ok, new_carries,
-                                                batch["carries"])
+                with jax.named_scope("health"):
+                    health = H.grad_health(grads, score)
+                    ok = health["all_finite"]
+                    new_params = H.gate_update(ok, new_params, params)
+                    new_ustate = H.gate_update(ok, new_ustate, ustate)
+                    new_state = H.gate_update(ok, new_state, state)
+                    if batch.get("carries") is not None:
+                        new_carries = H.gate_update(ok, new_carries,
+                                                    batch["carries"])
                 return (new_params, new_ustate, new_state, score,
                         new_carries, health)
             return new_params, new_ustate, new_state, score, new_carries
@@ -602,6 +607,24 @@ class ComputationGraph:
             for mds in group[rb + 1:]:  # counters/rng restored; replay
                 self._fit_mds(mds)
         return self
+
+    def lower_step(self, ds):
+        """Lower (trace without running) the jitted step `fit` calls for one
+        DataSet / MultiDataSet of this shape, as `ParallelWrapper.lower_step`
+        does for the sharded step: `.compile().as_text()` is the compiled
+        HLO whose `op_name` metadata carries the layer scopes
+        (optimize/profiler.py `op_scopes`). Consumes nothing: the loop
+        state and the rng stream are left as they are."""
+        self._ensure_init()
+        if self._jit_step is None:
+            self._jit_step = self._make_step()
+        if isinstance(ds, DataSet):
+            ds = _dataset_to_mds(ds)
+        loop = self._loop or {"iteration": jnp.zeros((), jnp.float32),
+                              "rng": self._rng}
+        return self._jit_step.lower(
+            self._params, self._updater_state, self._model_state, loop,
+            *self._canon_mds(ds))
 
     def _fit_mds(self, mds: MultiDataSet):
         if self._jit_step is None:

@@ -37,6 +37,7 @@ from .. import obs
 from ..datasets.dataset import DataSet
 from ..datasets.iterators import (AsyncDataSetIterator, DataSetIterator,
                                   ListDataSetIterator, next_processed)
+from .conf.layers.base import layer_scope
 from .conf.neural_net_configuration import MultiLayerConfiguration
 from .updater import updaters as U
 
@@ -112,22 +113,27 @@ class MultiLayerNetwork:
             x = x.astype(cdt)
         for i in range(n):
             layer = self.layers[i]
-            if i in self.conf.preprocessors:
-                x = self.conf.preprocessors[i].pre_process(x)
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            p = jax.tree.map(lambda a: a.astype(cdt)
-                             if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                             params[i])
-            if isinstance(layer, BaseRecurrentLayer) and carries is not None:
-                x, c = layer.forward_with_carry(p, x, carries[i], train=train,
-                                                rng=lrng, mask=fmask)
-                new_carries[i] = c
-            elif layer.has_state():
-                x, st = layer.forward_with_state(p, x, state[i], train=train,
-                                                 rng=lrng, mask=fmask)
-                new_state[i] = st
-            else:
-                x = layer.forward(p, x, train=train, rng=lrng, mask=fmask)
+            # everything the layer traces is under `<kind>.<name or index>`
+            with layer_scope(layer, i if layer.name is None else layer.name):
+                if i in self.conf.preprocessors:
+                    x = self.conf.preprocessors[i].pre_process(x)
+                p = jax.tree.map(
+                    lambda a: a.astype(cdt)
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                    params[i])
+                if (isinstance(layer, BaseRecurrentLayer)
+                        and carries is not None):
+                    x, c = layer.forward_with_carry(
+                        p, x, carries[i], train=train, rng=lrng, mask=fmask)
+                    new_carries[i] = c
+                elif layer.has_state():
+                    x, st = layer.forward_with_state(
+                        p, x, state[i], train=train, rng=lrng, mask=fmask)
+                    new_state[i] = st
+                else:
+                    x = layer.forward(p, x, train=train, rng=lrng,
+                                      mask=fmask)
             acts.append(x)
         return acts, new_state, new_carries
 
@@ -174,14 +180,16 @@ class MultiLayerNetwork:
         out_layer = self.layers[-1]
         i = len(self.layers) - 1
         lrng = jax.random.fold_in(rng, i) if rng is not None else None
-        p_out = jax.tree.map(lambda a: a.astype(self.compute_dtype)
-                             if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                             params[i])
-        per_ex = out_layer.compute_score_per_example(
-            p_out, h, labels, train=train, rng=lrng, mask=lmask)
-        if per_ex.dtype == jnp.bfloat16:
-            per_ex = per_ex.astype(jnp.float32)
-        score = jnp.mean(per_ex)
+        with jax.named_scope(
+                f"loss.{i if out_layer.name is None else out_layer.name}"):
+            p_out = jax.tree.map(
+                lambda a: a.astype(self.compute_dtype)
+                if jnp.issubdtype(a.dtype, jnp.floating) else a, params[i])
+            per_ex = out_layer.compute_score_per_example(
+                p_out, h, labels, train=train, rng=lrng, mask=lmask)
+            if per_ex.dtype == jnp.bfloat16:
+                per_ex = per_ex.astype(jnp.float32)
+            score = jnp.mean(per_ex)
         reg = 0.0
         for layer, p in zip(self.layers, params):
             reg = reg + layer.reg_score(p)
@@ -218,6 +226,7 @@ class MultiLayerNetwork:
         per-variable updater state machine (reference LayerUpdater.java:72)."""
         layers = self.layers
 
+        @jax.named_scope("update")
         def apply_updates(params, ustate, grads, iteration):
             new_params = []
             new_ustate = []
@@ -277,14 +286,15 @@ class MultiLayerNetwork:
                                                    batch["iteration"])
             if emit_health:
                 from ..common import health as H
-                health = H.grad_health(grads, score)
-                ok = health["all_finite"]
-                new_params = H.gate_update(ok, new_params, params)
-                new_ustate = H.gate_update(ok, new_ustate, ustate)
-                new_state = H.gate_update(ok, new_state, state)
-                if batch.get("carries") is not None:
-                    new_carries = H.gate_update(ok, new_carries,
-                                                batch["carries"])
+                with jax.named_scope("health"):
+                    health = H.grad_health(grads, score)
+                    ok = health["all_finite"]
+                    new_params = H.gate_update(ok, new_params, params)
+                    new_ustate = H.gate_update(ok, new_ustate, ustate)
+                    new_state = H.gate_update(ok, new_state, state)
+                    if batch.get("carries") is not None:
+                        new_carries = H.gate_update(ok, new_carries,
+                                                    batch["carries"])
                 return ((new_params, new_ustate, new_state, score,
                          new_carries) + tuple(acts) + (health,))
             return ((new_params, new_ustate, new_state, score, new_carries)

@@ -10,6 +10,9 @@ package is that substrate:
     Threaded through both servers (enqueue -> queue wait -> batch
     formation -> dispatch -> complete, one span per decode iteration)
     and the training fit loops (staging, dispatch, health, checkpoint).
+    `Tracer.annotate_with(factory)` adds one injected sink: the package
+    root hands `TRACER` `jax.profiler.TraceAnnotation`, so every span is
+    an event on the device trace's clock under a profiler session.
   * `registry.MetricsRegistry` — the named counter/gauge/reservoir/
     histogram surface everything publishes through (serving metrics,
     PS-transport retries, async-iterator queue depth, training-health

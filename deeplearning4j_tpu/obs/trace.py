@@ -16,6 +16,12 @@ Contracts (pinned by tests/test_obs.py):
     attribute check returning a shared no-op — nanosecond-scale, no
     allocation, no clock read. Serving and training ship with tracing
     OFF and pay nothing.
+  * One more sink, injected: `annotate_with(factory)` gives the tracer a
+    callable `(name, **args) -> context manager` (the package root hands
+    the process-wide tracer `jax.profiler.TraceAnnotation`), and every
+    `span()` then enters it too, ring on or off, so a span is an event
+    on the device trace's own clock whenever a profiler session runs.
+    `emit()` and `instant()` have no interval to enter: ring only.
   * Zero device work. This module (the whole obs/ package) never imports
     jax or numpy: recording a span can never add a device dispatch. The
     only device interaction is the OPTIONAL flight-recorder seam, which
@@ -91,23 +97,29 @@ _NOOP = _Noop()
 
 class _SpanCtx:
     __slots__ = ("_tracer", "_name", "_cat", "_track", "_trace_id",
-                 "_args", "_t0")
+                 "_args", "_ann", "_t0")
 
-    def __init__(self, tracer, name, cat, track, trace_id, args):
+    def __init__(self, tracer, name, cat, track, trace_id, args, ann=None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._track = track
         self._trace_id = trace_id
         self._args = args
+        self._ann = ann     # the injected sink's context, same interval
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
-        self._tracer.emit(self._name, t0, monotonic_ns() - t0,
+        dur = monotonic_ns() - t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer.emit(self._name, t0, dur,
                           cat=self._cat, track=self._track,
                           trace_id=self._trace_id, args=self._args)
         return False
@@ -116,9 +128,11 @@ class _SpanCtx:
 class Tracer:
     """Bounded span recorder; disabled by default."""
 
-    def __init__(self, capacity=16384, enabled=False, instance=None):
+    def __init__(self, capacity=16384, enabled=False, instance=None,
+                 annotate=None):
         self._buf = collections.deque(maxlen=int(capacity))
         self._enabled = bool(enabled)
+        self._annotate = annotate       # see annotate_with
         # instance name: the default process_name of this tracer's
         # chrome_trace() export. A fleet names its replicas' tracers so
         # obs.fleet.merge_traces renders each as its own process group.
@@ -144,6 +158,15 @@ class Tracer:
         self._enabled = False
         return self
 
+    def annotate_with(self, factory):
+        """Install (or, with None, remove) the annotation factory: a
+        callable `(name, **args) -> context manager` that `span()` enters
+        over the span's interval whether or not the ring is enabled. The
+        jax side passes `jax.profiler.TraceAnnotation`, inert while no
+        profiler session runs; this module never imports it."""
+        self._annotate = factory
+        return self
+
     def enable_for(self, n_spans, on_done=None, restore=None):
         """Flight-recorder arm: record the next `n_spans` spans, then
         restore the previous enabled state (or the explicit `restore`
@@ -157,17 +180,22 @@ class Tracer:
 
     # -- hot path ------------------------------------------------------
     def span(self, name, cat="host", track=None, trace_id=None, **args):
-        """Context manager timing one span. Disabled: returns a shared
-        no-op without reading the clock or allocating."""
+        """Context manager timing one span. Disabled and no annotation
+        factory: returns a shared no-op without reading the clock or
+        allocating. With a factory the span is entered as an annotation
+        too: ring off, the annotation itself is returned."""
+        annotate = self._annotate
         if not self._enabled:
-            return _NOOP
-        return _SpanCtx(self, name, cat, track, trace_id, args or None)
+            return _NOOP if annotate is None else annotate(name, **args)
+        return _SpanCtx(self, name, cat, track, trace_id, args or None,
+                        None if annotate is None else annotate(name, **args))
 
     def emit(self, name, t0_ns, dur_ns, cat="host", track=None,
              trace_id=None, args=None):
         """Record one completed span with explicit timing — for spans
         whose start was a plain timestamp taken before the outcome was
-        known (queue wait: t_submit -> batch formation)."""
+        known (queue wait: t_submit -> batch formation). Ring only: an
+        interval in the past cannot be entered as an annotation."""
         if not self._enabled:
             return
         self._buf.append(Span(name, cat, track, trace_id,
@@ -197,7 +225,7 @@ class Tracer:
 
     def instant(self, name, cat="host", track=None, **args):
         """Zero-duration marker (flight-recorder trigger, swap installed,
-        rollback landed)."""
+        rollback landed). Ring only."""
         if not self._enabled:
             return
         self._buf.append(Span(name, cat, track, None,

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..datasets.dataset import DataSet
 from ..datasets.iterators import ListDataSetIterator, next_processed
 from .sharding import make_mesh, put_sharded, replicate, shard_params
@@ -233,7 +234,8 @@ class ParallelWrapper:
         return True
 
     def _round_done(self):
-        self._gate.round_done(self.model)
+        with obs.TRACER.span("parallel.checkpoint", cat="train"):
+            self._gate.round_done(self.model)
 
     def _inject_batch(self, ds):
         """Payload-corruption seam: site "wrapper.batch" over the shared
@@ -418,11 +420,13 @@ class ParallelWrapper:
             if not self._round_starts():
                 continue      # round covered by the restored checkpoint
             ds = self._inject_batch(ds)
-            net._rng, step_rng = jax.random.split(net._rng)
-            batch, feats = self._sharded_batch(ds, step_rng)
-            (net._params, net._updater_state, net._model_state, score,
-             _, *extras) = step(net._params, net._updater_state,
-                                net._model_state, batch)
+            with obs.TRACER.span("parallel.stage", cat="train"):
+                net._rng, step_rng = jax.random.split(net._rng)
+                batch, feats = self._sharded_batch(ds, step_rng)
+            with obs.TRACER.span("parallel.dispatch", cat="train"):
+                (net._params, net._updater_state, net._model_state, score,
+                 _, *extras) = step(net._params, net._updater_state,
+                                    net._model_state, batch)
             health = (extras.pop() if getattr(self, "_emits_health", False)
                       else None)
             if extras:
@@ -430,7 +434,8 @@ class ParallelWrapper:
                 net._last_activation_stats_iter = net.conf.iteration_count
             action = "ok"
             if health is not None:
-                action = self._handle_health(health, self._gate.round)
+                with obs.TRACER.span("parallel.health", cat="train"):
+                    action = self._handle_health(health, self._gate.round)
                 if action == "rollback":
                     continue    # counters/rng rewound; next batch retrains
             if action != "skip":
@@ -614,7 +619,8 @@ class ParallelWrapper:
     def _run_kstep(self, batches):
         net = self.model
         k = len(batches)
-        batches_tree, B = self._kstep_batches(batches)
+        with obs.TRACER.span("parallel.stage", cat="train", k=k):
+            batches_tree, B = self._kstep_batches(batches)
         h_gen = getattr(net, "_health_gen", 0)
         if self._jit_kstep is not None and \
                 getattr(self, "_kstep_health_gen", 0) != h_gen:
@@ -622,12 +628,15 @@ class ParallelWrapper:
         self._kstep_health_gen = h_gen
         if self._jit_kstep is None:
             self._jit_kstep = self._build_kstep()(batches_tree)
-        (net._params, net._updater_state, net._model_state,
-         score, *extra) = self._jit_kstep(net._params, net._updater_state,
-                                          net._model_state, batches_tree)
+        with obs.TRACER.span("parallel.dispatch", cat="train", k=k):
+            (net._params, net._updater_state, net._model_state,
+             score, *extra) = self._jit_kstep(
+                 net._params, net._updater_state, net._model_state,
+                 batches_tree)
         action = "ok"
         if getattr(self, "_kstep_emits_health", False):
-            action = self._handle_health(extra[0], self._gate.round)
+            with obs.TRACER.span("parallel.health", cat="train", k=k):
+                action = self._handle_health(extra[0], self._gate.round)
             if action == "rollback":
                 return action   # counters/rng rewound by the restore
         if action != "skip":

@@ -921,3 +921,186 @@ class TestObsReport:
         row = next(r for r in report["spans"]
                    if r["name"] == "serve.dispatch")
         assert row["count"] == 2
+
+
+# ---------------------------------------------------------------------------
+# (f) the annotation sink (ISSUE 26): spans are profiler annotations too
+# ---------------------------------------------------------------------------
+class _FakeAnnotations:
+    """An annotation factory that records what enters and exits it."""
+
+    def __init__(self):
+        self.entered, self.exited = [], []
+
+    def __call__(self, name, **args):
+        log = self
+
+        class Ctx:
+            def __enter__(self):
+                log.entered.append((name, args))
+                return self
+
+            def __exit__(self, *exc):
+                log.exited.append(name)
+                return False
+
+        self.last = Ctx()
+        return self.last
+
+    def names(self):
+        return [n for n, _ in self.entered]
+
+
+def _tiny_graph():
+    from deeplearning4j_tpu import ComputationGraph
+    gb = (NeuralNetConfiguration.Builder().seed(3).updater("sgd")
+          .learning_rate(0.05).graph_builder().add_inputs("in"))
+    gb.add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
+    gb.add_layer("out", OutputLayer(n_out=4, activation="softmax",
+                                    loss_function="mcxent"), "d")
+    conf = (gb.set_outputs("out")
+            .set_input_types(InputType.feed_forward(6)).build())
+    return ComputationGraph(conf).init()
+
+
+def _batches(n, rows=8):
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    rng = np.random.default_rng(4)
+    return [DataSet(rng.standard_normal((rows, 6)).astype(np.float32),
+                    np.eye(4, dtype=np.float32)[rng.integers(0, 4, rows)])
+            for _ in range(n)]
+
+
+class TestAnnotationSink:
+    @pytest.mark.parametrize("ring", [False, True])
+    def test_factory_sees_one_enter_and_exit_per_span(self, ring):
+        fake = _FakeAnnotations()
+        t = Tracer(enabled=ring, annotate=fake)
+        for i in range(3):
+            with t.span("train.dispatch", cat="train", k=i) as ctx:
+                assert len(fake.entered) == i + 1 and len(fake.exited) == i
+                # ring off: the annotation itself, no wrapper object
+                assert (ctx is fake.last) == (not ring)
+        assert fake.entered == [("train.dispatch", {"k": i})
+                                for i in range(3)]
+        assert fake.exited == ["train.dispatch"] * 3
+        # the ring records exactly when it is on, the same spans
+        assert [s.name for s in t.spans()] == \
+            (["train.dispatch"] * 3 if ring else [])
+
+    def test_ring_span_and_annotation_cover_the_same_interval(self):
+        stamps = {}
+
+        class Clocked:
+            def __init__(self, name, **args):
+                pass
+
+            def __enter__(self):
+                stamps["enter"] = time.monotonic_ns()
+
+            def __exit__(self, *exc):
+                stamps["exit"] = time.monotonic_ns()
+
+        t = Tracer(enabled=True).annotate_with(Clocked)
+        with t.span("x"):
+            time.sleep(0.002)
+        (s,) = t.spans()
+        assert stamps["enter"] <= s.t0_ns
+        assert s.t0_ns + s.dur_ns <= stamps["exit"]
+        assert s.dur_ns >= 0.9 * (stamps["exit"] - stamps["enter"]) - 50_000
+
+    def test_emit_and_instant_stay_ring_only(self):
+        fake = _FakeAnnotations()
+        t = Tracer(enabled=True, annotate=fake)
+        t.emit("serve.queue_wait", time.monotonic_ns() - 1000, 1000)
+        t.instant("flight.trigger")
+        assert fake.entered == [] and fake.exited == []
+        assert [s.name for s in t.spans()] == ["serve.queue_wait",
+                                               "flight.trigger"]
+
+    def test_without_a_factory_the_disabled_span_is_the_shared_noop(self):
+        from deeplearning4j_tpu.obs.trace import _NOOP
+        t = Tracer(enabled=False)
+        assert t.span("x") is _NOOP and t.span("y", k=1) is _NOOP
+        # and a factory can be taken away again
+        t.annotate_with(_FakeAnnotations()).annotate_with(None)
+        assert t.span("x") is _NOOP
+
+    def test_an_exception_inside_a_span_exits_the_annotation(self):
+        fake = _FakeAnnotations()
+        t = Tracer(enabled=True, annotate=fake)
+        with pytest.raises(ValueError):
+            with t.span("x"):
+                raise ValueError("boom")
+        assert fake.exited == ["x"] and len(t.spans("x")) == 1
+
+    def test_the_process_wide_tracer_writes_into_a_profiler_session(
+            self, tmp_path):
+        """The package root installs jax.profiler.TraceAnnotation on
+        obs.TRACER: with the ring OFF, a span is an event of the host
+        plane of a running profiler session, on the trace's clock."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        assert not obs.TRACER.enabled
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for i in range(3):
+                with obs.TRACER.span("pr26.probe", cat="train", k=i):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        host = [e.name for plane in ProfileData.from_file(path).planes
+                if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events]
+        assert sum(n.split("#")[0] == "pr26.probe" for n in host) == 3
+        assert len(obs.TRACER) == 0
+
+    def test_graph_fit_annotates_one_dispatch_a_step(self):
+        net, fake = _tiny_graph(), _FakeAnnotations()
+        with _global_tracer(Tracer(annotate=fake)) as t:
+            for ds in _batches(3):
+                net.fit(ds)
+        assert fake.names() == ["train.dispatch"] * 3
+        assert fake.exited == fake.names() and len(t) == 0
+
+    @pytest.mark.parametrize("k", [1, 2])
+    def test_parallel_wrapper_annotates_its_host_work_once_a_round(self, k):
+        from deeplearning4j_tpu.datasets.iterators import \
+            ListDataSetIterator
+        from deeplearning4j_tpu.parallel.parallel_wrapper import \
+            ParallelWrapper
+        pw = (ParallelWrapper.Builder(_tiny_graph()).workers(2)
+              .averaging_frequency(k).build())
+        fake = _FakeAnnotations()
+        with _global_tracer(Tracer(annotate=fake)):
+            pw.fit(ListDataSetIterator(_batches(4), 8))
+        rounds = 4 // k
+        assert fake.names() == ["parallel.stage", "parallel.dispatch",
+                                "parallel.checkpoint"] * rounds
+        assert fake.exited == fake.names()
+        if k > 1:
+            assert all(a == {"k": k} for n, a in fake.entered
+                       if n != "parallel.checkpoint")
+
+    def test_parallel_wrapper_health_is_a_span_when_armed(self):
+        from deeplearning4j_tpu.datasets.iterators import \
+            ListDataSetIterator
+        from deeplearning4j_tpu.parallel.parallel_wrapper import \
+            ParallelWrapper
+        pw = (ParallelWrapper.Builder(_tiny_graph()).workers(2)
+              .health_policy(True).build())
+        with _global_tracer(Tracer(enabled=True)) as t:
+            pw.fit(ListDataSetIterator(_batches(2), 8))
+        for name in ("parallel.stage", "parallel.dispatch",
+                     "parallel.health", "parallel.checkpoint"):
+            assert len(t.spans(name)) == 2, name
+        # stage ends before its dispatch starts: never nested
+        for st, d in zip(t.spans("parallel.stage"),
+                         t.spans("parallel.dispatch")):
+            assert st.t0_ns + st.dur_ns <= d.t0_ns

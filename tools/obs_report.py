@@ -9,7 +9,12 @@ One place that joins the three telemetry surfaces PR 6 standardized:
     scheduling-gap phases, aggregated per phase — included automatically
     whenever the spans contain `serve.request` lanes;
   * the device-op table from `optimize.profiler.summarize_trace` (an
-    xplane/trace capture directory, when one exists);
+    xplane/trace capture directory, when one exists), and with the
+    compiled step's HLO text (`--hlo`: what
+    `net.lower_step(ds).compile().as_text()` returns, saved to a file)
+    the same device time by LAYER kind, forward and backward apart
+    (`summarize_layers`: the containers' `jax.named_scope`s, joined to
+    the trace by instruction name);
   * one or more metrics snapshots (`ServingMetrics.snapshot()` dicts or
     a `MetricsRegistry.snapshot()`), None-guarded via the shared
     `obs.registry.fmt` helper.
@@ -19,7 +24,8 @@ One place that joins the three telemetry surfaces PR 6 standardized:
 renders a saved trace + profile dir + metrics JSON from disk:
 
     python tools/obs_report.py --trace /tmp/serve.trace.json \
-        [--profile /tmp/prof] [--metrics /tmp/snapshot.json]
+        [--profile /tmp/prof [--hlo /tmp/step.hlo.txt]] \
+        [--metrics /tmp/snapshot.json]
 
 `--trace` repeats: two or more saved traces are stitched on their
 `clock_sync` wall-clock anchors into ONE Perfetto-loadable file
@@ -77,13 +83,16 @@ def span_summary(spans_or_trace):
     return rows
 
 
-def build_report(spans=None, profile_logdir=None, metrics=None):
+def build_report(spans=None, profile_logdir=None, metrics=None,
+                 hlo_text=None):
     """Assemble the combined report dict. `metrics` is a snapshot dict
     or {label: snapshot}; `profile_logdir` is summarized when readable
     (missing/unparsable traces degrade to None, never raise — the host
-    report must survive a profile that was never captured)."""
+    report must survive a profile that was never captured); `hlo_text`
+    (the traced step's compiled text) adds the by-layer table."""
     report = {"spans": span_summary(spans) if spans is not None else None,
-              "device_ops": None, "metrics": None, "decomposition": None}
+              "device_ops": None, "device_layers": None, "metrics": None,
+              "decomposition": None}
     if spans is not None:
         from deeplearning4j_tpu.obs.decompose import decompose
         dec = decompose(spans)
@@ -91,9 +100,12 @@ def build_report(spans=None, profile_logdir=None, metrics=None):
             report["decomposition"] = dec
     if profile_logdir is not None:
         try:
-            from deeplearning4j_tpu.optimize.profiler import \
-                summarize_trace
+            from deeplearning4j_tpu.optimize.profiler import (
+                op_scopes, summarize_layers, summarize_trace)
             report["device_ops"] = summarize_trace(profile_logdir)
+            if hlo_text is not None:
+                report["device_layers"] = summarize_layers(
+                    profile_logdir, op_scopes(hlo_text))
         except Exception as e:      # no trace / no schema: degrade
             report["device_ops_error"] = str(e)
     if metrics is not None:
@@ -154,6 +166,10 @@ def format_report(report, top=20):
         lines += _table(report["device_ops"],
                         ["name", "total_ms", "count", "pct"],
                         "device ops", limit=top)
+        if report.get("device_layers") is not None:
+            lines += _table(report["device_layers"],
+                            ["name", "total_ms", "count", "pct"],
+                            "device time by layer")
     elif report.get("device_ops_error"):
         lines.append(f"== device ops ==\n  unavailable: "
                      f"{report['device_ops_error']}")
@@ -176,6 +192,9 @@ def main():
                          "than one --trace is given (default: "
                          "<first-trace>.merged.json)")
     ap.add_argument("--profile", help="jax.profiler logdir to summarize")
+    ap.add_argument("--hlo", help="compiled text of the traced step "
+                    "(net.lower_step(ds).compile().as_text()): adds the "
+                    "device time by layer to --profile's table")
     ap.add_argument("--metrics", help="metrics snapshot JSON file")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of text")
@@ -195,8 +214,12 @@ def main():
     if args.metrics:
         with open(args.metrics) as fh:
             metrics = json.load(fh)
+    hlo_text = None
+    if args.hlo:
+        with open(args.hlo) as fh:
+            hlo_text = fh.read()
     report = build_report(spans=spans, profile_logdir=args.profile,
-                          metrics=metrics)
+                          metrics=metrics, hlo_text=hlo_text)
     print(json.dumps(report) if args.json else format_report(report))
 
 
