@@ -24,6 +24,22 @@ from ..input_type import ConvolutionalInputType, FeedForwardInputType, InputType
 from .base import LayerConf, register_layer
 
 
+def _bn_train_stats(x, gamma, beta, eps, axes, fast_var):
+    """Train-mode batch norm of `x` over `axes`: (y, mean, var, rstd), the
+    statistics accumulated in >= f32. One-pass E[x]/E[x^2] variance when
+    `fast_var` (both reductions over the SAME read of x)."""
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(acc)
+    mean = jnp.mean(xf, axis=axes)
+    if fast_var:
+        var = jnp.maximum(jnp.mean(xf * xf, axis=axes) - mean * mean, 0.0)
+    else:
+        var = jnp.var(xf, axis=axes)
+    rstd = jax.lax.rsqrt(var + eps)
+    xn = (xf - mean) * rstd * gamma.astype(acc) + beta.astype(acc)
+    return xn.astype(x.dtype), mean, var, rstd
+
+
 def _bn_train_fused(eps, axes, fast_var):
     """Batch-norm train-mode core with a hand-fused VJP.
 
@@ -44,24 +60,11 @@ def _bn_train_fused(eps, axes, fast_var):
     """
     @jax.custom_vjp
     def f(x, gamma, beta):
-        y, mean, var, _ = _impl(x, gamma, beta)
-        return y, mean, var
-
-    def _impl(x, gamma, beta):
-        acc = jnp.promote_types(x.dtype, jnp.float32)
-        xf = x.astype(acc)
-        mean = jnp.mean(xf, axis=axes)
-        if fast_var:
-            var = jnp.maximum(jnp.mean(xf * xf, axis=axes) - mean * mean,
-                              0.0)
-        else:
-            var = jnp.var(xf, axis=axes)
-        rstd = jax.lax.rsqrt(var + eps)
-        xn = (xf - mean) * rstd * gamma.astype(acc) + beta.astype(acc)
-        return xn.astype(x.dtype), mean, var, rstd
+        return _bn_train_stats(x, gamma, beta, eps, axes, fast_var)[:3]
 
     def fwd(x, gamma, beta):
-        y, mean, var, rstd = _impl(x, gamma, beta)
+        y, mean, var, rstd = _bn_train_stats(x, gamma, beta, eps, axes,
+                                             fast_var)
         return (y, mean, var), (x, gamma, mean, rstd)
 
     def bwd(res, cts):
@@ -79,6 +82,95 @@ def _bn_train_fused(eps, axes, fast_var):
         dx = (g * rstd) * (dyf - s1 / n - xc * (rstd * rstd) * (s2 / n))
         return (dx.astype(x.dtype), (s2 * rstd).astype(gamma.dtype),
                 s1.astype(gamma.dtype))
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def _conv1x1_bn_train_fused(eps, fast_var, stride):
+    """The PAIR (1x1 convolution without bias -> train-mode batch norm) as
+    one custom VJP whose backward never reads the convolution's output.
+
+    `_bn_train_fused`'s backward needs x at every position, and x is the
+    wide tensor of an expanding 1x1 convolution: XLA re-reads it in the
+    batch-norm sums and in the prologue of both of the convolution's
+    gradient fusions, on a step that is HBM-bound (PERF.md section 5). But
+    x = a.W is a linear function of the narrow input `a`, so with
+        s1 = sum dy [Cout]            sa = sum a [Cin]
+        A1 = a^T.dy [Cin,Cout]        G  = a^T.a [Cin,Cin]
+        s2 = sum_j A1[j,:]*W[j,:] - mean*s1      (= sum dy*(x - mean))
+        k  = gamma*rstd ;  c2 = rstd^2*s2/n
+    the same gradients are
+        dW = k*(A1 - sa(x)s1/n - (G.W - sa(x)mean)*c2)
+        da = dy.(W diag(k))^T - a.M + (-k*s1/n + mean*k*c2).W^T ,
+             M = W diag(k*c2) W^T [Cin,Cin]
+        dgamma = s2*rstd ;  dbeta = s1
+    and the residuals are (a, W, gamma, mean, rstd): x is dead after the
+    forward. G costs n*Cin^2 multiply-adds, under the convolution's own
+    n*Cin*Cout while Cout > Cin, which is where the container engages this
+    (`ComputationGraph._convbn_plan`). Operands stay in the compute dtype;
+    the sums, A1, G and all [C,C] algebra are >= f32.
+
+    f(a, w, gamma, beta) -> (y, mean, var) with a [N,H,W,Cin] and w
+    [1,1,Cin,Cout]; the forward is the strided convolution followed by
+    `_bn_train_stats`, the unpaired layers' own operations. In the backward
+    a stride subsamples `a` first (XLA reads the rows it needs, not the
+    tensor). mean/var feed the running statistics and take no gradient, as
+    in `_bn_train_fused`.
+    """
+    sh, sw = stride
+    axes = (0, 1, 2)
+
+    def _forward(a, w, gamma, beta):
+        x = jax.lax.conv_general_dilated(
+            a, w, window_strides=(sh, sw), padding="VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        return _bn_train_stats(x, gamma, beta, eps, axes, fast_var)
+
+    @jax.custom_vjp
+    def f(a, w, gamma, beta):
+        return _forward(a, w, gamma, beta)[:3]
+
+    def fwd(a, w, gamma, beta):
+        y, mean, var, rstd = _forward(a, w, gamma, beta)
+        return (y, mean, var), (a, w, gamma, mean, rstd)
+
+    def bwd(res, cts):
+        dy, _dmean, _dvar = cts      # EMA path carries no gradient
+        a, w, gamma, mean, rstd = res
+        cdt = a.dtype
+        acc = jnp.promote_types(cdt, jnp.float32)
+        hi = jax.lax.Precision.HIGHEST
+        a_s, unstride = jax.vjp(
+            lambda t: jax.lax.slice(t, (0, 0, 0, 0), t.shape,
+                                    (1, sh, sw, 1)), a)
+        n = a_s.shape[0] * a_s.shape[1] * a_s.shape[2]
+        wm = w[0, 0].astype(acc)                          # [Cin, Cout]
+        s1 = jnp.sum(dy.astype(acc), axis=axes)
+        sa = jnp.sum(a_s.astype(acc), axis=axes)
+        a1 = jnp.einsum("nhwi,nhwo->io", a_s, dy,
+                        preferred_element_type=acc)
+        g = jnp.einsum("nhwi,nhwj->ij", a_s, a_s,
+                       preferred_element_type=acc)
+        s2 = jnp.sum(a1 * wm, axis=0) - mean * s1
+        k = gamma.astype(acc) * rstd
+        c2 = rstd * rstd * (s2 / n)
+        gw = jnp.dot(g, wm, precision=hi)
+        dw = k * (a1 - jnp.outer(sa, s1 / n)
+                  - (gw - jnp.outer(sa, mean)) * c2)
+        m = jnp.dot(wm * (k * c2), wm.T, precision=hi)    # [Cin, Cin]
+        const = jnp.dot(mean * k * c2 - k * s1 / n, wm.T, precision=hi)
+        # da in two products: the narrow one first, stored in the compute
+        # dtype as every activation and cotangent of the step is, so the
+        # wide one takes it as its epilogue's operand (in f32 XLA writes
+        # the wide product out and reads it back: 2 more passes over a)
+        t = jnp.einsum("nhwi,ij->nhwj", a_s, (-m).astype(cdt))
+        da_s = (jnp.einsum("nhwo,io->nhwi", dy, (wm * k).astype(cdt),
+                           preferred_element_type=acc)
+                + t.astype(acc) + const).astype(cdt)
+        da, = unstride(da_s)
+        return (da, dw[None, None].astype(w.dtype),
+                (s2 * rstd).astype(gamma.dtype), s1.astype(gamma.dtype))
 
     f.defvjp(fwd, bwd)
     return f
@@ -134,6 +226,11 @@ class BatchNormalization(LayerConf):
         return {"mean": jnp.zeros((self.n_out,), jnp.float32),
                 "var": jnp.ones((self.n_out,), jnp.float32)}
 
+    def running_stats(self, state, mean, var):
+        """The EMA update of the running statistics from one batch's."""
+        return {"mean": self.decay * state["mean"] + (1 - self.decay) * mean,
+                "var": self.decay * state["var"] + (1 - self.decay) * var}
+
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
         axes = tuple(range(x.ndim - 1))  # all but channel/feature axis
@@ -142,11 +239,7 @@ class BatchNormalization(LayerConf):
             y, mean, var = _bn_train_fused(
                 self.eps, axes, self.use_fast_variance)(
                     x, params["gamma"], params["beta"])
-            new_state = {
-                "mean": self.decay * state["mean"] + (1 - self.decay) * mean,
-                "var": self.decay * state["var"] + (1 - self.decay) * var,
-            }
-            return y, new_state
+            return y, self.running_stats(state, mean, var)
         if train:
             # One-pass statistics: E[x] and E[x^2] reduce over the SAME read
             # of x (XLA fuses the two reductions into a single pass), vs
@@ -161,10 +254,7 @@ class BatchNormalization(LayerConf):
                     jnp.mean(xf * xf, axis=axes) - mean * mean, 0.0)
             else:
                 var = jnp.var(xf, axis=axes)
-            new_state = {
-                "mean": self.decay * state["mean"] + (1 - self.decay) * mean,
-                "var": self.decay * state["var"] + (1 - self.decay) * var,
-            }
+            new_state = self.running_stats(state, mean, var)
         else:
             mean, var = state["mean"], state["var"]
             new_state = state

@@ -23,8 +23,12 @@ import numpy as np
 from ... import obs
 from ...datasets.dataset import DataSet, MultiDataSet
 from ...datasets.iterators import next_processed
+from .. import activations
 from ..conf.computation_graph_configuration import ComputationGraphConfiguration
 from ..conf.layers.base import LayerConf, layer_scope
+from ..conf.layers.convolution import ConvolutionLayer
+from ..conf.layers.normalization import (BatchNormalization,
+                                         _conv1x1_bn_train_fused)
 from ..conf.layers.recurrent import BaseRecurrentLayer
 from ..updater import updaters as U
 
@@ -140,7 +144,8 @@ class ComputationGraph:
                 spec, params.get(name), in_acts, in_masks, train=train,
                 lrng=lrng, state_entry=state.get(name),
                 carry_entry=(carries or {}).get(name)
-                if carries is not None else None)
+                if carries is not None else None,
+                pair=self._pair_of(name, train, params, acts))
             acts[name] = out
             if st is not None:
                 new_state[name] = st
@@ -162,12 +167,30 @@ class ComputationGraph:
             if jnp.issubdtype(a.dtype, jnp.floating) else a, p)
 
     def _forward_vertex(self, spec, p, in_acts, in_masks, *, train, lrng,
-                        state_entry=None, carry_entry=None):
+                        state_entry=None, carry_entry=None, pair=None):
         """One vertex's forward — the SINGLE dispatch (preprocessor, param
-        cast, carry/state/stateless branches) shared by `_apply_graph` and
-        the remat segment body, so the two forward paths cannot drift.
-        Returns (out, new_state | None, new_carry | None). Everything it
-        traces is under the scope `<kind>.<vertex name>`."""
+        cast, carry/state/stateless/paired branches) shared by
+        `_apply_graph` and the remat segment body, so the two forward paths
+        cannot drift. Returns (out, new_state | None, new_carry | None).
+        Everything it traces is under the scope `<kind>.<vertex name>`;
+        `pair` is `_pair_of`'s answer for this vertex."""
+        if pair is not None:
+            # the batch norm of a planned pair: convolution and batch norm
+            # as one function of the convolution's INPUT, under the
+            # convolution's scope (its backward is convolution work); the
+            # running statistics under the batch norm's
+            cspec, cp, a = pair
+            with layer_scope(cspec.conf, cspec.name):
+                if cspec.preprocessor is not None:
+                    a = cspec.preprocessor.pre_process(a)
+                p = self._cast_params(p)
+                y, mean, var = _conv1x1_bn_train_fused(
+                    spec.conf.eps, spec.conf.use_fast_variance,
+                    cspec.conf.stride)(
+                        a, self._cast_params(cp)["W"], p["gamma"], p["beta"])
+            with layer_scope(spec.conf, spec.name):
+                return y, spec.conf.running_stats(state_entry, mean,
+                                                  var), None
         with layer_scope(spec.conf, spec.name):
             if spec.is_layer:
                 layer = spec.conf
@@ -189,6 +212,63 @@ class ComputationGraph:
                         None, None)
             return (spec.conf.forward(in_acts, masks=in_masks, train=train,
                                       rng=lrng), None, None)
+
+    def _convbn_plan(self):
+        """{batch-norm vertex: convolution vertex} for every pair the
+        training forward computes as one function
+        (`_conv1x1_bn_train_fused`: a backward that never reads the
+        convolution's output; the step is HBM-bound). The rule is read from
+        the graph, once a container: an EXPANDING 1x1 convolution
+        (n_out > n_in: the output is the wide tensor, and the backward's
+        extra Cin x Cin product stays under the convolution's own work)
+        with no bias, no padding, identity activation and no input dropout,
+        whose only consumer is a batch norm with learned scale and shift,
+        the fused backward and no preprocessor. Every other convolution and
+        batch norm runs its own layer's code. The gauge
+        `train.convbn_pairs` says how many the plan holds."""
+        if getattr(self, "_convbn_plan_cache", None) is None:
+            verts = self.conf.vertices
+            consumers = {}
+            for name, spec in verts.items():
+                for i in spec.inputs:
+                    consumers.setdefault(i, []).append(name)
+            plan = {}
+            for name, spec in verts.items():
+                bn = spec.conf
+                cspec = verts.get(spec.inputs[0]) if spec.inputs else None
+                if (type(bn) is not BatchNormalization or cspec is None
+                        or type(cspec.conf) is not ConvolutionLayer):
+                    continue
+                conv = cspec.conf
+                if (conv.kernel_size == (1, 1) and not conv.has_bias
+                        and (conv.padding == (0, 0)
+                             or str(conv.convolution_mode).lower() == "same")
+                        and (activations.get(conv.activation)
+                             is activations.identity)
+                        and not conv.dropout
+                        and conv.n_out > conv.n_in
+                        and consumers[cspec.name] == [name]
+                        and cspec.name not in self.conf.network_outputs
+                        and spec.preprocessor is None
+                        and bn.fused_backward and not bn.lock_gamma_beta):
+                    plan[name] = cspec.name
+            self._convbn_plan_cache = plan
+            obs.default_registry().gauge("train.convbn_pairs").set(len(plan))
+        return self._convbn_plan_cache
+
+    def _pair_of(self, name, train, params, acts):
+        """(convolution's spec, its parameters, its input) when `name` is
+        the batch norm of a planned pair and this forward trains; None
+        otherwise, and where the convolution's side is not in reach (a
+        remat segment that holds the batch norm alone)."""
+        conv = self._convbn_plan().get(name) if train else None
+        if conv is None:
+            return None
+        cspec = self.conf.vertices[conv]
+        a = acts.get(cspec.inputs[0])
+        if a is None or not params.get(conv) or not params.get(name):
+            return None
+        return cspec, params[conv], a
 
     def _remat_plan(self):
         """Segment the topological order at element-wise (residual-add)
@@ -262,7 +342,8 @@ class ComputationGraph:
                     out, st, _ = self._forward_vertex(
                         spec, p_sub.get(name), in_acts,
                         [None] * len(in_acts), train=train, lrng=lrng,
-                        state_entry=st_sub.get(name))
+                        state_entry=st_sub.get(name),
+                        pair=self._pair_of(name, train, p_sub, local))
                     if st is not None:
                         st_new[name] = st
                     local[name] = out
@@ -608,13 +689,15 @@ class ComputationGraph:
                 self._fit_mds(mds)
         return self
 
-    def lower_step(self, ds):
+    def lower_step(self, ds, sharding=None):
         """Lower (trace without running) the jitted step `fit` calls for one
         DataSet / MultiDataSet of this shape, as `ParallelWrapper.lower_step`
         does for the sharded step: `.compile().as_text()` is the compiled
         HLO whose `op_name` metadata carries the layer scopes
         (optimize/profiler.py `op_scopes`). Consumes nothing: the loop
-        state and the rng stream are left as they are."""
+        state and the rng stream are left as they are. With `sharding`
+        every argument is lowered as a shape placed on it: a device that is
+        described and not attached holds no array (tools/step_bytes.py)."""
         self._ensure_init()
         if self._jit_step is None:
             self._jit_step = self._make_step()
@@ -622,9 +705,13 @@ class ComputationGraph:
             ds = _dataset_to_mds(ds)
         loop = self._loop or {"iteration": jnp.zeros((), jnp.float32),
                               "rng": self._rng}
-        return self._jit_step.lower(
-            self._params, self._updater_state, self._model_state, loop,
-            *self._canon_mds(ds))
+        args = (self._params, self._updater_state, self._model_state, loop,
+                *self._canon_mds(ds))
+        if sharding is not None:
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sharding), args)
+        return self._jit_step.lower(*args)
 
     def _fit_mds(self, mds: MultiDataSet):
         if self._jit_step is None:

@@ -209,13 +209,18 @@ def scope_of(op_name):
     return None
 
 
+def scope_label(op_name):
+    """"<kind> forward|backward" of an op_name path's layer scope (see
+    `scope_of`), or `UNSCOPED`: the row an operation is summed under."""
+    found = scope_of(op_name)
+    return f"{found[0]} {found[2]}".strip() if found else UNSCOPED
+
+
 def summarize_layers(logdir, scopes):
     """The same operation line by layer kind, forward and backward apart:
     rows {"name": "<kind> forward|backward", "total_ms", "count", "pct"}
     from `scopes` (see `op_scopes`; the compiled text of the step that was
     traced). Operations whose instruction is not in the table, or whose
     op_name holds no layer scope, are the row `UNSCOPED`."""
-    def key(event_name):
-        found = scope_of(scopes.get(instruction_name(event_name), ""))
-        return f"{found[0]} {found[2]}".strip() if found else UNSCOPED
-    return _rows((key(name), ms) for name, ms in _device_ops(logdir))
+    return _rows((scope_label(scopes.get(instruction_name(name), "")), ms)
+                 for name, ms in _device_ops(logdir))
