@@ -207,7 +207,15 @@ def make_moe_block_fn(n_heads, moe_apply):
     `make_block_fn`, the FFN replaced by `moe_apply(moe_params, tokens)`
     (dense or expert-parallel — `parallel/moe.py`). Stage params must carry
     a "moe" subtree instead of "mlp". Returns (y, aux_loss) so the trainer
-    can add the load-balance term."""
+    can add the load-balance term.
+
+    This is the expert layer that DROPS: GeLU experts, top-1/top-2, fixed
+    capacity, overflow left to the residual path (`moe_mlp_dense` /
+    `moe_mlp_sharded`), used by this module's pipeline/EP trainers. The
+    one that does not drop (`parallel/moe.py held_experts_ffn`: gated SiLU
+    experts, top-k of all, one chip's share) is the containers' `moe`
+    layer kind (`nn/conf/layers/decoder.py`, `models/zoo/keye_vl.py`);
+    `parallel/moe.py`'s module docstring says why two remain."""
 
     def block_fn(p, x):
         B, T, D = x.shape

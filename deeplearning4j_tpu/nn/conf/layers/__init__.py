@@ -1,5 +1,7 @@
 from .attention import SelfAttentionLayer
 from .base import LAYER_REGISTRY, LayerConf, register_layer
+from .decoder import (LMHeadLayer, MoELayer, RMSNormLayer,
+                      SparseAttentionLayer, TokenEmbeddingLayer)
 from .convolution import (ConvolutionLayer, GlobalPoolingLayer,
                           SubsamplingLayer, ZeroPaddingLayer)
 from .feedforward import (ActivationLayer, AutoEncoder, DenseLayer,
@@ -20,7 +22,8 @@ __all__ = [
     "ConvolutionLayer", "SubsamplingLayer", "ZeroPaddingLayer",
     "GlobalPoolingLayer", "BatchNormalization", "LocalResponseNormalization",
     "BaseRecurrentLayer", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
-    "SelfAttentionLayer", "RBM", "VariationalAutoencoder",
+    "SelfAttentionLayer", "TokenEmbeddingLayer", "RMSNormLayer",
+    "SparseAttentionLayer", "MoELayer", "LMHeadLayer", "RBM", "VariationalAutoencoder",
     "BernoulliReconstructionDistribution",
     "GaussianReconstructionDistribution",
 ]

@@ -124,6 +124,16 @@ class LayerConf:
     def init_state(self):
         return {}
 
+    # A layer whose training loss depends on its ACTIVATIONS (not only on
+    # its params, as `reg_score` does) sets this and returns that loss in
+    # its state's "layer_loss" entry; ComputationGraph adds it to the score.
+    has_layer_loss = False
+
+    def gauges(self, state):
+        """{name: scalar} worth publishing from the state the last step
+        left (ComputationGraph.publish_layer_gauges)."""
+        return {}
+
     # ------------------------------------------------------------------
     # Regularization score contribution (reference BaseLayer.calcL1/calcL2)
     # ------------------------------------------------------------------

@@ -1,0 +1,376 @@
+"""Layers of a sparse-attention mixture-of-experts decoder, for the containers.
+
+Beyond-reference capability (the reference predates all of it). Five layer
+kinds, each traced under its own `<kind>.<vertex>` scope by the container:
+
+- `tokenembedding`: ids [B, T] -> rows of a table; a second input
+  (`extras[0]`, [B, P, D]: an image's embeddings) replaces the rows at the
+  first P positions.
+- `rmsnorm`: x * rsqrt(mean(x^2) + eps) * g, computed in float32.
+- `sparseattention`: grouped-query attention with per-head RMSNorm of q and
+  k and three-axis rotary positions (second input, [B, T, 3]), under a
+  LEARNED sparse selection: a small indexer scores every causal pair, the
+  `topk` best keys of a query are kept, and the main attention reads only
+  those. The selection is discrete, so the indexer learns from a loss of its
+  own (KL from the main attention's probabilities, summed over heads, to the
+  indexer's softmax over the selected keys), which the layer returns through
+  its state's `layer_loss` entry; the container adds it to the score.
+- `moe`: router over ALL experts, the top k a token, and this chip's share
+  of the experts (`parallel/moe.py` `held_experts_ffn`: nothing dropped).
+- `lmhead`: logits over a vocabulary (slice) and the masked mean
+  cross-entropy over a sequence with integer labels, in token chunks so that
+  no [tokens, vocabulary] array outlives a chunk.
+
+Layout [batch, time, features], as the recurrent and attention layers.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..input_type import InputType
+from .base import LayerConf, register_layer
+
+NEG = -1e30          # a masked score: exp() of it is 0, and it is finite
+
+
+def _normal(key, shape, std, dtype):
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def rms_norm(x, g, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+class _SequenceLayer(LayerConf):
+    """[B, T, n_in] -> [B, T, n_out]; sizes are given, not inferred."""
+
+    def get_output_type(self, input_type):
+        return InputType.recurrent(self.n_out or self.n_in,
+                                   getattr(input_type, "time_series_length",
+                                           -1))
+
+
+class _StatefulSequenceLayer(_SequenceLayer):
+    """A layer whose state is what its last forward SAID (a loss of its
+    own, counters), never what the next one reads."""
+
+    def has_state(self):
+        return True
+
+    def forward(self, params, x, *, state=None, **kw):
+        return self.forward_with_state(params, x, state, **kw)[0]
+
+
+@register_layer("tokenembedding")
+@dataclass
+class TokenEmbeddingLayer(_SequenceLayer):
+    n_in: int = None            # rows of the table (the vocabulary slice)
+    n_out: int = None
+    init_std: float = 0.02
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"W": _normal(key, (self.n_in, self.n_out), self.init_std,
+                             dtype)}
+
+    def forward(self, params, x, *, train=False, rng=None, mask=None,
+                state=None, extras=()):
+        emb = jnp.take(params["W"], x.astype(jnp.int32), axis=0)
+        if extras:
+            emb = jax.lax.dynamic_update_slice_in_dim(
+                emb, extras[0].astype(emb.dtype), 0, axis=1)
+        return emb
+
+
+@register_layer("rmsnorm")
+@dataclass
+class RMSNormLayer(_SequenceLayer):
+    n_in: int = None
+    n_out: int = None
+    eps: float = 1e-6
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"g": jnp.ones((self.n_in,), dtype)}
+
+    def forward(self, params, x, *, train=False, rng=None, mask=None,
+                state=None):
+        return rms_norm(x, params["g"], self.eps)
+
+
+# ---------------------------------------------------------------------------
+# sparse attention
+# ---------------------------------------------------------------------------
+def mrope_angles(positions, head_dim, theta, sections):
+    """cos, sin [B, T, head_dim/2] (float32) of the three-axis rotary turn:
+    frequency slot i turns by the position on the axis its section names
+    (`sections` slots for t, then h, then w)."""
+    half = head_dim // 2
+    inv = theta ** (-(2.0 * jnp.arange(half, dtype=jnp.float32)) / head_dim)
+    axis = jnp.repeat(jnp.arange(3), jnp.asarray(sections),
+                      total_repeat_length=half)
+    ang = jnp.take(positions.astype(jnp.float32), axis, axis=-1) * inv
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rotate_half(x, cos, sin):
+    """x [B, T, H, Dh]; pairs (i, i + Dh/2)."""
+    xf = x.astype(jnp.float32)
+    a, b = jnp.split(xf, 2, axis=-1)
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate([a * c - b * s, b * c + a * s], -1).astype(x.dtype)
+
+
+def index_scores(qi, ki, w):
+    """I[c, s] = sum_j w[c, j] ReLU(qi[c, j] . ki[s]) in float32.
+    qi [C, HI, DI], ki [S, DI], w [C, HI] -> [C, S]."""
+    dots = jnp.einsum("chd,sd->hcs", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots)
+                   * w.astype(jnp.float32).T[:, :, None], 0)
+
+
+def select_keys(scores, q_pos, topk):
+    """The selection of each query row of `scores` [C, S] (keys 0..S-1,
+    queries at positions `q_pos` [C]): the causal keys whose score is at
+    least the `topk`-th largest (ties with it are kept), all causal keys
+    where there are no more than `topk`. One set for every head. Returns a
+    bool mask [C, S]."""
+    from ....ops.sparse_attention import at_least_kth
+    causal = jnp.arange(scores.shape[1])[None, :] <= q_pos[:, None]
+    if scores.shape[1] <= topk:
+        return causal
+    masked = jnp.where(causal, jax.lax.stop_gradient(scores), -jnp.inf)
+    return at_least_kth(masked, topk) != 0
+
+
+@register_layer("sparseattention")
+@dataclass
+class SparseAttentionLayer(_StatefulSequenceLayer):
+    n_in: int = None
+    n_out: int = None
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    eps: float = 1e-6
+    rope_theta: float = 1e7
+    mrope_section: tuple = (16, 24, 24)
+    indexer_heads: int = 16
+    indexer_head_dim: int = 64
+    topk: int = 2048
+    q_chunk_size: int = 512
+    init_std: float = 0.02
+
+    has_layer_loss = True           # state["layer_loss"] joins the score
+
+    def init_state(self):
+        return {"layer_loss": jnp.zeros((), jnp.float32),
+                "selected_keys": jnp.zeros((), jnp.float32)}
+
+    def gauges(self, state):
+        return {"selected_keys_per_query": state["selected_keys"],
+                "indexer_loss": state["layer_loss"]}
+
+    def init_params(self, key, dtype=jnp.float32):
+        D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
+        HI, DI = self.indexer_heads, self.indexer_head_dim
+        k = jax.random.split(key, 7)
+        mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        return {"Wq": mk(k[0], (D, H * Dh)), "Wk": mk(k[1], (D, KV * Dh)),
+                "Wv": mk(k[2], (D, KV * Dh)), "Wo": mk(k[3], (H * Dh, D)),
+                "q_norm": jnp.ones((Dh,), dtype),
+                "k_norm": jnp.ones((Dh,), dtype),
+                "WqI": mk(k[4], (D, HI * DI)), "WkI": mk(k[5], (D, DI)),
+                "Ww": mk(k[6], (D, HI))}
+
+    # -- pieces, public so that a check can call what the step calls -------
+    def project(self, p, h, positions):
+        """q [B,T,KV,R,Dh], k, v [B,T,KV,Dh] (normed, turned) and the
+        indexer's qi [B,T,HI,DI], ki [B,T,DI], w [B,T,HI] (from
+        stop_gradient(h))."""
+        B, T, _ = h.shape
+        H, KV, Dh = self.n_heads, self.n_kv_heads, self.head_dim
+        cos, sin = mrope_angles(positions, Dh, self.rope_theta,
+                                self.mrope_section)
+        q = rms_norm((h @ p["Wq"]).reshape(B, T, H, Dh), p["q_norm"],
+                     self.eps)
+        k = rms_norm((h @ p["Wk"]).reshape(B, T, KV, Dh), p["k_norm"],
+                     self.eps)
+        q = rotate_half(q, cos, sin).reshape(B, T, KV, H // KV, Dh)
+        k = rotate_half(k, cos, sin)
+        v = (h @ p["Wv"]).reshape(B, T, KV, Dh)
+        with jax.named_scope("indexer"):
+            hb = jax.lax.stop_gradient(h)
+            qi = (hb @ p["WqI"]).reshape(B, T, self.indexer_heads,
+                                         self.indexer_head_dim)
+            ki, w = hb @ p["WkI"], hb @ p["Ww"]
+        return q, k, v, qi, ki, w
+
+    def _row(self, q, k, v, qi, ki, w):
+        """One sequence: (o [T, H, Dh], the sum of its queries' KL, its
+        selected pairs). Index scores and the selection a chunk of queries
+        at a time, each against its causal prefix of keys (a chunk keeps
+        nothing for the backward but its inputs); then the main attention
+        over the selected pairs and the indexer's target, the probabilities
+        of all heads added up, as Pallas kernels (ops/sparse_attention.py)
+        that never write a head's [T, T] scores out."""
+        from ....ops.sparse_attention import (KEEP, head_summed_probs,
+                                              masked_attention)
+        T, KV, R, Dh = q.shape
+        C = min(self.q_chunk_size, T)
+        scores, sel = [], []
+        for a in range(0, T, C):
+            b = min(a + C, T)
+            with jax.named_scope("indexer"):
+                s = jax.checkpoint(index_scores)(qi[a:b], ki[:b], w[a:b])
+            with jax.named_scope("select"):
+                m = select_keys(s, jnp.arange(a, b), self.topk)
+            scores.append(jnp.pad(s, ((0, 0), (0, T - b))))
+            sel.append(jnp.pad(m, ((0, 0), (0, T - b))))
+        scores, sel = jnp.concatenate(scores), jnp.concatenate(sel)
+        mask = checkpoint_name(
+            jax.lax.stop_gradient(sel.astype(jnp.int8))[None], KEEP)
+        heads = lambda a: jnp.moveaxis(a.reshape(T, -1, Dh), 0, 1)[None]
+        qh, kh, vh = heads(q), heads(k), heads(v)
+        scale = 1.0 / math.sqrt(Dh)
+        with jax.named_scope("attend"):
+            o, lse = masked_attention(qh, kh, vh, mask, scale)
+        with jax.named_scope("indexer"):
+            sg = jax.lax.stop_gradient
+            # the main attention's probabilities over all heads, as the
+            # indexer's target; L1-normalised over the selection
+            target = sg(head_summed_probs(sg(qh), sg(kh), sg(lse), mask,
+                                          scale))[0] / (KV * R)
+            logq = jax.nn.log_softmax(jnp.where(sel, scores, NEG), -1)
+            kl = jnp.sum(jnp.where(
+                sel, jax.scipy.special.xlogy(target, target) - target * logq,
+                0.0))
+        return (jnp.moveaxis(o[0], 0, 1), kl,
+                jnp.sum(sel, dtype=jnp.float32))
+
+    def forward_with_state(self, params, x, state, *, train=False, rng=None,
+                           mask=None, extras=()):
+        B, T, _ = x.shape
+        parts = self.project(params, x, extras[0])
+        # a row keeps for its backward its inputs, the selection and the
+        # kernel's output; its scores and the target are computed again
+        from ....ops.sparse_attention import KEEP
+        row = jax.checkpoint(
+            self._row,
+            policy=jax.checkpoint_policies.save_only_these_names(KEEP))
+        o, kl, n = jax.lax.map(lambda parts: row(*parts), parts)
+        out = o.reshape(B, T, -1) @ params["Wo"]
+        return out, {"layer_loss": jnp.sum(kl) / (B * T),
+                     "selected_keys": jnp.sum(n) / (B * T)}
+
+
+# ---------------------------------------------------------------------------
+# experts
+# ---------------------------------------------------------------------------
+@register_layer("moe")
+@dataclass
+class MoELayer(_StatefulSequenceLayer):
+    """Routes over `n_experts`, `experts_per_token` a token; holds experts
+    `first_held .. first_held + experts_held - 1` (all of them by default)
+    and computes their part of the result, every routed pair of it."""
+    n_in: int = None
+    n_out: int = None
+    n_experts: int = 128
+    experts_per_token: int = 8
+    expert_width: int = 768
+    norm_topk_prob: bool = True
+    experts_held: int = None
+    first_held: int = 0
+    init_std: float = 0.02
+
+    def _held(self):
+        return self.experts_held or self.n_experts
+
+    def init_state(self):
+        return {"held_pairs": jnp.zeros((self._held(),), jnp.float32),
+                "absent_pairs": jnp.zeros((), jnp.float32)}
+
+    def gauges(self, state):
+        held = state["held_pairs"]
+        return {"held_pairs_max": jnp.max(held),
+                "held_pairs_mean": jnp.mean(held),
+                "absent_pairs": state["absent_pairs"]}
+
+    def init_params(self, key, dtype=jnp.float32):
+        D, F, G = self.n_in, self.expert_width, self._held()
+        k = jax.random.split(key, 4)
+        mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        return {"Wr": mk(k[0], (D, self.n_experts)),
+                "Wg": mk(k[1], (G, D, F)), "Wu": mk(k[2], (G, D, F)),
+                "Wd": mk(k[3], (G, F, D))}
+
+    def forward_with_state(self, params, x, state, *, train=False, rng=None,
+                           mask=None):
+        from ....parallel.moe import held_experts_ffn, route_all
+        B, T, D = x.shape
+        tokens = x.reshape(B * T, D)
+        with jax.named_scope("router"):
+            experts, gates = route_all(params["Wr"], tokens,
+                                       self.experts_per_token,
+                                       self.norm_topk_prob)
+        y, counts = held_experts_ffn(
+            tokens, experts, gates, params["Wg"], params["Wu"], params["Wd"],
+            self.first_held, self.n_experts)
+        counts = counts.astype(jnp.float32)
+        return y.astype(x.dtype).reshape(B, T, D), {
+            "held_pairs": counts,
+            "absent_pairs": B * T * self.experts_per_token - jnp.sum(counts)}
+
+
+# ---------------------------------------------------------------------------
+# head
+# ---------------------------------------------------------------------------
+@register_layer("lmhead")
+@dataclass
+class LMHeadLayer(_SequenceLayer):
+    """Logits over `n_out` rows of a vocabulary; the loss is the mean
+    cross-entropy over the positions the label mask keeps (integer labels
+    [B, T]), whatever row they are in."""
+    n_in: int = None
+    n_out: int = None
+    token_chunk: int = 2048
+    init_std: float = 0.02
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"W": _normal(key, (self.n_in, self.n_out), self.init_std,
+                             dtype)}
+
+    def forward(self, params, x, *, train=False, rng=None, mask=None,
+                state=None):
+        return jnp.dot(x, params["W"], preferred_element_type=jnp.float32)
+
+    def compute_score_per_example(self, params, x, labels, *, train=False,
+                                  rng=None, mask=None):
+        B, T, D = x.shape
+        keep = (jnp.ones((B, T), jnp.float32) if mask is None
+                else mask.astype(jnp.float32))
+        C = min(self.token_chunk, T)
+        pad = -T % C
+        chunks = lambda a: jnp.moveaxis(jnp.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)).reshape(
+                (B, (T + pad) // C, C) + a.shape[2:]), 1, 0)
+
+        @jax.checkpoint
+        def chunk_loss(args):
+            xc, yc, mc = args
+            logits = jnp.dot(xc, params["W"],
+                             preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(
+                logits, yc.astype(jnp.int32)[..., None], -1)[..., 0]
+            return jnp.sum((jax.nn.logsumexp(logits, -1) - picked) * mc, -1)
+
+        per_row = jnp.sum(jax.lax.map(
+            chunk_loss, (chunks(x), chunks(labels), chunks(keep))), 0)
+        # the container takes the mean over rows: scale so that it is the
+        # mean over the kept positions of the whole batch
+        return per_row * (B / jnp.maximum(jnp.sum(keep), 1.0))

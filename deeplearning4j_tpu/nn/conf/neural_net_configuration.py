@@ -165,6 +165,12 @@ class NeuralNetConfiguration:
             """'float32' | 'bfloat16' (compute dtype; params stay float32)."""
             self.g["data_type"] = str(v); return self
 
+        def remat_segments(self, v=True):
+            """ComputationGraph: rematerialise the training forward in
+            segments bounded by the element-wise (residual-add) vertices
+            (see ComputationGraph.__init__)."""
+            self.g["remat_segments"] = bool(v); return self
+
         def updater_state_dtype(self, v):
             """Storage dtype for updater state (Adam m/v, momentum...).
             'bfloat16' halves optimizer HBM traffic; see

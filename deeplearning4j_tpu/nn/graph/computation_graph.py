@@ -49,8 +49,10 @@ class ComputationGraph:
         activations; workspace reuse is its only memory lever —
         WorkspaceMode in MultiLayerConfiguration.java)."""
         self.conf = conf
-        self._remat = bool(remat_segments)
         g = conf.global_conf
+        # the configuration may ask for it too (`remat_segments(True)` on
+        # the builder): a model that only fits rematerialised says so itself
+        self._remat = bool(remat_segments or g.get("remat_segments"))
         dt = str(g.get("data_type", "float32"))
         self.compute_dtype = {"bfloat16": jnp.bfloat16,
                               "float64": jnp.float64}.get(dt, jnp.float32)
@@ -204,11 +206,16 @@ class ComputationGraph:
                     out, c = layer.forward_with_carry(
                         p, x, carry_entry, train=train, rng=lrng, mask=m)
                     return out, None, c
+                # a layer wired to further inputs (positions, an image's
+                # embeddings) is handed them as `extras`
+                more = {"extras": in_acts[1:]} if len(in_acts) > 1 else {}
                 if layer.has_state():
                     out, st = layer.forward_with_state(
-                        p, x, state_entry, train=train, rng=lrng, mask=m)
+                        p, x, state_entry, train=train, rng=lrng, mask=m,
+                        **more)
                     return out, st, None
-                return (layer.forward(p, x, train=train, rng=lrng, mask=m),
+                return (layer.forward(p, x, train=train, rng=lrng, mask=m,
+                                      **more),
                         None, None)
             return (spec.conf.forward(in_acts, masks=in_masks, train=train,
                                       rng=lrng), None, None)
@@ -416,7 +423,13 @@ class ComputationGraph:
                 total = total + jnp.mean(per_ex)
         reg = 0.0
         for n in self._layer_names():
-            reg = reg + self.conf.vertices[n].conf.reg_score(params[n])
+            layer = self.conf.vertices[n].conf
+            reg = reg + layer.reg_score(params[n])
+            # a loss that depends on a layer's ACTIVATIONS (a sparse
+            # attention's indexer learns from the attention it serves):
+            # the layer returns it in its state, the score takes it
+            if layer.has_layer_loss:
+                total = total + new_state[n]["layer_loss"]
         return total + reg, (new_state, new_carries)
 
     # ------------------------------------------------------------------
@@ -527,6 +540,22 @@ class ComputationGraph:
             return (p, u, s, score, car, new_loop) + tuple(extras)
 
         return jax.jit(step, donate_argnums=(0, 1, 2, 3))
+
+    def publish_layer_gauges(self, registry=None):
+        """Set `<kind>.<vertex>.<name>` gauges from every layer's
+        `gauges(state)` (a `moe` layer's routed pairs, a `sparseattention`'s
+        selected keys a query and indexer loss), as the last step left
+        them. One host read; call it outside a timed window. Returns
+        {gauge name: value}."""
+        registry = registry or obs.default_registry()
+        out = {}
+        for n in self._layer_names():
+            layer = self.conf.vertices[n].conf
+            for k, v in layer.gauges(self._model_state[n]).items():
+                name = f"{layer.layer_type}.{n}.{k}"
+                out[name] = float(v)
+                registry.gauge(name).set(out[name])
+        return out
 
     def training_health(self, policy=True, checkpoint_dir=None,
                         checkpoint_every=10, keep_checkpoints=3):

@@ -1,16 +1,34 @@
-"""Mixture-of-Experts with expert parallelism over an "expert" mesh axis.
+"""Mixture-of-Experts: two expert layers, one that drops and one that does not.
 
-The reference has NO MoE / expert parallelism (SURVEY.md §2.5 marks EP as
+**`moe_mlp_sharded` / `moe_mlp_dense` (GeLU experts with biases, top-1 or
+top-2, FIXED CAPACITY, overflow DROPPED)**: expert parallelism over an
+"expert" mesh axis. The reference has NO MoE (SURVEY.md §2.5 marks EP as
 absent/optional) — this is a TPU-first extension: top-1 routing with raw
 router-prob gates (the Switch Transformer recipe) or top-k with
-renormalized combine weights (GShard/Mixtral, ``k=2``), fixed expert
-capacity, and an ``lax.all_to_all`` token shuffle over ICI so each device
-hosts exactly one (or E/devices) expert's FFN. The dense einsum path
-(`moe_mlp_dense`) is the single-chip reference implementation the sharded
-path is tested against, at every k.
+renormalized combine weights (GShard/Mixtral, ``k=2``), and an
+``lax.all_to_all`` token shuffle over ICI so each device hosts exactly one
+(or E/devices) expert's FFN. The `[E, C, D]` send buffer's static capacity
+is what makes the exchange one `all_to_all`; units past it are dropped. The
+dense einsum path (`moe_mlp_dense`) is the single-chip reference the sharded
+path is tested against, at every k. Used by `models/zoo/transformer.py`
+`make_moe_block_fn` (the pipeline/EP trainer of `examples/three_axis_mesh.py`
+and `__graft_entry__.dryrun_multichip`), never by the containers.
 
-Shapes: tokens [B, D]; E experts, capacity C per (source device, expert).
-Dispatch (per device, inside shard_map over axis "expert"):
+**`held_experts_ffn` (gated SiLU experts without biases, top-k of all E,
+NO CAPACITY, NOTHING DROPPED)**: one chip's share of an expert-parallel
+layer. It routes over all E experts, is told which contiguous range it
+holds, and computes every routed (token, choice) pair of a held expert:
+pairs sorted by expert, the three products as `lax.ragged_dot` over the
+ragged groups, in row blocks of static size of which only those that hold a
+pair run. It has no exchange and nothing that stands in for the absent
+chips: what their experts would add is left out. Used by the `moe` layer
+kind of the containers (`nn/conf/layers/decoder.py`,
+`models/zoo/keye_vl.py`). Two remain because the first one's fixed-capacity
+buffers ARE its exchange format (and its tests pin the drop rule), while a
+trainer at the sizes of a 128-expert model may not drop.
+
+Shapes (first layer): tokens [B, D]; E experts, capacity C per (source
+device, expert). Dispatch (per device, inside shard_map over axis "expert"):
 
   1. gate logits -> top-k experts + combine weights per token
   2. each (token, choice) dispatch unit scatters into a [E, C, D] send
@@ -223,3 +241,81 @@ def shard_moe_params(params, mesh, axis="expert"):
         spec = P() if k == "gate" else P(axis)
         out[k] = put_sharded(v, NamedSharding(mesh, spec), full_array=True)
     return out
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel layer: no capacity, nothing dropped
+# ---------------------------------------------------------------------------
+def route_all(router_w, x, k, norm_topk=True):
+    """Softmax over ALL experts in float32, the top k of it, and the combine
+    weights (`norm_topk`: divided by the sum over all k winners, held here
+    or not). Returns (experts [N, k] int32, gates [N, k] float32)."""
+    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    top_p, experts = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    return experts, top_p
+
+
+def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
+                     n_experts=None, block_rows=None):
+    """y[t] = sum over the routed pairs (t, e) with e held here of
+    gates[t, e] * W_down,e(SiLU(W_gate,e x[t]) * W_up,e x[t]).
+
+    x [N, D]; experts/gates [N, k] from `route_all`; w_gate/w_up [G, D, F],
+    w_down [G, F, D] are experts first_held .. first_held + G - 1. EVERY
+    pair of a held expert is computed, however the routing is skewed: the
+    N*k pairs are sorted by expert (absent experts last) and walked in
+    blocks of `block_rows` rows of static shape; a block runs only if a
+    held pair lies in it, so the work follows the routing and the memory is
+    one block's. By default a block is 1.25 times the held experts' expected
+    share of the pairs (N k G / `n_experts`): an even router fills one block
+    and every further block is skipped, at the cost, forward and backward,
+    of passing the carried sums through (each skipped block of the backward
+    still adds a zero gradient the size of the held weights: few blocks
+    matter more than small ones). Returns (y [N, D] float32, pairs of each
+    held expert [G]).
+    """
+    N, D = x.shape
+    k = experts.shape[1]
+    G = w_gate.shape[0]
+    local = experts.reshape(-1) - first_held
+    key = jnp.where((local >= 0) & (local < G), local, G)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.bincount(key, length=G + 1)[:G].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    n_held = ends[-1]
+    starts = ends - counts
+    if block_rows is None:
+        share = N * k * G / (n_experts or G)
+        block_rows = -(-int(1.25 * share) // 512) * 512
+    rows = min(int(block_rows), N * k)
+    n_blocks = -(-(N * k) // rows)
+    order = jnp.pad(order, (0, n_blocks * rows - N * k))
+    gate_flat = gates.reshape(-1)
+
+    def block(y, i):
+        lo = i * rows
+
+        def run(y):
+            pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            valid = (lo + jnp.arange(rows) < n_held)[:, None]
+            tok = pair // k
+            sizes = (jnp.clip(ends, lo, lo + rows)
+                     - jnp.clip(starts, lo, lo + rows))
+            # rows past the last group are not the kernel's to define
+            rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
+                a, w, sizes, preferred_element_type=jnp.float32), 0.0)
+            xs = jnp.where(valid, x[tok], 0)
+            h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(x.dtype)
+            out = rd(h, w_down) * jnp.where(valid[:, 0], gate_flat[pair],
+                                            0.0)[:, None]
+            return y.at[tok].add(out)
+
+        return jax.lax.cond(lo < n_held, run, lambda y: y, y), None
+
+    with jax.named_scope("experts"):
+        y, _ = jax.lax.scan(jax.checkpoint(block),
+                            jnp.zeros((N, D), jnp.float32),
+                            jnp.arange(n_blocks, dtype=jnp.int32))
+    return y, counts
