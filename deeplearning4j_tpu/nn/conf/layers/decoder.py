@@ -170,11 +170,14 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
 
     def init_state(self):
         return {"layer_loss": jnp.zeros((), jnp.float32),
-                "selected_keys": jnp.zeros((), jnp.float32)}
+                "selected_keys": jnp.zeros((), jnp.float32),
+                "attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
 
     def gauges(self, state):
         return {"selected_keys_per_query": state["selected_keys"],
-                "indexer_loss": state["layer_loss"]}
+                "indexer_loss": state["layer_loss"],
+                "attend_grid_steps_per_tile":
+                    state["attend_grid_steps_per_tile"]}
 
     def init_params(self, key, dtype=jnp.float32):
         D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
@@ -259,14 +262,17 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
         parts = self.project(params, x, extras[0])
         # a row keeps for its backward its inputs, the selection and the
         # kernel's output; its scores and the target are computed again
-        from ....ops.sparse_attention import KEEP
+        from ....ops.sparse_attention import KEEP, grid_steps_per_tile
         row = jax.checkpoint(
             self._row,
             policy=jax.checkpoint_policies.save_only_these_names(KEEP))
         o, kl, n = jax.lax.map(lambda parts: row(*parts), parts)
         out = o.reshape(B, T, -1) @ params["Wo"]
+        # the kernels' schedule is a function of T: a constant of the trace
         return out, {"layer_loss": jnp.sum(kl) / (B * T),
-                     "selected_keys": jnp.sum(n) / (B * T)}
+                     "selected_keys": jnp.sum(n) / (B * T),
+                     "attend_grid_steps_per_tile": jnp.float32(
+                         grid_steps_per_tile(T))}
 
 
 # ---------------------------------------------------------------------------

@@ -22,10 +22,18 @@ chosen by the block index map, nothing is repeated in HBM.
   `lax.top_k` at k = 2048 of 8192 is a full sort on this chip, 2.7 ms for
   512 rows, and was 30% of the step.
 
-Key blocks wholly after a query block are skipped (the mask is causal);
-inside the causal part every tile is computed, whatever its density.
-`work_keye.py` of the benchmark counts the SELECTED pairs only, so a
-roofline share read from these kernels counts the masked-out work as waste.
+The mask is causal, so `masked_attention`'s three kernels walk only the
+(query block, key block) tiles with a pair on or under the diagonal: their
+grid is (batch x head, step), and `tile_schedule` lists each step's tile in
+int32 tables that `pltpu.PrefetchScalarGridSpec` hands to the index maps and
+to the kernel (a run's first step clears the accumulators, its last writes
+the result). No step is empty and no block is fetched unused; before PR 29
+the grid was the rectangle and 47% of its steps failed a `pl.when`.
+`head_summed_probs` keeps the rectangle: its skipped steps write the zeros
+of a full [T, T] result. Inside the causal part every tile is computed,
+whatever its density. `work_keye.py` of the benchmark counts the SELECTED
+pairs only, so a roofline share read from these kernels counts the
+masked-out work, and the diagonal tiles' upper halves, as waste.
 """
 from __future__ import annotations
 
@@ -33,6 +41,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -42,6 +51,13 @@ from .flash_attention import _divisor_block, _resolve_interpret
 KEEP = "sparse_attention"    # checkpoint name of what a backward needs kept
 NEG = -1e30      # a masked score; finite, so a row that has met no key yet
 #                  (its first tiles all masked) folds without NaN
+FLOOR = -1e20    # where the forward's running maximum starts: under every
+#                  real score and so far above NEG that exp(NEG - m) is 0 by
+#                  itself; a row that has met no key keeps l = 0
+BLOCK = 1024     # query and key block of masked_attention's three kernels:
+#                  the fastest of 256 .. 2048 a side on the chip at T = 8192,
+#                  d = 128 (PERF.md section 6, PR 29; the scoped VMEM default
+#                  holds its 4 MB float32 tiles)
 
 
 def _params(interpret, semantics):
@@ -52,35 +68,82 @@ def _params(interpret, semantics):
 def _scores(q_ref, k_ref, mask_ref, scale):
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    keep = mask_ref[0].astype(jnp.int32) != 0
-    return jnp.where(keep, s, NEG), keep
+    return jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG)
+
+
+# ----------------------------------------------------------- tile schedule
+FIRST, LAST = 1, 2      # bits of a step's `edge`: its run's first, its last
+
+
+def tile_schedule(T, bq, bk, heads=1):
+    """The tiles that `masked_attention`'s kernels visit at query blocks of
+    `bq` and key blocks of `bk`: those with a pair on or under the diagonal,
+    each once, and no other. `grid_steps` is the length of the kernels'
+    second grid axis a head (the first walks batch x head), and
+    `computing_steps` how many of those steps hold such a pair: all of
+    them. The tables are int32 numpy arrays, one entry a step:
+
+    - `by_query` (i, j, edge): query block by query block, its key blocks
+      0 .. last(i) in order (forward, dQ: one run a query block);
+    - `by_key` (j, r, i, edge): key block by key block, inside it head r of
+      `heads` (a key/value head's group of query heads), inside that the
+      query blocks first(j) .. nq-1 (dK/dV: one run a key block, so the sum
+      over the group's heads stays in VMEM).
+
+    `edge` marks a run's first step (FIRST: clear the accumulators) and its
+    last (LAST: write the result)."""
+    nq, nk = T // bq, T // bk
+    last = lambda i: (i * bq + bq - 1) // bk     # the last key block i sees
+    first = lambda j: (j * bk) // bq             # the first that sees j
+    by_query = [(i, j, FIRST * (j == 0) | LAST * (j == last(i)))
+                for i in range(nq) for j in range(last(i) + 1)]
+    by_key = [(j, r, i, FIRST * (r == 0 and i == first(j))
+               | LAST * (r == heads - 1 and i == nq - 1))
+              for j in range(nk) for r in range(heads)
+              for i in range(first(j), nq)]
+    cols = lambda rows: tuple(np.asarray(c, np.int32) for c in zip(*rows))
+    return {"grid_steps": len(by_query),
+            "computing_steps": sum(j * bk <= i * bq + bq - 1
+                                   for i, j, _ in by_query),
+            "by_query": cols(by_query), "by_key": cols(by_key)}
+
+
+def _call(kernel, tables, grid, in_specs, out_specs, out_shape, scratch,
+          name, interpret, operands):
+    """One kernel over (batch x head, the steps of `tables`): the tables
+    are prefetched scalars, read by the index maps and by the kernel."""
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, interpret=interpret, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        **_params(interpret, ("parallel", "arbitrary")))(
+            *(jnp.asarray(t) for t in tables), *operands)
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
-                acc_ref, *, scale, bq, bk):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                lse_ref, m_ref, l_ref, acc_ref, *, scale):
+    e = edge[pl.program_id(1)]
 
-    @pl.when(j == 0)
+    @pl.when(e & FIRST != 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG)
+        m_ref[:] = jnp.full_like(m_ref, FLOOR)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * bk <= i * bq + bq - 1)
-    def _step():
-        s, keep = _scores(q_ref, k_ref, mask_ref, scale)
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_cur
+    s = _scores(q_ref, k_ref, mask_ref, scale)
+    m_prev = m_ref[:, :1]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)       # a masked pair: exp(NEG - m_cur) is 0
+    l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:, :1] = m_cur
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(e & LAST != 0)
     def _emit():
         l_fin = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l_fin).astype(o_ref.dtype)
@@ -88,18 +151,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
 
 
 def _specs(bq, bk, d, R, H):
-    """Block specs of a (batch * head, query block i, key block j) grid:
-    q-shaped, k/v-shaped (the group's head), the mask's tile, a per-row
-    column. A key block wholly after the query block is not fetched: the
-    index stays at the last block the queries see."""
+    """Block specs of a (batch * head b, step t) grid whose step t is tile
+    (i_tab[t], j_tab[t]) of `tile_schedule`'s `by_query`: q-shaped,
+    k/v-shaped (the group's head), the mask's tile, a per-row column. No
+    step lies above the diagonal, so nothing is fetched that is not used;
+    a block whose index the next step keeps is not fetched again."""
     vm = {"memory_space": pltpu.VMEM}
-    seen = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
-    return (pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **vm),
+    return (pl.BlockSpec((1, bq, d), lambda b, t, i, j, e: (b, i[t], 0), **vm),
             pl.BlockSpec((1, bk, d),
-                         lambda b, i, j: (b // R, seen(i, j), 0), **vm),
+                         lambda b, t, i, j, e: (b // R, j[t], 0), **vm),
             pl.BlockSpec((1, bq, bk),
-                         lambda b, i, j: (b // H, i, seen(i, j)), **vm),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), **vm))
+                         lambda b, t, i, j, e: (b // H, i[t], j[t]), **vm),
+            pl.BlockSpec((1, bq, 1), lambda b, t, i, j, e: (b, i[t], 0), **vm))
 
 
 def _fwd(q, k, v, mask, scale, bq, bk, interpret):
@@ -107,73 +170,63 @@ def _fwd(q, k, v, mask, scale, bq, bk, interpret):
     BH, T, d = q.shape
     q_spec, kv_spec, mask_spec, row_spec = _specs(
         bq, bk, d, BH // k.shape[0], BH // mask.shape[0])
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk),
-        grid=(BH, T // bq, T // bk),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, d), q.dtype),
-                   jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret, name="sparse_attention_fwd",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
-            q, k, v, mask)
+    sched = tile_schedule(T, bq, bk)
+    return _call(
+        functools.partial(_fwd_kernel, scale=scale), sched["by_query"],
+        (BH, sched["grid_steps"]), [q_spec, kv_spec, kv_spec, mask_spec],
+        [q_spec, row_spec],
+        [jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+         jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
+        [pltpu.VMEM((bq, 128), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32),
+         pltpu.VMEM((bq, d), jnp.float32)],
+        "sparse_attention_fwd", interpret, (q, k, v, mask))
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, acc_ref, *, scale, bq, bk):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, mask_ref, do_ref,
+               lse_ref, delta_ref, dq_ref, acc_ref, *, scale):
+    e = edge[pl.program_id(1)]
 
-    @pl.when(j == 0)
+    @pl.when(e & FIRST != 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * bk <= i * bq + bq - 1)
-    def _step():
-        s, _ = _scores(q_ref, k_ref, mask_ref, scale)
-        p = jnp.exp(s - lse_ref[0])
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale) - lse_ref[0])
+    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    acc_ref[:] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(e & LAST != 0)
     def _emit():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq, bk, nq):
-    j, t = pl.program_id(1), pl.program_id(2)
-    i = t % nq                      # t walks the group's heads, then blocks
+def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, mask_ref,
+                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                scale):
+    e = edge[pl.program_id(1)]
 
-    @pl.when(t == 0)
+    @pl.when(e & FIRST != 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i * bq + bq - 1 >= j * bk)
-    def _step():
-        s, _ = _scores(q_ref, k_ref, mask_ref, scale)
-        p = jnp.exp(s - lse_ref[0])
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale) - lse_ref[0])
+    dv_acc[:] += jax.lax.dot_general(
+        p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    dk_acc[:] += jax.lax.dot_general(
+        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
 
-    @pl.when(t == pl.num_programs(2) - 1)
+    @pl.when(e & LAST != 0)
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -183,45 +236,40 @@ def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret):
     BH, T, d = q.shape
     BKV = k.shape[0]
     H, R, KV = BH // mask.shape[0], BH // BKV, BKV // mask.shape[0]
-    nq = T // bq
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
                     keepdims=True)
     vm = {"memory_space": pltpu.VMEM}
+    operands = (q, k, v, mask, do, lse, delta)
     q_spec, kvq_spec, mask_spec, row_spec = _specs(bq, bk, d, R, H)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk),
-        grid=(BH, nq, T // bk),
-        in_specs=[q_spec, kvq_spec, kvq_spec, mask_spec, q_spec, row_spec,
-                  row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret, name="sparse_attention_dq",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
-            q, k, v, mask, do, lse, delta)
-    # key block j of group g; inside, head r of the group and query block i
-    first = lambda j: (j * bk) // bq        # query blocks before it see none
-    qi = lambda j, t: jnp.maximum(t % nq, first(j))
+    sched = tile_schedule(T, bq, bk)
+    dq = _call(
+        functools.partial(_dq_kernel, scale=scale), sched["by_query"],
+        (BH, sched["grid_steps"]),
+        [q_spec, kvq_spec, kvq_spec, mask_spec, q_spec, row_spec, row_spec],
+        q_spec, jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+        [pltpu.VMEM((bq, d), jnp.float32)], "sparse_attention_dq", interpret,
+        operands)
+    # step t of key/value head g: key block j[t], head r[t] of g's group,
+    # query block i[t] (`tile_schedule`'s `by_key`)
+    sched = tile_schedule(T, bq, bk, heads=R)
     qh_spec = pl.BlockSpec(
-        (1, bq, d), lambda g, j, t: (g * R + t // nq, qi(j, t), 0), **vm)
+        (1, bq, d), lambda g, t, j, r, i, e: (g * R + r[t], i[t], 0), **vm)
     rowh_spec = pl.BlockSpec(
-        (1, bq, 1), lambda g, j, t: (g * R + t // nq, qi(j, t), 0), **vm)
-    kv_spec = pl.BlockSpec((1, bk, d), lambda g, j, t: (g, j, 0), **vm)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk, nq=nq),
-        grid=(BKV, T // bk, R * nq),
-        in_specs=[qh_spec, kv_spec, kv_spec,
-                  pl.BlockSpec((1, bq, bk),
-                               lambda g, j, t: (g // KV, qi(j, t), j), **vm),
-                  qh_spec, rowh_spec, rowh_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret, name="sparse_attention_dkv",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
-            q, k, v, mask, do, lse, delta)
+        (1, bq, 1), lambda g, t, j, r, i, e: (g * R + r[t], i[t], 0), **vm)
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda g, t, j, r, i, e: (g, j[t], 0), **vm)
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, scale=scale), sched["by_key"],
+        (BKV, R * sched["grid_steps"]),
+        [qh_spec, kv_spec, kv_spec,
+         pl.BlockSpec((1, bq, bk),
+                      lambda g, t, j, r, i, e: (g // KV, i[t], j[t]), **vm),
+         qh_spec, rowh_spec, rowh_spec],
+        [kv_spec, kv_spec],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
+        "sparse_attention_dkv", interpret, operands)
     return dq, dk, dv
 
 
@@ -230,17 +278,26 @@ def _blocks(T, block_q, block_k):
     return _divisor_block(T, block_q), _divisor_block(T, block_k)
 
 
+def grid_steps_per_tile(T, block_q=BLOCK, block_k=BLOCK):
+    """Grid steps over computing steps of `masked_attention`'s kernels at T:
+    1.0 when no step is empty (PR 28's rectangular grid of 512 x 512 read
+    256 / 136 = 1.88 at T = 8192)."""
+    sched = tile_schedule(T, *_blocks(T, block_q, block_k))
+    return sched["grid_steps"] / sched["computing_steps"]
+
+
 def _flat(a):
     return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def masked_attention(q, k, v, mask, scale, block_q=512, block_k=512,
+def masked_attention(q, k, v, mask, scale, block_q=BLOCK, block_k=BLOCK,
                      interpret=None):
     """softmax over the keys `mask` keeps of q k^T * scale, times v.
     q [B, H, T, d]; k, v [B, KV, T, d] (H a multiple of KV); mask int8
     [B, T, T], nonzero where query t reads key s, causal (s <= t) and with
-    at least one key a query. Returns (o [B, H, T, d], lse [B, H, T] f32)."""
+    at least one key a query. A T no longer than a block is one tile.
+    Returns (o [B, H, T, d], lse [B, H, T] f32)."""
     return _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret)[0]
 
 

@@ -1,0 +1,172 @@
+"""One call of each masked-attention kernel (ops/sparse_attention.py: forward,
+dQ, dK/dV) timed alone on the chip at a cell's shape, block shape by block
+shape: the numbers PERF.md gives for "a call", and what the module's `BLOCK`
+was chosen from. One JSON line a (kernel, block shape).
+
+    chiprun -- python tools/attend_kernel_times.py                  # this tree
+    chiprun -- python tools/attend_kernel_times.py \\
+        --module .chip_tree/parent/deeplearning4j_tpu/ops/sparse_attention.py
+
+`--module` times another checkout's kernels in the same process (a parent
+unpacked under .chip_tree/). `--compile-only` compiles every shape for a
+described v5e with no chip attached and times nothing (what Mosaic refuses,
+it refuses here). A time comes from a TPU or not at all.
+"""
+import argparse
+import importlib.util
+import json
+import os
+import statistics
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax                                                        # noqa: E402
+import jax.numpy as jnp                                           # noqa: E402
+
+# one sequence of keye-vl-2.0-30b-a3b's attention, as a layer's row calls it
+HEADS, KV, D, TOPK = 32, 4, 128, 2048
+REPS, SETS = 20, 5      # calls a timing, timings a median
+
+
+def load(path):
+    if path is None:
+        from deeplearning4j_tpu.ops import sparse_attention
+        return sparse_attention
+    # under the package's name, so that its relative imports resolve
+    spec = importlib.util.spec_from_file_location(
+        "deeplearning4j_tpu.ops.sparse_attention_at_" + str(abs(hash(path))),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def kernels(mod, a):
+    """(name, bq, bk, function of (q, k, v, mask, o, lse, do)) of every
+    kernel and block shape asked for, one kernel a function: a result
+    nobody reads takes its kernel out of the program."""
+    for block in a.blocks.split(","):
+        bq, bk = (int(x) for x in block.split("x"))
+        for name, fn in one_each(mod, D ** -0.5, bq, bk).items():
+            if name in a.kernels.split(","):
+                yield name, bq, bk, jax.jit(fn)
+
+
+def one_each(mod, scale, bq, bk):
+    flat = mod._flat
+
+    def bwd(q, k, v, mask, o, lse, do):
+        B, H, T, _ = q.shape
+        return mod._bwd(flat(q), flat(k), flat(v), mask, flat(o),
+                        lse.reshape(B * H, T, 1), flat(do), scale, bq, bk,
+                        False)
+
+    return {
+        "fwd": lambda q, k, v, mask, o, lse, do: mod._fwd(
+            flat(q), flat(k), flat(v), mask, scale, bq, bk, False),
+        "dq": lambda *a: bwd(*a)[0],
+        "dkv": lambda *a: bwd(*a)[1:]}
+
+
+def shapes(a, sharding=None):
+    B, H, T, d = 1, HEADS, a.T, D
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    bf = jnp.bfloat16
+    return (s((B, H, T, d), bf), s((B, KV, T, d), bf), s((B, KV, T, d), bf),
+            s((B, T, T), jnp.int8), s((B, H, T, d), bf),
+            s((B, H, T), jnp.float32), s((B, H, T, d), bf))
+
+
+def arrays(a, seed=0):
+    """Seeded inputs; the selection keeps `topk` random causal keys a query
+    (all of them where there are no more), the diagonal among them. The
+    kernels' time does not depend on it: every tile under the diagonal is
+    computed."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    sh = shapes(a)
+    q, k, v, do = (jax.random.normal(kk, s.shape, s.dtype)
+                   for kk, s in zip(ks, (sh[0], sh[1], sh[2], sh[6])))
+    t = jnp.arange(a.T)
+    u = jax.random.uniform(ks[4], (a.T, a.T))
+    keep = (t[None, :] <= t[:, None]) & (
+        (u * (t[:, None] + 1) < TOPK) | (t[None, :] == t[:, None]))
+    return q, k, v, keep.astype(jnp.int8)[None], do
+
+
+def device_ms(fn, operands):
+    """The kernel's own time on the device's operation line, a call: the
+    median of REPS traced calls' `sparse_attention_*` events (the host's
+    clock above adds the dispatch and, for dQ, the row sums' fusion)."""
+    import tempfile
+    from deeplearning4j_tpu.optimize.profiler import _device_ops, trace
+    with tempfile.TemporaryDirectory() as logdir:
+        with trace(logdir):
+            for _ in range(REPS):
+                out = fn(*operands)
+            jax.block_until_ready(out)
+        ms = [t for name, t in _device_ops(logdir)
+              if "sparse_attention" in name]
+    return statistics.median(ms) if ms else None
+
+
+def main():
+    p = argparse.ArgumentParser()
+    p.add_argument("--module", default=None)
+    p.add_argument("--blocks", default="512x512,1024x512,512x1024,1024x1024")
+    p.add_argument("--kernels", default="fwd,dq,dkv")
+    p.add_argument("--T", type=int, default=8192)
+    p.add_argument("--compile-only", action="store_true")
+    a = p.parse_args()
+    mod = load(a.module)
+    say = lambda **kw: print(json.dumps(
+        {"module": a.module or "this tree", "T": a.T, **kw}), flush=True)
+
+    if a.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sh = shapes(a, SingleDeviceSharding(topo.devices[0]))
+        for name, bq, bk, fn in kernels(mod, a):
+            try:
+                fn.lower(*sh).compile()
+                say(kernel=name, bq=bq, bk=bk, compiled=True)
+            except Exception as e:                  # the compiler's refusal
+                say(kernel=name, bq=bq, bk=bk, compiled=False,
+                    error=str(e).splitlines()[0][:300])
+        return 0
+
+    if jax.default_backend() != "tpu":
+        print(f"found platform {jax.default_backend()!r}, not a TPU; "
+              "refusing to measure", file=sys.stderr)
+        return 4
+    q, k, v, mask, do = arrays(a)
+    o, lse = jax.jit(lambda *x: mod.masked_attention(*x, D ** -0.5))(
+        q, k, v, mask)
+    operands = (q, k, v, mask, o, lse, do)
+    for name, bq, bk, fn in kernels(mod, a):
+        try:
+            jax.block_until_ready(fn(*operands))
+        except Exception as e:
+            say(kernel=name, bq=bq, bk=bk, error=str(e).splitlines()[0][:300])
+            continue
+        ms = []
+        for _ in range(SETS):
+            t0 = time.perf_counter()
+            for _ in range(REPS):
+                out = fn(*operands)
+            jax.block_until_ready(out)
+            ms.append((time.perf_counter() - t0) / REPS * 1e3)
+        say(kernel=name, bq=bq, bk=bk, ms=statistics.median(ms),
+            ms_min=min(ms), ms_max=max(ms),
+            device_ms=device_ms(fn, operands),
+            device=jax.devices()[0].device_kind)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
