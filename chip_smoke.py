@@ -82,8 +82,8 @@ FULL = dict(
                prefix_blocks=8, n_shared=28, suffix=(1, 200), new=(8, 33),
                short_prompts=(5, 20, 50, 61), ref_prefill=48),
     lm_train=dict(batch=8, seq=1024, steps=3),
-    # bench.py's flash shape, and the same at the 128-wide heads every model
-    # in ROADMAP Queue 2 has
+    # a long-context flash shape, and the same at the 128-wide heads every
+    # model in ROADMAP Queue 2 has
     kernels=(dict(B=4, T=8192, H=8, D=64), dict(B=2, T=8192, H=8, D=128)),
     multichip=dict(batch=128, hw=224, classes=1000, learning_rate=0.01),
 )

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives — one rule, used by every
-entry script that initialises a backend (`chip_smoke.py`, `bench.py`
-children, `tools/*`, `--replica-serve` children, `tests/conftest.py`).
+entry script that initialises a backend (`chip_smoke.py`, `benchmarks/run.py`,
+`tools/*`, `--replica-serve` children, `tests/conftest.py`).
 
 If `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and nothing is
 set in code: whoever runs the program decides where compiled programs

@@ -350,8 +350,8 @@ def apply_policy(policy, health, round_index, rollback=None):
 def install(net, policy=True, checkpoint_dir=None, checkpoint_every=10,
             keep_checkpoints=3):
     """Arm (or disarm) the training-health watchdog on a network — the one
-    implementation behind MultiLayerNetwork.training_health and
-    ComputationGraph.training_health.
+    implementation behind the containers' `training_health`
+    (nn/trainer.py) and ParallelWrapper's `health_policy`.
 
     policy: a TrainingHealthPolicy, True for the defaults, or None/False
     to disarm. checkpoint_dir (optional) gives the single-process fit
@@ -372,7 +372,7 @@ def install(net, policy=True, checkpoint_dir=None, checkpoint_every=10,
         policy = None
     armed = policy is not None
     net._health_policy = policy
-    net._health_gen = getattr(net, "_health_gen", 0) + 1
+    net._health_gen += 1
     net._jit_step = None                 # recompile with/without health
     net._health_ckpt = None
     net._health_ckpt_every = max(1, int(checkpoint_every))
@@ -392,7 +392,7 @@ def finish_step(net, health, score):
     already restored and the caller must abandon the current
     batch/sequence; ABORT raises TrainingDivergedError."""
     rollback = None
-    if getattr(net, "_health_ckpt", None) is not None:
+    if net._health_ckpt is not None:
         def rollback():
             return fit_loop_rollback(net)
     action = apply_policy(net._health_policy, health,
@@ -466,7 +466,7 @@ def fit_loop_rollback(net):
     checkpoint INTO the net (counters, rng and device loop state
     included). Returns the restored round (iteration) number, or False
     when no checkpoint exists yet."""
-    mgr = getattr(net, "_health_ckpt", None)
+    mgr = net._health_ckpt
     if mgr is None or mgr.latest_step() is None:
         return False
     last = mgr.latest_step()
@@ -477,7 +477,7 @@ def fit_loop_rollback(net):
 def fit_loop_checkpoint(net):
     """Periodic save for the fit-loop seam: checkpoint the full training
     state at the current iteration count when due."""
-    mgr = getattr(net, "_health_ckpt", None)
+    mgr = net._health_ckpt
     if mgr is None:
         return
     it = int(net.conf.iteration_count)

@@ -20,8 +20,10 @@ import jax.numpy as jnp
 
 import jax
 
+from ... import activations
 from ..input_type import ConvolutionalInputType, FeedForwardInputType, InputType
-from .base import LayerConf, register_layer
+from .base import LayerConf, layer_scope, register_layer
+from .convolution import ConvolutionLayer
 
 
 def _bn_train_stats(x, gamma, beta, eps, axes, fast_var):
@@ -107,8 +109,8 @@ def _conv1x1_bn_train_fused(eps, fast_var, stride):
         dgamma = s2*rstd ;  dbeta = s1
     and the residuals are (a, W, gamma, mean, rstd): x is dead after the
     forward. G costs n*Cin^2 multiply-adds, under the convolution's own
-    n*Cin*Cout while Cout > Cin, which is where the container engages this
-    (`ComputationGraph._convbn_plan`). Operands stay in the compute dtype;
+    n*Cin*Cout while Cout > Cin, which is where `convbn_pairs` engages this.
+    Operands stay in the compute dtype;
     the sums, A1, G and all [C,C] algebra are >= f32.
 
     f(a, w, gamma, beta) -> (y, mean, var) with a [N,H,W,Cin] and w
@@ -174,6 +176,62 @@ def _conv1x1_bn_train_fused(eps, fast_var, stride):
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def convbn_pairs(conf):
+    """{batch-norm vertex: convolution vertex} for every pair of a graph
+    configuration that the training forward computes as one function
+    (`_conv1x1_bn_train_fused`: a backward that never reads the
+    convolution's output; the step is HBM-bound). The rule: an EXPANDING
+    1x1 convolution (n_out > n_in: the output is the wide tensor, and the
+    backward's extra Cin x Cin product stays under the convolution's own
+    work) with no bias, no padding, identity activation and no input
+    dropout, whose only consumer is a batch norm with learned scale and
+    shift, the fused backward and no preprocessor. Every other convolution
+    and batch norm runs its own layer's code."""
+    verts = conf.vertices
+    consumers = {}
+    for name, spec in verts.items():
+        for i in spec.inputs:
+            consumers.setdefault(i, []).append(name)
+    plan = {}
+    for name, spec in verts.items():
+        bn = spec.conf
+        cspec = verts.get(spec.inputs[0]) if spec.inputs else None
+        if (type(bn) is not BatchNormalization or cspec is None
+                or type(cspec.conf) is not ConvolutionLayer):
+            continue
+        conv = cspec.conf
+        if (conv.kernel_size == (1, 1) and not conv.has_bias
+                and (conv.padding == (0, 0)
+                     or str(conv.convolution_mode).lower() == "same")
+                and (activations.get(conv.activation)
+                     is activations.identity)
+                and not conv.dropout
+                and conv.n_out > conv.n_in
+                and consumers[cspec.name] == [name]
+                and cspec.name not in conf.network_outputs
+                and spec.preprocessor is None
+                and bn.fused_backward and not bn.lock_gamma_beta):
+            plan[name] = cspec.name
+    return plan
+
+
+def convbn_pair_forward(spec, p, state, cspec, cp, a, *, cast):
+    """The training forward of the batch norm `spec` of a planned pair, as
+    one function of its convolution's (`cspec`, parameters `cp`) INPUT `a`,
+    under the convolution's scope (its backward is convolution work); the
+    running statistics under the batch norm's. `cast` is the container's
+    cast of stored parameters to the compute dtype. Returns (y, state')."""
+    with layer_scope(cspec.conf, cspec.name):
+        if cspec.preprocessor is not None:
+            a = cspec.preprocessor.pre_process(a)
+        p = cast(p)
+        y, mean, var = _conv1x1_bn_train_fused(
+            spec.conf.eps, spec.conf.use_fast_variance, cspec.conf.stride)(
+                a, cast(cp)["W"], p["gamma"], p["beta"])
+    with layer_scope(spec.conf, spec.name):
+        return y, spec.conf.running_stats(state, mean, var)
 
 
 @register_layer("batchnorm")

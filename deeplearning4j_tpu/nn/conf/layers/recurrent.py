@@ -73,8 +73,7 @@ class GravesLSTM(BaseRecurrentLayer):
     # lax.scan unroll factor: >1 lets XLA fuse several timesteps into one
     # loop body (fewer loop-carried DMA round trips on TPU, bigger fused
     # elementwise chains) at compile-time/code-size cost. Same math,
-    # different fusion — equivalent to float-reassociation tolerance;
-    # bench A/B `char_rnn_lstm_unroll` measures the win on chip.
+    # different fusion — equivalent to float-reassociation tolerance.
     # reference seam: LSTMHelpers.java:157-171 (the per-timestep loop
     # this scan replaces).
     scan_unroll: int = 1

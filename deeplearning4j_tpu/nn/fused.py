@@ -7,9 +7,8 @@ The reference's own answer was batching work behind one native call
 (AggregateSkipGram's batched pair kernel, ParallelWrapper's
 averaging-interval of local steps); `parallel/parallel_wrapper.py`
 already runs k local steps in one `lax.scan` program — this module gives
-the SINGLE-PROCESS fit loops (MultiLayerNetwork.fit /
-ComputationGraph.fit, the paths bench.py and every example actually
-exercise) the same shape:
+the SINGLE-PROCESS fit loops (the Trainer's, nn/trainer.py, behind
+MultiLayerNetwork.fit and ComputationGraph.fit) the same shape:
 
   * the fit loop stages K batches (the AsyncDataSetIterator machinery —
     prefetch thread, wire-dtype levers, device staging — unchanged),
@@ -64,7 +63,7 @@ def scan_steps(raw, params, ustate, state, loop, carries, xs, make_batch):
     def body(carry, x):
         params, ustate, state, loop, carries = carry
         # same per-step rng/iteration advance as the single-step program
-        # (see MultiLayerNetwork._make_step) — the stream is bit-identical
+        # (see Trainer._make_step) — the stream is bit-identical
         rng, next_rng = jax.random.split(loop["rng"])
         batch = make_batch(x)
         batch["iteration"] = loop["iteration"]
@@ -125,7 +124,7 @@ def group_size(net, k):
     a checkpoint seam — a due round's checkpoint must save the EXACT
     post-due-step state (which only exists at a dispatch boundary), and
     the cadence stays counted in optimizer steps, never stretched by K."""
-    if getattr(net, "_health_ckpt", None) is None:
+    if net._health_ckpt is None:
         return k
     every = net._health_ckpt_every
     done = int(net.conf.iteration_count) % every
@@ -133,12 +132,12 @@ def group_size(net, k):
 
 
 def install(net, k):
-    """The one implementation behind MultiLayerNetwork.fused_steps and
-    ComputationGraph.fused_steps: record K and invalidate the cached
+    """The implementation behind the containers' `fused_steps`
+    (nn/trainer.py): record K and invalidate the cached
     fused programs (the single-step program is untouched — fused_steps=1
     compiles the identical HLO as never-armed, pinned by test)."""
     k = max(1, int(k))
-    if k != getattr(net, "_fused_steps", 1):
+    if k != net._fused_steps:
         net._fused_steps = k
         net._fused_cache = None
     return net
@@ -149,9 +148,8 @@ def fused_program(net, key, builder):
     health watchdog or activation-stats mode toggles (the same
     generation counters ParallelWrapper watches)."""
     from .. import obs
-    gen = (getattr(net, "_health_gen", 0),
-           getattr(net, "_act_stats_gen", 0))
-    cache = getattr(net, "_fused_cache", None)
+    gen = (net._health_gen, net._act_stats_gen)
+    cache = net._fused_cache
     if cache is None or cache.get("gen") != gen:
         cache = {"gen": gen}
         net._fused_cache = cache

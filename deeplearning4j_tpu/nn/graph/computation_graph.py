@@ -6,11 +6,16 @@ backward replacing doBackward (:123), multi-input/multi-output with
 MultiDataSet, fit (:809), computeGradientAndScore (:952), flattened-params
 contract (:281-345).
 
-Same TPU-first redesign as MultiLayerNetwork: the whole training step
-(params, updater_state, model_state, batch) -> (params', ...) is ONE donated
-jit-compiled XLA program; the DAG structure is unrolled at trace time (the
-topological order is static), so XLA sees a flat fused computation regardless
-of graph shape.
+Same TPU-first redesign as MultiLayerNetwork, and the same Trainer
+(nn/trainer.py: the step, its loop state, the fit loops, the flattened-params
+contract): the whole training step (params, updater_state, model_state,
+batch) -> (params', ...) is ONE donated jit-compiled XLA program; the DAG
+structure is unrolled at trace time (the topological order is static), so XLA
+sees a flat fused computation regardless of graph shape. This file holds what
+a GRAPH of vertices is: the forward over the DAG (remat segments, the planned
+convolution -> batch-norm pairs, extra inputs), the loss over the output
+vertices with the layers' own losses, multi-input canonicalisation,
+`lower_step`, the layer gauges, inference and evaluation.
 """
 from __future__ import annotations
 
@@ -22,20 +27,16 @@ import numpy as np
 
 from ... import obs
 from ...datasets.dataset import DataSet, MultiDataSet
-from ...datasets.iterators import next_processed
-from .. import activations
 from ..conf.computation_graph_configuration import ComputationGraphConfiguration
-from ..conf.layers.base import LayerConf, layer_scope
-from ..conf.layers.convolution import ConvolutionLayer
-from ..conf.layers.normalization import (BatchNormalization,
-                                         _conv1x1_bn_train_fused)
+from ..conf.layers.base import layer_scope
+from ..conf.layers.normalization import convbn_pair_forward, convbn_pairs
 from ..conf.layers.recurrent import BaseRecurrentLayer
-from ..updater import updaters as U
+from ..trainer import Trainer
 
 log = logging.getLogger(__name__)
 
 
-class ComputationGraph:
+class ComputationGraph(Trainer):
     def __init__(self, conf: ComputationGraphConfiguration,
                  remat_segments=False):
         """remat_segments=True: gradient-checkpoint the graph in segments
@@ -48,26 +49,16 @@ class ComputationGraph:
         (pinned by test). The reference has no equivalent (it stores all
         activations; workspace reuse is its only memory lever —
         WorkspaceMode in MultiLayerConfiguration.java)."""
-        self.conf = conf
-        g = conf.global_conf
+        super().__init__(conf)
         # the configuration may ask for it too (`remat_segments(True)` on
         # the builder): a model that only fits rematerialised says so itself
-        self._remat = bool(remat_segments or g.get("remat_segments"))
-        dt = str(g.get("data_type", "float32"))
-        self.compute_dtype = {"bfloat16": jnp.bfloat16,
-                              "float64": jnp.float64}.get(dt, jnp.float32)
-        self.param_dtype = jnp.float64 if dt == "float64" else jnp.float32
-        self._params = None          # dict name -> param dict (layer vertices)
-        self._updater_state = None
-        self._model_state = None     # dict name -> state dict
-        self._rng = jax.random.PRNGKey(int(g.get("seed", 123)))
-        self.listeners = []
-        self._score = None
-        self._last_batch_size = 0
-        self._jit_step = None
-        self._jit_forward = {}
-        self._loop = None            # device-resident {iteration, rng}
+        self._remat = bool(remat_segments
+                           or conf.global_conf.get("remat_segments"))
+        self._convbn_plan_cache = None
+        self._remat_plan_cache = None
 
+    # ------------------------------------------------------------------
+    # What a graph of vertices supplies to the trainer (nn/trainer.py)
     # ------------------------------------------------------------------
     def _layer_names(self):
         """Layer vertices in topological order (the flattened-params order —
@@ -75,34 +66,19 @@ class ComputationGraph:
         return [n for n in self.conf.topological_order
                 if self.conf.vertices[n].is_layer]
 
-    def init(self, parameters=None, clone_parameters=False):
-        if self._params is None:
-            names = self._layer_names()
-            keys = jax.random.split(self._rng, len(names) + 1)
-            self._rng = keys[0]
-            self._params = {}
-            self._model_state = {}
-            for i, n in enumerate(names):
-                layer = self.conf.vertices[n].conf
-                self._params[n] = layer.init_params(keys[i + 1], self.param_dtype)
-                self._model_state[n] = layer.init_state()
-            self._init_updater_state()
-        if parameters is not None:
-            self.set_params(parameters)
-        return self
+    def _layer_items(self):
+        return [(n, self.conf.vertices[n].conf) for n in self._layer_names()]
 
-    def _init_updater_state(self):
-        sd = self.conf.global_conf.get("updater_state_dtype")
-        self._updater_state = {}
-        for n in self._layer_names():
-            layer = self.conf.vertices[n].conf
-            init_fn, _ = U.get(layer.updater or "sgd")
-            st = {k: init_fn(v) for k, v in self._params[n].items()}
-            self._updater_state[n] = U.cast_updater_state(st, sd)
+    def _per_layer(self, values):
+        return dict(zip(self._layer_names(), values))
 
-    def _ensure_init(self):
-        if self._params is None:
-            self.init()
+    def _canon_batch(self, features, labels, fmask=None, lmask=None):
+        """Loose arrays (one array, a list in `network_inputs` order or a
+        name-keyed dict; one label an output) -> the raw step's name-keyed
+        feature dict, label list and mask trees."""
+        return (self._canon_inputs(features), _as_list(labels),
+                self._canon_masks(fmask),
+                None if lmask is None else _as_list(lmask))
 
     # ------------------------------------------------------------------
     # Forward — reference: per-vertex doForward in topological order
@@ -177,22 +153,9 @@ class ComputationGraph:
         Everything it traces is under the scope `<kind>.<vertex name>`;
         `pair` is `_pair_of`'s answer for this vertex."""
         if pair is not None:
-            # the batch norm of a planned pair: convolution and batch norm
-            # as one function of the convolution's INPUT, under the
-            # convolution's scope (its backward is convolution work); the
-            # running statistics under the batch norm's
-            cspec, cp, a = pair
-            with layer_scope(cspec.conf, cspec.name):
-                if cspec.preprocessor is not None:
-                    a = cspec.preprocessor.pre_process(a)
-                p = self._cast_params(p)
-                y, mean, var = _conv1x1_bn_train_fused(
-                    spec.conf.eps, spec.conf.use_fast_variance,
-                    cspec.conf.stride)(
-                        a, self._cast_params(cp)["W"], p["gamma"], p["beta"])
-            with layer_scope(spec.conf, spec.name):
-                return y, spec.conf.running_stats(state_entry, mean,
-                                                  var), None
+            out, st = convbn_pair_forward(spec, p, state_entry, *pair,
+                                          cast=self._cast_params)
+            return out, st, None
         with layer_scope(spec.conf, spec.name):
             if spec.is_layer:
                 layer = spec.conf
@@ -222,45 +185,14 @@ class ComputationGraph:
 
     def _convbn_plan(self):
         """{batch-norm vertex: convolution vertex} for every pair the
-        training forward computes as one function
-        (`_conv1x1_bn_train_fused`: a backward that never reads the
-        convolution's output; the step is HBM-bound). The rule is read from
-        the graph, once a container: an EXPANDING 1x1 convolution
-        (n_out > n_in: the output is the wide tensor, and the backward's
-        extra Cin x Cin product stays under the convolution's own work)
-        with no bias, no padding, identity activation and no input dropout,
-        whose only consumer is a batch norm with learned scale and shift,
-        the fused backward and no preprocessor. Every other convolution and
-        batch norm runs its own layer's code. The gauge
-        `train.convbn_pairs` says how many the plan holds."""
-        if getattr(self, "_convbn_plan_cache", None) is None:
-            verts = self.conf.vertices
-            consumers = {}
-            for name, spec in verts.items():
-                for i in spec.inputs:
-                    consumers.setdefault(i, []).append(name)
-            plan = {}
-            for name, spec in verts.items():
-                bn = spec.conf
-                cspec = verts.get(spec.inputs[0]) if spec.inputs else None
-                if (type(bn) is not BatchNormalization or cspec is None
-                        or type(cspec.conf) is not ConvolutionLayer):
-                    continue
-                conv = cspec.conf
-                if (conv.kernel_size == (1, 1) and not conv.has_bias
-                        and (conv.padding == (0, 0)
-                             or str(conv.convolution_mode).lower() == "same")
-                        and (activations.get(conv.activation)
-                             is activations.identity)
-                        and not conv.dropout
-                        and conv.n_out > conv.n_in
-                        and consumers[cspec.name] == [name]
-                        and cspec.name not in self.conf.network_outputs
-                        and spec.preprocessor is None
-                        and bn.fused_backward and not bn.lock_gamma_beta):
-                    plan[name] = cspec.name
-            self._convbn_plan_cache = plan
-            obs.default_registry().gauge("train.convbn_pairs").set(len(plan))
+        training forward computes as one function: `convbn_pairs` (beside
+        the operation, in conf/layers/normalization.py) reads the rule from
+        the graph, once a container. The gauge `train.convbn_pairs` says
+        how many the plan holds."""
+        if self._convbn_plan_cache is None:
+            self._convbn_plan_cache = convbn_pairs(self.conf)
+            obs.default_registry().gauge("train.convbn_pairs").set(
+                len(self._convbn_plan_cache))
         return self._convbn_plan_cache
 
     def _pair_of(self, name, train, params, acts):
@@ -280,7 +212,7 @@ class ComputationGraph:
     def _remat_plan(self):
         """Segment the topological order at element-wise (residual-add)
         vertex boundaries. Returns (segment-id per vertex, n_segments)."""
-        if getattr(self, "_remat_plan_cache", None) is None:
+        if self._remat_plan_cache is None:
             from ..conf.graph_vertices import ElementWiseVertex
             seg, s = {}, 0
             for name in self.conf.topological_order:
@@ -432,115 +364,6 @@ class ComputationGraph:
                 total = total + new_state[n]["layer_loss"]
         return total + reg, (new_state, new_carries)
 
-    # ------------------------------------------------------------------
-    # Fused train step (same contract as MultiLayerNetwork.make_raw_step)
-    # ------------------------------------------------------------------
-    def make_grad_fn(self):
-        """(params, state, batch) -> (grads, score, new_state, new_carries) —
-        gradient half of the step (async-PS worker compute; see
-        multilayer.make_grad_fn)."""
-        def grad_fn(params, state, batch):
-            (score, (new_state, new_carries)), grads = jax.value_and_grad(
-                self._loss_fn, has_aux=True)(
-                    params, state, batch["features"], batch["labels"],
-                    batch.get("fmask"), batch.get("lmask"), batch["rng"],
-                    True, batch.get("carries"))
-            return grads, score, new_state, new_carries
-        return grad_fn
-
-    def make_apply_fn(self):
-        """(params, ustate, grads, iteration) -> (new_params, new_ustate) —
-        updater half of the step (reference ComputationGraphUpdater)."""
-        names = self._layer_names()
-
-        @jax.named_scope("update")
-        def apply_updates(params, ustate, grads, iteration):
-            minimize = self.conf.global_conf.get("minimize", True)
-            new_params = dict(params)
-            new_ustate = dict(ustate)
-            for n in names:
-                layer = self.conf.vertices[n].conf
-                g_n = U.normalize_gradients(
-                    grads[n], layer.gradient_normalization,
-                    layer.gradient_normalization_threshold or 1.0)
-                _, apply_fn = U.get(layer.updater or "sgd")
-                hp = layer.updater_hp()
-                p_new, s_new = {}, {}
-                for k, p in params[n].items():
-                    base_lr = layer.learning_rate or 0.1
-                    if k in ("b", "beta") and layer.bias_learning_rate is not None:
-                        base_lr = layer.bias_learning_rate
-                    lr = U.schedule_lr(
-                        base_lr, layer.lr_policy or "none", iteration,
-                        decay_rate=layer.lr_policy_decay_rate or 0.0,
-                        steps=layer.lr_policy_steps or 1.0,
-                        power=layer.lr_policy_power or 1.0,
-                        schedule_map=layer.lr_schedule,
-                        max_iterations=layer.lr_policy_max_iterations)
-                    upd, s_k = apply_fn(ustate[n][k], g_n[k], lr, hp)
-                    p_new[k] = p - upd if minimize else p + upd
-                    # keep the stored state dtype (bf16 when
-                    # updater_state_dtype is set; math promotes to f32)
-                    s_new[k] = jax.tree.map(
-                        lambda a, old: a.astype(old.dtype), s_k, ustate[n][k])
-                new_params[n] = p_new
-                new_ustate[n] = s_new
-            return new_params, new_ustate
-
-        return apply_updates
-
-    def make_raw_step(self, emit_health=False):
-        """Same contract as MultiLayerNetwork.make_raw_step:
-        emit_health=True appends the scalar health pytree to the return
-        tuple and gates the whole update on the all-finite predicate
-        (`jnp.where` — a poisoned batch is skipped on device); False
-        compiles the identical program as before."""
-        grad_fn = self.make_grad_fn()
-        apply_updates = self.make_apply_fn()
-
-        def step(params, ustate, state, batch):
-            grads, score, new_state, new_carries = grad_fn(params, state,
-                                                           batch)
-            new_params, new_ustate = apply_updates(params, ustate, grads,
-                                                   batch["iteration"])
-            if emit_health:
-                from ...common import health as H
-                with jax.named_scope("health"):
-                    health = H.grad_health(grads, score)
-                    ok = health["all_finite"]
-                    new_params = H.gate_update(ok, new_params, params)
-                    new_ustate = H.gate_update(ok, new_ustate, ustate)
-                    new_state = H.gate_update(ok, new_state, state)
-                    if batch.get("carries") is not None:
-                        new_carries = H.gate_update(ok, new_carries,
-                                                    batch["carries"])
-                return (new_params, new_ustate, new_state, score,
-                        new_carries, health)
-            return new_params, new_ustate, new_state, score, new_carries
-
-        return step
-
-    def _make_step(self):
-        emit_health = getattr(self, "_health_policy", None) is not None
-        self._step_emits_health = emit_health
-        raw = self.make_raw_step(emit_health)
-
-        def step(params, ustate, state, loop, features, labels, fmask, lmask,
-                 carries=None):
-            # device-resident loop state (iteration counter + PRNG key):
-            # advances inside the compiled step — no per-iteration host
-            # scalar transfer or key-split dispatch (see multilayer.py)
-            rng, next_rng = jax.random.split(loop["rng"])
-            batch = {"features": features, "labels": labels, "fmask": fmask,
-                     "lmask": lmask, "iteration": loop["iteration"],
-                     "rng": rng, "carries": carries}
-            p, u, s, score, car, *extras = raw(params, ustate, state, batch)
-            # loop state advances on skipped steps too (see multilayer.py)
-            new_loop = {"iteration": loop["iteration"] + 1.0, "rng": next_rng}
-            return (p, u, s, score, car, new_loop) + tuple(extras)
-
-        return jax.jit(step, donate_argnums=(0, 1, 2, 3))
-
     def publish_layer_gauges(self, registry=None):
         """Set `<kind>.<vertex>.<name>` gauges from every layer's
         `gauges(state)` (a `moe` layer's routed pairs, a `sparseattention`'s
@@ -557,39 +380,6 @@ class ComputationGraph:
                 registry.gauge(name).set(out[name])
         return out
 
-    def training_health(self, policy=True, checkpoint_dir=None,
-                        checkpoint_every=10, keep_checkpoints=3):
-        """Arm the training-health watchdog (see
-        MultiLayerNetwork.training_health — identical contract)."""
-        from ...common import health as H
-        H.install(self, policy, checkpoint_dir, checkpoint_every,
-                  keep_checkpoints)
-        return self
-
-    def fused_steps(self, k=8):
-        """Fuse K optimizer steps into one device dispatch (see
-        MultiLayerNetwork.fused_steps — identical contract; multi-input
-        feature dicts and multi-output label lists stack per leaf)."""
-        from .. import fused as F
-        return F.install(self, k)
-
-    def _fused_k(self):
-        k = getattr(self, "_fused_steps", 1)
-        if (k <= 1
-                or int(self.conf.global_conf.get("num_iterations", 1)) != 1):
-            return 1
-        return k
-
-    def _loop_state(self):
-        if self._loop is None:
-            self._rng, k = jax.random.split(self._rng)
-            self._loop = {
-                "iteration": jnp.asarray(self.conf.iteration_count,
-                                         jnp.float32),
-                "rng": k,
-            }
-        return self._loop
-
     # ------------------------------------------------------------------
     # fit — reference ComputationGraph.fit:809
     # ------------------------------------------------------------------
@@ -597,126 +387,9 @@ class ComputationGraph:
         self._ensure_init()
         if labels is not None:
             data = MultiDataSet(data, labels)
-        if isinstance(data, DataSet):
-            data = _dataset_to_mds(data)
-        if isinstance(data, MultiDataSet):
-            return self._fit_mds(data)
-        # iterator of DataSet / MultiDataSet: prefetch + stage off the
-        # training thread like the reference (ComputationGraph.fit wraps
-        # in Async(Multi)DataSetIterator), with the bf16 feature wire for
-        # bf16 models (bit-identical — the step casts features anyway)
-        from ...datasets.iterators import (AsyncDataSetIterator,
-                                           DataSetIterator,
-                                           wrap_async_for_fit)
-        wrapped_here = False
-        if isinstance(data, DataSetIterator):
-            # the wrapper stages DataSet AND MultiDataSet batches
-            # (per-batch dispatch), so one class covers both protocols.
-            # A caller-supplied plain iterator may be mid-stream: reset
-            # BEFORE wrapping so the fresh wrapper prefetches from 0 and
-            # the epoch-0 reset skip is trivially safe (ADVICE r5)
-            wrapped_here = not isinstance(data, AsyncDataSetIterator)
-            if wrapped_here:
-                data.reset()
-            data = wrap_async_for_fit(
-                data, self.compute_dtype,
-                queue_size=max(2, getattr(self, "_fused_steps", 1) + 1))
-        for epoch in range(num_epochs):
-            # a fresh async wrapper fit() itself created is already
-            # prefetching; resetting it on epoch 0 would drain (and
-            # stage) one full pass unseen. CALLER-supplied iterators may
-            # be mid-stream and reset unconditionally (ADVICE r5)
-            if hasattr(data, "reset") and (
-                    epoch > 0 or not wrapped_here
-                    or not getattr(data, "has_next", lambda: False)()):
-                data.reset()
-            it = iter(data) if not hasattr(data, "has_next") else None
-            if it is not None:
-                for ds in it:
-                    self._fit_mds(_dataset_to_mds(ds)
-                                  if isinstance(ds, DataSet) else ds)
-            else:
-                while data.has_next():
-                    k = (self._fused_k()
-                         if self.conf.backprop_type != "tbptt" else 1)
-                    if k <= 1:
-                        ds = next_processed(data)
-                        self._fit_mds(_dataset_to_mds(ds)
-                                      if isinstance(ds, DataSet) else ds)
-                        continue
-                    from .. import fused as F
-                    group = []
-                    g = F.group_size(self, k)
-                    with obs.TRACER.span("train.stage", cat="train", k=g):
-                        while len(group) < g and data.has_next():
-                            ds = next_processed(data)
-                            group.append(_dataset_to_mds(ds)
-                                         if isinstance(ds, DataSet) else ds)
-                    if len(group) == g and F.uniform_group(group):
-                        self._fit_mds_fused(group)
-                    else:
-                        # ragged tail / mixed shapes: single-step stream
-                        for mds in group:
-                            self._fit_mds(mds)
-            self.conf.epoch_count += 1
-        return self
-
-    def _canon_mds(self, mds):
-        """One MultiDataSet -> the raw-step batch pieces (name-keyed
-        feature dict, label list, mask trees) — the _fit_mds conversion,
-        shared with the fused super-batch path."""
-        features = {n: jnp.asarray(f)
-                    for n, f in zip(self.conf.network_inputs, mds.features)}
-        labels = [jnp.asarray(l) for l in mds.labels]
-        fmasks = None
-        if mds.features_masks:
-            fmasks = {n: jnp.asarray(m) if m is not None else None
-                      for n, m in zip(self.conf.network_inputs,
-                                      mds.features_masks)}
-        lmasks = None
-        if mds.labels_masks:
-            lmasks = [jnp.asarray(m) if m is not None else None
-                      for m in mds.labels_masks]
-        return features, labels, fmasks, lmasks
-
-    def _fit_mds_fused(self, group):
-        """ONE dispatch for len(group) staged MultiDataSets (see
-        MultiLayerNetwork._fit_super_batch — same contract, tree-stacked
-        multi-input/multi-output batch pieces)."""
-        from .. import fused as F
-        emit_health = getattr(self, "_health_policy", None) is not None
-        g = len(group)
-        parts = [self._canon_mds(mds) for mds in group]
-
-        def build():
-            raw = self.make_raw_step(emit_health)
-
-            def prog(params, ustate, state, loop, batch_list):
-                return F.scan_batches(raw, params, ustate, state, loop,
-                                      batch_list)
-
-            return jax.jit(prog, donate_argnums=(0, 1, 2, 3))
-
-        step = F.fused_program(self, ("batch", g), build)
-        batch_list = tuple(
-            {"features": p[0], "labels": p[1], "fmask": p[2],
-             "lmask": p[3]} for p in parts)
-        self._last_batch_size = int(
-            jax.tree.leaves(parts[0][0])[0].shape[0])
-        with obs.TRACER.span("train.fused_group", cat="train", k=g):
-            with obs.TRACER.span("train.dispatch", cat="train", k=g):
-                (self._params, self._updater_state, self._model_state,
-                 scores, _, self._loop, *extras) = step(
-                     self._params, self._updater_state, self._model_state,
-                     self._loop_state(), batch_list)
-            from ...common import health as H
-            with obs.TRACER.span("train.health", cat="train", k=g):
-                rb = H.finish_fused(self, scores,
-                                    extras[-1] if emit_health else None, g)
-        if rb is not None:
-            for mds in group[rb + 1:]:  # counters/rng restored; replay
-                self._fit_mds(mds)
-        return self
+        if isinstance(data, (DataSet, MultiDataSet)):
+            return self._fit_batch(data)
+        return self._fit_iterator(data, num_epochs)
 
     def lower_step(self, ds, sharding=None):
         """Lower (trace without running) the jitted step `fit` calls for one
@@ -728,51 +401,15 @@ class ComputationGraph:
         every argument is lowered as a shape placed on it: a device that is
         described and not attached holds no array (tools/step_bytes.py)."""
         self._ensure_init()
-        if self._jit_step is None:
-            self._jit_step = self._make_step()
-        if isinstance(ds, DataSet):
-            ds = _dataset_to_mds(ds)
         loop = self._loop or {"iteration": jnp.zeros((), jnp.float32),
                               "rng": self._rng}
         args = (self._params, self._updater_state, self._model_state, loop,
-                *self._canon_mds(ds))
+                *jax.tree.map(jnp.asarray, self._batch_parts(ds)))
         if sharding is not None:
             args = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=sharding), args)
-        return self._jit_step.lower(*args)
-
-    def _fit_mds(self, mds: MultiDataSet):
-        if self._jit_step is None:
-            self._jit_step = self._make_step()
-        features, labels, fmasks, lmasks = self._canon_mds(mds)
-        self._last_batch_size = int(mds.features[0].shape[0])
-        if self.conf.backprop_type == "tbptt":
-            return self._fit_tbptt(features, labels, fmasks, lmasks)
-        num_iterations = int(self.conf.global_conf.get("num_iterations", 1))
-        for _ in range(num_iterations):
-            with obs.TRACER.span("train.dispatch", cat="train"):
-                (self._params, self._updater_state, self._model_state,
-                 score, _, self._loop, *extras) = self._jit_step(
-                     self._params, self._updater_state, self._model_state,
-                     self._loop_state(), features, labels, fmasks, lmasks)
-            action = "ok"
-            if not getattr(self, "_step_emits_health", False):
-                self._score = score
-            else:
-                from ...common import health as H
-                with obs.TRACER.span("train.health", cat="train"):
-                    action = H.finish_step(self, extras[-1], score)
-                if action == "rollback":
-                    break           # counters/rng restored; next batch
-            self.conf.iteration_count += 1
-            for l in self.listeners:
-                l.iteration_done(self, self.conf.iteration_count - 1)
-            if action == "ok" and getattr(self, "_step_emits_health", False):
-                from ...common.health import fit_loop_checkpoint
-                with obs.TRACER.span("train.checkpoint", cat="train"):
-                    fit_loop_checkpoint(self)
-        return self
+        return self._single_step().lower(*args)
 
     # ------------------------------------------------------------------
     # TBPTT + streaming RNN state — reference ComputationGraph TBPTT path
@@ -790,128 +427,6 @@ class ComputationGraph:
                                                          self.compute_dtype)
                 for n in self._recurrent_names()}
 
-    def _fit_tbptt(self, features, labels, fmasks, lmasks):
-        """Slice the time axis into tbptt_fwd_length segments, carrying RNN
-        state (not gradients) across segments — reference ComputationGraph
-        TBPTT (same semantics as MultiLayerNetwork.doTruncatedBPTT:1140)."""
-        seq_names = [n for n, f in features.items() if f.ndim >= 3]
-        T = int(features[seq_names[0]].shape[1])
-        L = self.conf.tbptt_fwd_length
-        B = int(next(iter(features.values())).shape[0])
-        carries = self._init_carries(B)
-        t0 = 0
-        while t0 < T:
-            k = self._fused_k()
-            if k > 1:
-                from .. import fused as F
-                g = min(F.group_size(self, k), (T - t0) // L)
-                if g > 1:
-                    carries, t0, done = self._fit_tbptt_fused(
-                        features, labels, fmasks, lmasks, carries, t0, g,
-                        T, L)
-                    if done:        # rollback: abandon this sequence
-                        return self
-                    continue
-            def _seg(a):
-                # only sequence-shaped arrays have a time axis to slice;
-                # static inputs/labels/masks pass through whole
-                if a is None or a.ndim < 2 or a.shape[1] < T:
-                    return a
-                return a[:, t0:t0 + L]
-
-            f_seg = {n: (_seg(f) if f.ndim >= 3 else f)
-                     for n, f in features.items()}
-            l_seg = [(_seg(l) if l.ndim >= 3 else l) for l in labels]
-            fm_seg = ({n: _seg(m) for n, m in fmasks.items()}
-                      if fmasks else None)
-            lm_seg = ([_seg(m) for m in lmasks] if lmasks else None)
-            with obs.TRACER.span("train.dispatch", cat="train",
-                                 tbptt=True):
-                (self._params, self._updater_state, self._model_state,
-                 score, carries, self._loop, *extras) = self._jit_step(
-                     self._params, self._updater_state, self._model_state,
-                     self._loop_state(), f_seg, l_seg, fm_seg, lm_seg,
-                     carries)
-            action = "ok"
-            if not getattr(self, "_step_emits_health", False):
-                self._score = score
-            else:
-                from ...common import health as H
-                action = H.finish_step(self, extras[-1], score)
-                if action == "rollback":
-                    break       # abandon the rest of this sequence
-            self.conf.iteration_count += 1
-            for l in self.listeners:
-                l.iteration_done(self, self.conf.iteration_count - 1)
-            if action == "ok" and getattr(self, "_step_emits_health", False):
-                from ...common.health import fit_loop_checkpoint
-                with obs.TRACER.span("train.checkpoint", cat="train"):
-                    fit_loop_checkpoint(self)
-            t0 += L
-        return self
-
-    def _fit_tbptt_fused(self, features, labels, fmasks, lmasks, carries,
-                         t0, g, T, L):
-        """ONE dispatch for g full TBPTT segments (see
-        MultiLayerNetwork._fit_tbptt_fused): the scan body dynamic-slices
-        sequence-shaped arrays (static inputs/labels/masks pass through
-        whole, as in the sequential loop) and threads the RNN carries
-        through the scan carry. Returns (carries', next_t0, rolled_back)."""
-        from .. import fused as F
-        emit_health = getattr(self, "_health_policy", None) is not None
-
-        def build():
-            raw = self.make_raw_step(emit_health)
-
-            def prog(params, ustate, state, loop, features, labels,
-                     fmask, lmask, carries, t0s):
-                def make_batch(s):
-                    def sl(a, min_ndim):
-                        # same slice conditions as the sequential loop's
-                        # _seg (static at trace time): features/labels
-                        # only when sequence-shaped (ndim >= 3), masks
-                        # from ndim >= 2; arrays without a full time
-                        # axis pass through whole
-                        if (a is None or a.ndim < min_ndim
-                                or a.ndim < 2 or a.shape[1] < T):
-                            return a
-                        return jax.lax.dynamic_slice_in_dim(a, s, L, axis=1)
-
-                    return {"features": jax.tree.map(
-                                lambda a: sl(a, 3), features),
-                            "labels": jax.tree.map(
-                                lambda a: sl(a, 3), labels),
-                            "fmask": (jax.tree.map(
-                                lambda a: sl(a, 2), fmask)
-                                if fmask is not None else None),
-                            "lmask": (jax.tree.map(
-                                lambda a: sl(a, 2), lmask)
-                                if lmask is not None else None)}
-
-                return F.scan_steps(raw, params, ustate, state, loop,
-                                    carries, t0s, make_batch)
-
-            return jax.jit(prog, donate_argnums=(0, 1, 2, 3))
-
-        key = ("tbptt", g, T, L,
-               fmasks is not None, lmasks is not None)
-        step = F.fused_program(self, key, build)
-        t0s = jnp.arange(t0, t0 + g * L, L, dtype=jnp.int32)
-        with obs.TRACER.span("train.fused_group", cat="train", k=g,
-                             tbptt=True):
-            with obs.TRACER.span("train.dispatch", cat="train", k=g,
-                                 tbptt=True):
-                (self._params, self._updater_state, self._model_state,
-                 scores, carries, self._loop, *extras) = step(
-                     self._params, self._updater_state, self._model_state,
-                     self._loop_state(), features, labels, fmasks, lmasks,
-                     carries, t0s)
-            from ...common import health as H
-            with obs.TRACER.span("train.health", cat="train", k=g):
-                rb = H.finish_fused(self, scores,
-                                    extras[-1] if emit_health else None, g)
-        return carries, t0 + g * L, rb is not None
-
     def rnn_time_step(self, *features):
         """Single/multi-step streaming inference with carried RNN state
         (reference: ComputationGraph.rnnTimeStep). Returns the list of
@@ -925,7 +440,7 @@ class ComputationGraph:
         if single:
             inputs = {n: x[:, None, :] for n, x in inputs.items()}
         B = int(next(iter(inputs.values())).shape[0])
-        state = getattr(self, "_rnn_state", None)
+        state = self._rnn_state
         if state is not None:
             held = next(iter(next(iter(state.values())).values())).shape[0] \
                 if state else B
@@ -1017,130 +532,6 @@ class ComputationGraph:
         return infer
 
     # ------------------------------------------------------------------
-    # Score / gradients (gradient-check compatible API)
-    # ------------------------------------------------------------------
-    def score(self, data=None, training=False):
-        if data is None:
-            return float(self._score) if self._score is not None else float("nan")
-        self._ensure_init()
-        if isinstance(data, DataSet):
-            data = _dataset_to_mds(data)
-        features = {n: jnp.asarray(f)
-                    for n, f in zip(self.conf.network_inputs, data.features)}
-        labels = [jnp.asarray(l) for l in data.labels]
-        # Honor DataSet/MultiDataSet masks (same as _fit_mds) — dropping them
-        # silently skews validation loss on variable-length sequence data.
-        fmasks = None
-        if data.features_masks:
-            fmasks = {n: jnp.asarray(m) if m is not None else None
-                      for n, m in zip(self.conf.network_inputs,
-                                      data.features_masks)}
-        lmasks = None
-        if data.labels_masks:
-            lmasks = [jnp.asarray(m) if m is not None else None
-                      for m in data.labels_masks]
-        self._rng, rng = jax.random.split(self._rng)
-        s, _ = self._loss_fn(self._params, self._model_state, features, labels,
-                             fmasks, lmasks, rng, training)
-        return float(s)
-
-    def compute_gradient_and_score(self, features, labels, fmask=None,
-                                   lmask=None, train=True):
-        self._ensure_init()
-        rng = jax.random.PRNGKey(0)
-        features = {n: jnp.asarray(f) for n, f in
-                    self._canon_inputs(features).items()}
-        labels = [jnp.asarray(l) for l in _as_list(labels)]
-        fmasks = self._canon_masks(fmask)
-        if fmasks:
-            fmasks = {n: jnp.asarray(m) for n, m in fmasks.items()}
-        lmasks = ([jnp.asarray(m) if m is not None else None
-                   for m in _as_list(lmask)] if lmask is not None else None)
-        (score, _), grads = jax.value_and_grad(self._loss_fn, has_aux=True)(
-            self._params, self._model_state, features, labels, fmasks, lmasks,
-            rng, train)
-        return grads, float(score)
-
-    # ------------------------------------------------------------------
-    # Flattened-params contract — reference init:281-345
-    # ------------------------------------------------------------------
-    def _param_leaves(self):
-        leaves = []
-        for n in self._layer_names():
-            p = self._params[n]
-            for k in sorted(p.keys(), key=_param_sort_key):
-                leaves.append(((n, k), p[k]))
-        return leaves
-
-    def params(self):
-        self._ensure_init()
-        vecs = [np.asarray(v).ravel() for _, v in self._param_leaves()]
-        if not vecs:
-            return np.zeros((0,), np.float32)
-        return np.concatenate(vecs)
-
-    def set_params(self, flat):
-        self._ensure_init()
-        flat = np.asarray(flat).ravel()
-        offset = 0
-        new_params = {n: dict(p) for n, p in self._params.items()}
-        for (n, k), v in self._param_leaves():
-            sz = int(np.prod(v.shape)) if v.shape else 1
-            new_params[n][k] = jnp.asarray(
-                flat[offset:offset + sz].reshape(v.shape), v.dtype)
-            offset += sz
-        if offset != flat.size:
-            raise ValueError(f"Expected {offset} params, got {flat.size}")
-        self._params = new_params
-
-    setParams = set_params
-
-    def num_params(self):
-        return int(sum(int(np.prod(v.shape)) for _, v in self._param_leaves()))
-
-    numParams = num_params
-
-    def unflatten_params(self, flat):
-        offset = 0
-        out = {n: dict(p) for n, p in self._params.items()}
-        for n in self._layer_names():
-            p = self._params[n]
-            for k in sorted(p.keys(), key=_param_sort_key):
-                v = p[k]
-                sz = int(np.prod(v.shape)) if v.shape else 1
-                out[n][k] = flat[offset:offset + sz].reshape(v.shape).astype(v.dtype)
-                offset += sz
-        return out
-
-    def make_flat_score_fn(self, features, labels, fmask=None, lmask=None,
-                           train=True):
-        features = {n: jnp.asarray(f) for n, f in
-                    self._canon_inputs(features).items()}
-        labels = [jnp.asarray(l) for l in _as_list(labels)]
-        fmasks = self._canon_masks(fmask)
-        if fmasks:
-            fmasks = {n: jnp.asarray(m) for n, m in fmasks.items()}
-        lmasks = ([jnp.asarray(m) if m is not None else None
-                   for m in _as_list(lmask)] if lmask is not None else None)
-        rng = jax.random.PRNGKey(0)
-
-        def score_fn(flat):
-            params = self.unflatten_params(flat)
-            s, _ = self._loss_fn(params, self._model_state, features, labels,
-                                 fmasks, lmasks, rng, train)
-            return s
-
-        return jax.jit(score_fn)
-
-    def flatten_gradients(self, grads):
-        vecs = []
-        for n in self._layer_names():
-            p = grads[n]
-            for k in sorted(p.keys(), key=_param_sort_key):
-                vecs.append(np.asarray(p[k], np.float64).ravel())
-        return np.concatenate(vecs) if vecs else np.zeros((0,))
-
-    # ------------------------------------------------------------------
     # Evaluation
     # ------------------------------------------------------------------
     def evaluate(self, data, output_index=0):
@@ -1165,24 +556,6 @@ class ComputationGraph:
             ev.eval(mds.labels[output_index],
                     np.asarray(outs[output_index]), mask=lmask)
         return ev
-
-    # ------------------------------------------------------------------
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
-
-    setListeners = set_listeners
-
-    def clone(self):
-        net = ComputationGraph(self.conf.clone())
-        if self._params is not None:
-            net.init()
-            # materialize COPIES: aliasing the live arrays would let the
-            # next donated train step delete the clone's buffers with it
-            net._params = jax.tree.map(jnp.copy, self._params)
-            net._updater_state = jax.tree.map(jnp.copy, self._updater_state)
-            net._model_state = jax.tree.map(jnp.copy, self._model_state)
-        return net
 
     def get_layer(self, name):
         return self.conf.vertices[name].conf
@@ -1211,7 +584,3 @@ def _as_list(x):
         return list(x)
     return [x]
 
-
-def _param_sort_key(k):
-    order = {"W": 0, "RW": 1, "b": 2, "gamma": 0, "beta": 1, "vb": 3}
-    return (order.get(k, 9), k)

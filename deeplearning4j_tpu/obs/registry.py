@@ -58,8 +58,8 @@ def sanitize(name):
 def fmt(v, nd=3):
     """None-safe rounding for metric read-outs: empty reservoirs report
     their percentiles/means as None (no data is not 0.0), and every
-    consumer that prints or JSON-encodes a snapshot (tools/serve_ab.py,
-    bench.py, tools/obs_report.py) must not crash on the idle case.
+    consumer that prints or JSON-encodes a snapshot (tools/load_sweep.py,
+    tools/obs_report.py) must not crash on the idle case.
     ONE shared helper so the guard cannot drift per call site."""
     if v is None:
         return None

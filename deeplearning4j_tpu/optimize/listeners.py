@@ -57,7 +57,7 @@ class ScoreIterationListener(IterationListener):
 class PerformanceListener(IterationListener):
     """Throughput instrumentation (reference:
     optimize/listeners/PerformanceListener.java — time/batch, samples/sec,
-    batches/sec). This is the measurement instrument bench.py uses."""
+    batches/sec)."""
 
     def __init__(self, frequency=1, report_score=False):
         self.frequency = max(1, int(frequency))

@@ -3,7 +3,7 @@
 The reference's profiling story is wall-clock listeners (PerformanceListener,
 BaseStatsListener timing, Spark phase timelines). On TPU the equivalent deep
 tool is the XLA device trace: this module wraps `jax.profiler` so a trace can
-be captured from bench.py or mid-training via a listener, and adds a
+be captured from benchmarks/run.py or mid-training via a listener, and adds a
 host-side summarizer that aggregates device-op time straight from the
 captured `.xplane.pb` (so no TensorBoard UI is needed to see where a step's
 time goes), by operation and, joined with the compiled step's own HLO, by

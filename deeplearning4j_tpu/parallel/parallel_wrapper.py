@@ -181,8 +181,15 @@ class ParallelWrapper:
             H.install(model, health_policy)
         self._ext_rollback = None
         self._sharded = False
+        # each compiled program with the net's generations it was built at
+        # (an activation-stats / watchdog toggle rebuilds it) and what its
+        # tail carries
         self._jit_step = None
+        self._act_gen = self._health_gen = 0
+        self._emits_health = False
         self._jit_kstep = None
+        self._kstep_health_gen = 0
+        self._kstep_emits_health = False
 
     # ------------------------------------------------------------------
     def _ensure_sharded(self):
@@ -251,6 +258,13 @@ class ParallelWrapper:
                               round_index=round_index,
                               rollback=self._health_rollback)
 
+    def _classify_round(self, health, score):
+        with obs.TRACER.span("parallel.health", cat="train"):
+            action = self._handle_health(health, self._gate.round)
+        if action not in ("skip", "rollback"):
+            self.model._score = score
+        return action
+
     def _health_rollback(self):
         """Restore the last checkpointed round — the wrapper's own
         `.checkpointing(...)` manager, or an externally installed seam
@@ -302,64 +316,24 @@ class ParallelWrapper:
                 self._fit_local_steps(data)
         return self
 
-    def _canon_parts(self, ds):
-        """Normalize a DataSet's pieces to the container's raw-step layout:
-        bare arrays for MultiLayerNetwork; name-keyed feature dict + label
-        list for ComputationGraph."""
-        net = self.model
-        f, l = ds.features, ds.labels
-        fm = getattr(ds, "features_mask",
-                     getattr(ds, "features_masks", None))
-        lm = getattr(ds, "labels_mask",
-                     getattr(ds, "labels_masks", None))
-        if not isinstance(net._params, dict):   # MultiLayerNetwork
-            return f, l, fm, lm
-        names = list(net.conf.network_inputs)
-        if isinstance(f, dict):
-            feats = f
-        else:
-            flist = list(f) if isinstance(f, (list, tuple)) else [f]
-            feats = dict(zip(names, flist))
-        labels = list(l) if isinstance(l, (list, tuple)) else [l]
-        fmasks = None
-        if fm is not None:
-            fmlist = list(fm) if isinstance(fm, (list, tuple)) else [fm]
-            fmasks = fm if isinstance(fm, dict) else dict(zip(names, fmlist))
-        lmasks = None
-        if lm is not None:
-            lmasks = list(lm) if isinstance(lm, (list, tuple)) else [lm]
-        return feats, labels, fmasks, lmasks
-
     # -- mode 1: per-step gradient allreduce (GSPMD via shardings) -----
     def _ensure_allreduce_step(self):
         net = self.model
-        act_gen = getattr(net, "_act_stats_gen", 0)
-        health_gen = getattr(net, "_health_gen", 0)
-        if self._jit_step is not None and \
-                (getattr(self, "_act_gen", 0) != act_gen
-                 or getattr(self, "_health_gen", 0) != health_gen):
+        gens = (net._act_stats_gen, net._health_gen)
+        if (self._act_gen, self._health_gen) != gens:
             self._jit_step = None     # activation-stats / watchdog toggle
         if self._jit_step is None:
-            self._act_gen = act_gen
-            self._health_gen = health_gen
+            self._act_gen, self._health_gen = gens
+            self._emits_health = net._health_policy is not None
             # honor the net's activation-stats mode (StatsListener arming
             # works identically under the sharded path); the k-local-steps
             # mode does NOT collect (k batches per program — see
-            # collect_activation_stats docstring)
-            collect = getattr(net, "_act_stats_cfg", None) is not None
-            emit_h = getattr(net, "_health_policy", None) is not None
-            self._collects_acts = collect
-            self._emits_health = emit_h
-            # positional only when armed: ComputationGraph's make_raw_step
-            # has no collect_acts parameter (and can never be armed). The
-            # psum'd gradients are replicated, so the health predicate —
-            # and the on-device skip — is identical on every device.
-            if collect:
-                raw = net.make_raw_step(True, emit_health=emit_h)
-            elif emit_h:
-                raw = net.make_raw_step(emit_health=True)
-            else:
-                raw = net.make_raw_step()
+            # collect_activation_stats docstring). The psum'd gradients are
+            # replicated, so the health predicate — and the on-device
+            # skip — is identical on every device.
+            raw = net.make_raw_step(
+                collect_acts=net._act_stats_cfg is not None,
+                emit_health=self._emits_health)
             if self._ustate_shardings is not None:
                 inner, shardings = raw, self._ustate_shardings
 
@@ -377,7 +351,7 @@ class ParallelWrapper:
 
     def _sharded_batch(self, ds, step_rng):
         net = self.model
-        feats, labels, fm, lm = self._canon_parts(ds)
+        feats, labels, fm, lm = net._batch_parts(ds)
         put = self._put_batch
         batch = {
             "features": jax.tree.map(put, feats),
@@ -423,32 +397,17 @@ class ParallelWrapper:
             with obs.TRACER.span("parallel.stage", cat="train"):
                 net._rng, step_rng = jax.random.split(net._rng)
                 batch, feats = self._sharded_batch(ds, step_rng)
+            net._last_batch_size = int(
+                jax.tree.leaves(feats)[0].shape[0])
             with obs.TRACER.span("parallel.dispatch", cat="train"):
                 (net._params, net._updater_state, net._model_state, score,
                  _, *extras) = step(net._params, net._updater_state,
                                     net._model_state, batch)
-            health = (extras.pop() if getattr(self, "_emits_health", False)
-                      else None)
-            if extras:
-                net._last_activation_stats = extras[0]
-                net._last_activation_stats_iter = net.conf.iteration_count
-            action = "ok"
-            if health is not None:
-                with obs.TRACER.span("parallel.health", cat="train"):
-                    action = self._handle_health(health, self._gate.round)
-                if action == "rollback":
-                    continue    # counters/rng rewound; next batch retrains
-            if action != "skip":
-                net._score = score
-            net._last_batch_size = int(
-                jax.tree.leaves(feats)[0].shape[0])
-            net.conf.iteration_count += 1
-            for l in net.listeners:
-                l.iteration_done(net, net.conf.iteration_count - 1)
-            if action == "ok" or health is None:
-                # a skipped/diverged round is never checkpointed — the
-                # last-good-round invariant the rollback seam relies on
-                self._round_done()
+            # the trainer's epilogue over the wrapper's seam: a rolled-back
+            # round rewound counters/rng, and the next batch retrains
+            net.finish_step(score, extras, self._emits_health,
+                            classify=self._classify_round,
+                            checkpoint=self._round_done)
 
     # -- mode 2: k local steps then parameter averaging ----------------
     def _fit_local_steps(self, it):
@@ -481,10 +440,9 @@ class ParallelWrapper:
         net = self.model
         mesh = self.mesh
         avg_upd = self.average_updaters
-        emit_h = getattr(net, "_health_policy", None) is not None
+        emit_h = net._health_policy is not None
         self._kstep_emits_health = emit_h
-        raw = (net.make_raw_step(emit_health=True) if emit_h
-               else net.make_raw_step())
+        raw = net.make_raw_step(emit_health=emit_h)
 
         def local_steps(params, ustate, state, batches):
             def body(carry, batch_t):
@@ -563,7 +521,7 @@ class ParallelWrapper:
         the model's rng stream). Returns (batches_tree, B)."""
         net = self.model
         k = len(batches)
-        parts = [self._canon_parts(b) for b in batches]
+        parts = [net._batch_parts(b) for b in batches]
         # batch size from the first FEATURE leaf so multi-input feature
         # dicts/lists (ComputationGraph / MultiDataSet) size correctly
         B = max(int(jax.tree.leaves(p[0])[0].shape[0]) for p in parts)
@@ -621,11 +579,9 @@ class ParallelWrapper:
         k = len(batches)
         with obs.TRACER.span("parallel.stage", cat="train", k=k):
             batches_tree, B = self._kstep_batches(batches)
-        h_gen = getattr(net, "_health_gen", 0)
-        if self._jit_kstep is not None and \
-                getattr(self, "_kstep_health_gen", 0) != h_gen:
+        if self._kstep_health_gen != net._health_gen:
             self._jit_kstep = None         # watchdog toggled mid-life
-        self._kstep_health_gen = h_gen
+            self._kstep_health_gen = net._health_gen
         if self._jit_kstep is None:
             self._jit_kstep = self._build_kstep()(batches_tree)
         with obs.TRACER.span("parallel.dispatch", cat="train", k=k):
@@ -634,7 +590,7 @@ class ParallelWrapper:
                  net._params, net._updater_state, net._model_state,
                  batches_tree)
         action = "ok"
-        if getattr(self, "_kstep_emits_health", False):
+        if self._kstep_emits_health:
             with obs.TRACER.span("parallel.health", cat="train", k=k):
                 action = self._handle_health(extra[0], self._gate.round)
             if action == "rollback":

@@ -93,23 +93,14 @@ def shard_params(net, mesh, tensor_parallel=False):
     """Return (sharded_params, param_shardings) for a container's per-layer
     param pytree — list-shaped for MultiLayerNetwork, name-keyed dict for
     ComputationGraph."""
-    if isinstance(net._params, dict):   # ComputationGraph
-        shardings = {
-            n: _layer_sharding(net.conf.vertices[n].conf, p, mesh,
-                               tensor_parallel)
-            for n, p in net._params.items()}
-    else:                               # MultiLayerNetwork
-        shardings = [
-            _layer_sharding(layer, p, mesh, tensor_parallel)
-            for layer, p in zip(net.layers, net._params)]
-    if isinstance(shardings, dict):
-        sharded = {n: {k: put_sharded(v, shardings[n][k], full_array=True)
-                       for k, v in p.items()}
-                   for n, p in net._params.items()}
-    else:
-        sharded = [{k: put_sharded(v, d[k], full_array=True)
-                    for k, v in p.items()}
-                   for d, p in zip(shardings, net._params)]
+    items = net._layer_items()
+    shardings = net._per_layer(
+        _layer_sharding(layer, net._params[key], mesh, tensor_parallel)
+        for key, layer in items)
+    sharded = net._per_layer(
+        {k: put_sharded(v, shardings[key][k], full_array=True)
+         for k, v in net._params[key].items()}
+        for key, _ in items)
     return sharded, shardings
 
 

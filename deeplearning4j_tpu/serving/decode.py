@@ -291,8 +291,8 @@ class ContinuousDecodeServer(_RequestLoop):
     full token list (prompt + generated, greedy decode — the
     `generate_batch` contract). `static_batching=True` degrades scheduling
     to gang admission (a new batch only forms when every slot is free) —
-    the A/B baseline `tools/serve_ab.py` measures against, through the
-    exact same machinery.
+    the A/B baseline of continuous batching, through the exact same
+    machinery.
     """
 
     _thread_name = "continuous-decode"

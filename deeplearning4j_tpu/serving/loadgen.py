@@ -1,9 +1,9 @@
 """Seeded load generation: arrival processes, size mixes, open/closed
 loops.
 
-`tools/serve_ab.py` replays fixed backlogs: every request is already
-queued when the clock starts, so the servers have only ever been
-measured at infinite offered load with zero queueing dynamics.
+A fixed backlog has every request already queued when the clock starts:
+a server measured that way is measured at infinite offered load with
+zero queueing dynamics.
 Production traffic is the opposite regime — requests ARRIVE, at some
 rate, in some pattern, and the latency a user sees is mostly what the
 arrival process does to the queue. This module generates that traffic:

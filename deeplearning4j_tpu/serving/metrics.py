@@ -1,8 +1,7 @@
 """Serving metrics: request-level latency percentiles + operational
 gauges + SLO attainment counters.
 
-A serving SLO is a percentile, not a mean (bench.py's decode config makes
-the same point for token latency) — so the core structure here is a
+A serving SLO is a percentile, not a mean — so the core structure here is a
 bounded latency reservoir per phase (queue wait, dispatch, total) with
 p50/p99 read out in `snapshot()`. Everything is host-side and O(1) per
 request: metrics must never add a device round-trip or a blocking call
@@ -15,7 +14,7 @@ so the `/metrics` Prometheus route on ui/server.py exports serving
 counters next to training-health and transport counters). The
 `snapshot()` dict is unchanged and remains the ONE export surface — the
 same dict feeds `ui.stats.ServingStatsReporter` (the existing UI storage
-path), the `served_throughput` bench entry, and `tools/serve_ab.py`.
+path) and `tools/load_sweep.py`.
 
 Queue-depth staleness fix (PR 6): depth used to be sampled ONLY at batch
 formation, so an idle-then-bursty server reported the depth of the last
@@ -51,7 +50,7 @@ visible on the Prometheus route before it costs goodput. The shed
 counters split by CAUSE (`shed_queue_full` / `shed_deadline` /
 `shed_blocks` / `shed_predicted` / `shed_brownout`), rendered together
 by `shed_view()` — the one breakdown implementation behind
-loadgen/load_sweep/serve_ab/bench, as `slo_view` is for goodput.
+loadgen/load_sweep, as `slo_view` is for goodput.
 """
 from __future__ import annotations
 
@@ -72,9 +71,8 @@ def slo_view(snap, throughput=None, base=None):
     a snapshot taken AFTER any compile-off-the-clock warm-up — the
     counters are all-time, and first-compile requests are guaranteed SLO
     misses that would permanently deflate attainment. The ONE
-    implementation behind tools/serve_ab.py and bench.py's serving
-    records, so the attainment/goodput definition cannot drift between
-    reports."""
+    implementation behind every serving record, so the attainment/goodput
+    definition cannot drift between reports."""
     def delta(key):
         return snap.get(key, 0) - (base.get(key, 0) if base else 0)
 
@@ -98,9 +96,8 @@ def shed_view(snap, base=None):
     """Shed-reason breakdown from one snapshot() dict (deltas vs `base`,
     like `slo_view`): the distinct counters behind what used to print as
     one "sheds" number. ONE implementation shared by
-    `serving.loadgen.run_load`, `tools/load_sweep.py`,
-    `tools/serve_ab.py`, and bench.py so the column set cannot drift
-    between reports. `evicted_mid_decode` rides along (it is the shed
+    `serving.loadgen.run_load` and `tools/load_sweep.py` so the column
+    set cannot drift between reports. `evicted_mid_decode` rides along (it is the shed
     the admission predictor exists to prevent: work paid for, then
     thrown away)."""
     def delta(key):

@@ -114,7 +114,7 @@ class StatsListener(IterationListener):
             report["memory"] = self._memory_info()
         if c.collect_learning_rates:
             report["learningRates"] = self._learning_rates(model)
-        pol = getattr(model, "_health_policy", None)
+        pol = model._health_policy
         if pol is not None:
             # run-health from the training-health watchdog
             # (common/health.py): skip/spike/rollback/validation-reject
@@ -211,11 +211,8 @@ class StatsListener(IterationListener):
 
     def _learning_rates(self, model):
         out = {}
-        layers = (model.layers if hasattr(model, "layers")
-                  else [s.conf for s in model.conf.vertices.values()
-                        if s.is_layer])
-        for i, l in enumerate(layers):
-            out[getattr(l, "name", None) or str(i)] = float(
+        for key, l in model._layer_items():
+            out[getattr(l, "name", None) or str(key)] = float(
                 l.learning_rate or 0.0)
         return out
 
@@ -282,14 +279,9 @@ class StatsListener(IterationListener):
         return out
 
     def _param_arrays(self, model):
-        if isinstance(model._params, dict):     # ComputationGraph
-            for name, p in model._params.items():
-                for k, v in p.items():
-                    yield f"{name}_{k}", np.asarray(v)
-        else:                                   # MultiLayerNetwork
-            for i, p in enumerate(model._params):
-                for k, v in p.items():
-                    yield f"{i}_{k}", np.asarray(v)
+        for key, _ in model._layer_items():
+            for k, v in model._params[key].items():
+                yield f"{key}_{k}", np.asarray(v)
 
 
 class ServingStatsReporter:

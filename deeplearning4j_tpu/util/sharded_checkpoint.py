@@ -39,7 +39,7 @@ def _tree(net):
     loop state doesn't exist yet, a structurally-identical placeholder is
     stored and `has_loop` records which it was."""
     import jax.numpy as jnp
-    loop = getattr(net, "_loop", None)
+    loop = net._loop
     return {
         "params": net._params,
         "updater_state": net._updater_state,

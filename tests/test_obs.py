@@ -501,8 +501,8 @@ class TestCostPins:
 # (d) metrics: storage keys, SLO counters, queue-depth staleness fix
 # ---------------------------------------------------------------------------
 class TestMetricsPins:
-    # the ONE export surface: every consumer (UI storage, bench.py,
-    # tools/serve_ab.py, tools/obs_report.py) reads these names — a
+    # the ONE export surface: every consumer (UI storage,
+    # tools/load_sweep.py, tools/obs_report.py) reads these names — a
     # rename must fail here before it silently breaks a dashboard
     PINNED_KEYS = (
         "completed", "latency_ms_p50", "latency_ms_p99",
@@ -514,13 +514,12 @@ class TestMetricsPins:
         # fused decode windows (serving/decode.py fused_serve=K,
         # ISSUE 18): window count, realized decode iterations, and the
         # amortization ratio (~1.0 unfused, ~K fused) — consumed by
-        # tools/serve_ab.py's fused_serve_vs_plain arm, bench.py's
-        # fused_decode config, and the Prometheus route
+        # tools/load_sweep.py's fused rate and the Prometheus route
         "fused_windows", "decode_iterations", "iterations_per_dispatch",
         # paged KV-cache pool view (serving/kvpool.py): arena pressure,
         # measured concurrency, prefix-cache hit rate, CoW and
-        # memory-gate accounting — consumed by tools/serve_ab.py's
-        # paged_vs_fixed arm and bench.py's paged_decode config
+        # memory-gate accounting — consumed by tools/load_sweep.py's
+        # paged rate
         "pool_blocks", "blocks_in_use_last", "blocks_in_use_max",
         "live_streams_max", "prefix_rows_hit", "prefix_rows_total",
         "prefix_hit_rate", "cow_copies", "blocked_on_memory",
@@ -529,7 +528,7 @@ class TestMetricsPins:
         # counters, brownout deferral, chunk dispatches, the live
         # service-rate gauge, and the admission estimator's signed
         # (predicted - actual) error histogram — consumed by the
-        # load_sweep/serve_ab overload A/Bs and the Prometheus route
+        # load_sweep overload A/B and the Prometheus route
         "shed_predicted", "shed_brownout", "deferred",
         "chunk_dispatches", "service_rate_tokens_per_sec",
         # prefix-hit priority admission (serving/decode.py, PR 10):
@@ -538,7 +537,7 @@ class TestMetricsPins:
         "admitted_prefix_priority",
         # durable KV state (serving/kvstate.py): preempt/resume/migrate
         # event counts, host bytes spilled, restored-prefix hits —
-        # consumed by tools/serve_ab.py's preempt_vs_shed arm and the
+        # consumed by tools/load_sweep.py's preempt rate and the
         # Prometheus route (eagerly created, so a server that never
         # preempted scrapes zero, not absence)
         "preempted", "resumed", "migrated", "migrated_out",

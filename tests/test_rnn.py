@@ -52,9 +52,8 @@ class TestLSTMShapes:
     def test_scan_unroll_equivalent_numerics(self):
         """scan_unroll is a scheduling knob (lax.scan unroll=N): the same
         math with different XLA fusion, so forward and a masked training
-        step match unroll=1 to float-reassociation tolerance — the bench
-        A/B `char_rnn_lstm_unroll` measures speed only. Full tier: the
-        knob is off by default and only the bench A/B sets it."""
+        step match unroll=1 to float-reassociation tolerance. Full tier: the
+        knob is off by default."""
         x, y = seq_data(dtype=np.float32)
         mask = np.ones((4, 6), np.float32)
         mask[2, 4:] = 0.0

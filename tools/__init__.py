@@ -1,3 +1,3 @@
 """Repo tooling. A package so `python -m tools.analyze` resolves;
-the sibling scripts (load_sweep.py, serve_ab.py, ...) stay directly
+the sibling scripts (load_sweep.py, obs_report.py, ...) stay directly
 runnable."""

@@ -39,7 +39,6 @@ controlled arm's shed-reason breakdown, and the monotonicity verdict
 blast-radius-containment arm: poison-pill quarantine, the spawn
 circuit breaker's factory-failure window, and the shared retry budget
 composed with the manager kill (ISSUE 17).
-`bench.py`'s `load_sweep` config pins one sweep point per record;
 tests/test_loadgen.py runs the smoke version in tier-1 and CI uploads
 its report JSON.
 """
@@ -222,7 +221,7 @@ def sweep_decode(rates, n_req=64, slo_ms=150.0, seed=0,
         snap = metrics.snapshot()
     finally:
         srv.stop(timeout=120)
-    # describe the model actually measured (bench.py passes bigger ones)
+    # describe the model actually measured
     d_model = int(lm.aux["tok"].shape[1])
     cache = (f"paged bs={block_size}" if paged else "fixed-slot")
     ctrl = ""

@@ -19,9 +19,8 @@ One place that joins the three telemetry surfaces PR 6 standardized:
     a `MetricsRegistry.snapshot()`), None-guarded via the shared
     `obs.registry.fmt` helper.
 
-`tools/serve_ab.py` routes its per-arm summaries through
-`format_report` (replacing its print-only paths), and the CLI below
-renders a saved trace + profile dir + metrics JSON from disk:
+`tools/load_sweep.py` routes its summaries through `format_report`, and
+the CLI below renders a saved trace + profile dir + metrics JSON from disk:
 
     python tools/obs_report.py --trace /tmp/serve.trace.json \
         [--profile /tmp/prof [--hlo /tmp/step.hlo.txt]] \
