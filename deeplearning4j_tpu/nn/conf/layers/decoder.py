@@ -299,13 +299,15 @@ class MoELayer(_StatefulSequenceLayer):
 
     def init_state(self):
         return {"held_pairs": jnp.zeros((self._held(),), jnp.float32),
-                "absent_pairs": jnp.zeros((), jnp.float32)}
+                "absent_pairs": jnp.zeros((), jnp.float32),
+                "blocks_run": jnp.zeros((), jnp.float32)}
 
     def gauges(self, state):
         held = state["held_pairs"]
         return {"held_pairs_max": jnp.max(held),
                 "held_pairs_mean": jnp.mean(held),
-                "absent_pairs": state["absent_pairs"]}
+                "absent_pairs": state["absent_pairs"],
+                "blocks_run": state["blocks_run"]}
 
     def init_params(self, key, dtype=jnp.float32):
         D, F, G = self.n_in, self.expert_width, self._held()
@@ -324,13 +326,14 @@ class MoELayer(_StatefulSequenceLayer):
             experts, gates = route_all(params["Wr"], tokens,
                                        self.experts_per_token,
                                        self.norm_topk_prob)
-        y, counts = held_experts_ffn(
+        y, counts, n_run = held_experts_ffn(
             tokens, experts, gates, params["Wg"], params["Wu"], params["Wd"],
             self.first_held, self.n_experts)
         counts = counts.astype(jnp.float32)
         return y.astype(x.dtype).reshape(B, T, D), {
             "held_pairs": counts,
-            "absent_pairs": B * T * self.experts_per_token - jnp.sum(counts)}
+            "absent_pairs": B * T * self.experts_per_token - jnp.sum(counts),
+            "blocks_run": n_run.astype(jnp.float32)}
 
 
 # ---------------------------------------------------------------------------

@@ -19,10 +19,11 @@ NO CAPACITY, NOTHING DROPPED)**: one chip's share of an expert-parallel
 layer. It routes over all E experts, is told which contiguous range it
 holds, and computes every routed (token, choice) pair of a held expert:
 pairs sorted by expert, the three products as `lax.ragged_dot` over the
-ragged groups, in row blocks of static size of which only those that hold a
-pair run. It has no exchange and nothing that stands in for the absent
-chips: what their experts would add is left out. Used by the `moe` layer
-kind of the containers (`nn/conf/layers/decoder.py`,
+ragged groups, in row blocks of static size, as many of them as hold a pair:
+the loop's trip count is read from the routing on the device, forward and
+in its hand-written backward. It has no exchange and nothing that stands in
+for the absent chips: what their experts would add is left out. Used by the
+`moe` layer kind of the containers (`nn/conf/layers/decoder.py`,
 `models/zoo/keye_vl.py`). Two remain because the first one's fixed-capacity
 buffers ARE its exchange format (and its tests pin the drop rule), while a
 trainer at the sizes of a 128-expert model may not drop.
@@ -41,6 +42,7 @@ device, expert). Dispatch (per device, inside shard_map over axis "expert"):
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -257,6 +259,66 @@ def route_all(router_w, x, k, norm_topk=True):
     return experts, top_p
 
 
+def _block_out(lo, rows, k, order, starts, ends, x, gate_flat, w_gate, w_up,
+               w_down):
+    """The sorted pairs lo .. lo + rows - 1: (their gated results
+    [rows, D] float32, zero past the last held pair; each row's token)."""
+    pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    valid = (lo + jnp.arange(rows) < ends[-1])[:, None]
+    tok = pair // k
+    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    # rows past the last group are not the kernel's to define
+    rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
+        a, w, sizes, preferred_element_type=jnp.float32), 0.0)
+    xs = jnp.where(valid, x[tok], 0)
+    h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(x.dtype)
+    out = rd(h, w_down) * jnp.where(valid[:, 0], gate_flat[pair],
+                                    0.0)[:, None]
+    return out, tok
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk_blocks(rows, k, x, gate_flat, w_gate, w_up, w_down, order, starts,
+                 ends, n_run):
+    """The first `n_run` blocks of `rows` sorted pairs, summed by token. A
+    loop whose trip count is read from the routing has no reverse-mode rule,
+    hence the hand-written backward: it keeps the inputs, nothing a block,
+    and walks the same blocks last to first, each computed again."""
+    def body(i, y):
+        out, tok = _block_out(i * rows, rows, k, order, starts, ends, x,
+                              gate_flat, w_gate, w_up, w_down)
+        return y.at[tok].add(out)
+
+    with jax.named_scope("experts"):
+        return jax.lax.fori_loop(0, n_run, body,
+                                 jnp.zeros(x.shape, jnp.float32))
+
+
+def _walk_blocks_fwd(rows, k, *args):
+    return _walk_blocks(rows, k, *args), args
+
+
+def _walk_blocks_bwd(rows, k, args, y_bar):
+    *diff, order, starts, ends, n_run = args
+
+    def body(j, acc):
+        _, pull, tok = jax.vjp(
+            lambda *a: _block_out((n_run - 1 - j) * rows, rows, k, order,
+                                  starts, ends, *a),
+            *diff, has_aux=True)
+        return jax.tree.map(jnp.add, acc, pull(y_bar[tok]))
+
+    # the scope again: what is traced here is not under the forward's, and
+    # the metrics that read it would gain what fell out of it
+    with jax.named_scope("experts"):
+        grads = jax.lax.fori_loop(0, n_run, body,
+                                  tuple(jnp.zeros_like(a) for a in diff))
+    return (*grads, None, None, None, None)
+
+
+_walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
+
+
 def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
                      n_experts=None, block_rows=None):
     """y[t] = sum over the routed pairs (t, e) with e held here of
@@ -266,15 +328,15 @@ def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
     w_down [G, F, D] are experts first_held .. first_held + G - 1. EVERY
     pair of a held expert is computed, however the routing is skewed: the
     N*k pairs are sorted by expert (absent experts last) and walked in
-    blocks of `block_rows` rows of static shape; a block runs only if a
-    held pair lies in it, so the work follows the routing and the memory is
-    one block's. By default a block is 1.25 times the held experts' expected
-    share of the pairs (N k G / `n_experts`): an even router fills one block
-    and every further block is skipped, at the cost, forward and backward,
-    of passing the carried sums through (each skipped block of the backward
-    still adds a zero gradient the size of the held weights: few blocks
-    matter more than small ones). Returns (y [N, D] float32, pairs of each
-    held expert [G]).
+    blocks of `block_rows` rows of static shape, as many of them as hold a
+    held pair: the trip count ceil(held pairs / `block_rows`) is read from
+    the routing on the device, forward and backward, so the work follows
+    the routing, the memory is one block's, and a block without a pair is
+    never entered. By default a block is 1.25 times the held experts'
+    expected share of the pairs (N k G / `n_experts`): an even router takes
+    one trip, a router that sends every pair here N k / `block_rows`.
+    Returns (y [N, D] float32, pairs of each held expert [G], the trip
+    count).
     """
     N, D = x.shape
     k = experts.shape[1]
@@ -284,38 +346,12 @@ def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     counts = jnp.bincount(key, length=G + 1)[:G].astype(jnp.int32)
     ends = jnp.cumsum(counts)
-    n_held = ends[-1]
-    starts = ends - counts
     if block_rows is None:
         share = N * k * G / (n_experts or G)
         block_rows = -(-int(1.25 * share) // 512) * 512
     rows = min(int(block_rows), N * k)
-    n_blocks = -(-(N * k) // rows)
-    order = jnp.pad(order, (0, n_blocks * rows - N * k))
-    gate_flat = gates.reshape(-1)
-
-    def block(y, i):
-        lo = i * rows
-
-        def run(y):
-            pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
-            valid = (lo + jnp.arange(rows) < n_held)[:, None]
-            tok = pair // k
-            sizes = (jnp.clip(ends, lo, lo + rows)
-                     - jnp.clip(starts, lo, lo + rows))
-            # rows past the last group are not the kernel's to define
-            rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
-                a, w, sizes, preferred_element_type=jnp.float32), 0.0)
-            xs = jnp.where(valid, x[tok], 0)
-            h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(x.dtype)
-            out = rd(h, w_down) * jnp.where(valid[:, 0], gate_flat[pair],
-                                            0.0)[:, None]
-            return y.at[tok].add(out)
-
-        return jax.lax.cond(lo < n_held, run, lambda y: y, y), None
-
-    with jax.named_scope("experts"):
-        y, _ = jax.lax.scan(jax.checkpoint(block),
-                            jnp.zeros((N, D), jnp.float32),
-                            jnp.arange(n_blocks, dtype=jnp.int32))
-    return y, counts
+    order = jnp.pad(order, (0, -(N * k) % rows))
+    n_run = (ends[-1] + rows - 1) // rows
+    y = _walk_blocks(rows, k, x, gates.reshape(-1), w_gate, w_up, w_down,
+                     order, ends - counts, ends, n_run)
+    return y, counts, n_run

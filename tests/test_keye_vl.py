@@ -8,6 +8,7 @@ must leave as it was.
 """
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -306,9 +307,10 @@ def test_no_routed_pair_of_a_held_expert_is_dropped_under_skew():
     wd = 0.2 * jax.random.normal(k[4], (8, f, d))
     experts, gates = route_all(wr, x, 2)
     assert (experts[:, 0] == 5).all()
-    y, counts = held_experts_ffn(x, experts, gates, wg[4:6], wu[4:6],
-                                 wd[4:6], 4, block_rows=16)
+    y, counts, n_run = held_experts_ffn(x, experts, gates, wg[4:6], wu[4:6],
+                                        wd[4:6], 4, block_rows=16)
     assert int(counts[1]) == n and int(counts.sum()) >= n
+    assert int(n_run) == -(-int(counts.sum()) // 16) < 2 * n // 16
     want = jnp.zeros((n, d))
     for e in (4, 5):
         out = (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e]
@@ -316,6 +318,135 @@ def test_no_routed_pair_of_a_held_expert_is_dropped_under_skew():
                                     -1)[:, None]
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert float(jnp.min(jnp.linalg.norm(y, axis=-1))) > 0   # no token lost
+
+
+def scan_of_conditionals(x, experts, gates, w_gate, w_up, w_down, first_held,
+                         block_rows):
+    """The plain reference of the walk over blocks, as it stood before the
+    trip count: EVERY block of the static worst case under a `lax.cond`,
+    scanned under `jax.checkpoint`, differentiated by jax."""
+    N, D = x.shape
+    k = experts.shape[1]
+    G = w_gate.shape[0]
+    local = experts.reshape(-1) - first_held
+    key = jnp.where((local >= 0) & (local < G), local, G)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.bincount(key, length=G + 1)[:G].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    n_held = ends[-1]
+    starts = ends - counts
+    rows = min(int(block_rows), N * k)
+    n_blocks = -(-(N * k) // rows)
+    order = jnp.pad(order, (0, n_blocks * rows - N * k))
+    gate_flat = gates.reshape(-1)
+
+    def block(y, i):
+        lo = i * rows
+
+        def run(y):
+            pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            valid = (lo + jnp.arange(rows) < n_held)[:, None]
+            tok = pair // k
+            sizes = (jnp.clip(ends, lo, lo + rows)
+                     - jnp.clip(starts, lo, lo + rows))
+            rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
+                a, w, sizes, preferred_element_type=jnp.float32), 0.0)
+            xs = jnp.where(valid, x[tok], 0)
+            h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(x.dtype)
+            out = rd(h, w_down) * jnp.where(valid[:, 0], gate_flat[pair],
+                                            0.0)[:, None]
+            return y.at[tok].add(out)
+
+        return jax.lax.cond(lo < n_held, run, lambda y: y, y), None
+
+    y, _ = jax.lax.scan(jax.checkpoint(block),
+                        jnp.zeros((N, D), jnp.float32),
+                        jnp.arange(n_blocks, dtype=jnp.int32))
+    return y
+
+
+# 96 tokens, 2 choices each, experts 4 .. 7 held, blocks of 40 pairs (1.25
+# times an even share of 32, as the default is; 5 in the static worst case);
+# the trips each routing must take
+WALK = {"n": 96, "d": 32, "f": 16, "k": 2, "held": 4, "rows": 40}
+ROUTINGS = {"no_held_pair": 0, "even": 1, "every_pair_held": 5}
+
+
+def walk_case(routing, dtype):
+    c = WALK
+    k = jax.random.split(jax.random.PRNGKey(11), 6)
+    x = jax.random.normal(k[0], (c["n"], c["d"]), jnp.float32)
+    gates = jax.nn.softmax(jax.random.normal(k[1], (c["n"], c["k"]),
+                                             jnp.float32), -1)
+    if routing == "every_pair_held":        # both choices among 4 .. 7
+        first = jax.random.randint(k[2], (c["n"],), 4, 8)
+        experts = jnp.stack([first, 4 + (first - 3) % 4], -1)
+    elif routing == "no_held_pair":         # both among 8 .. 23
+        first = jax.random.randint(k[2], (c["n"],), 8, 24)
+        experts = jnp.stack([first, 8 + (first - 7) % 16], -1)
+    else:                                   # 24 experts, 4 held: a sixth,
+                                            # 32 pairs expected
+        first = jax.random.randint(k[2], (c["n"],), 0, 24)
+        experts = jnp.stack([first, (first + 7) % 24], -1)
+    w = [0.3 * jax.random.normal(kk, shape, jnp.float32) for kk, shape in zip(
+        k[3:], [(c["held"], c["d"], c["f"])] * 2
+        + [(c["held"], c["f"], c["d"])])]
+    cast = lambda a: a.astype(dtype)
+    return (cast(x), experts.astype(jnp.int32), gates, *map(cast, w))
+
+
+@pytest.fixture(scope="module")
+def walks():
+    """y and the five gradients of sum(y * seeded cotangent), by the walk
+    with a trip count and by the scan of conditionals, jitted, a case a
+    (routing, dtype)."""
+    made = {}
+
+    def of(routing, dtype):
+        if (routing, dtype) not in made:
+            x, experts, gates, wg, wu, wd = walk_case(routing, dtype)
+            ct = jax.random.normal(jax.random.PRNGKey(12), x.shape,
+                                   jnp.float32)
+
+            def both(fn):
+                def loss(x, gates, wg, wu, wd):
+                    y = fn(x, gates, wg, wu, wd)
+                    return jnp.sum(y * ct), y
+                (_, y), g = jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                        x, gates, wg, wu, wd)
+                return dict(zip(("y", "x", "gates", "Wg", "Wu", "Wd"),
+                                (y,) + g))
+
+            mine = lambda *a: held_experts_ffn(
+                a[0], experts, *a[1:], 4, block_rows=WALK["rows"])
+            got = both(lambda *a: mine(*a)[0])
+            want = both(lambda x, gates, wg, wu, wd: scan_of_conditionals(
+                x, experts, gates, wg, wu, wd, 4, WALK["rows"]))
+            made[routing, dtype] = got, want, int(
+                mine(x, gates, wg, wu, wd)[2])
+        return made[routing, dtype]
+
+    return of
+
+
+@pytest.mark.parametrize("what", ["y", "x", "gates", "Wg", "Wu", "Wd"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_walk_with_a_trip_count_is_the_scan_of_conditionals(
+        walks, routing, dtype, what):
+    """Bit for bit at equal `block_rows`: the same blocks in the same
+    order forward, the running blocks last to first backward; what went is
+    additions of zero."""
+    got, want, n_run = walks(routing, jnp.dtype(dtype))
+    assert n_run == ROUTINGS[routing]
+    assert got[what].dtype == want[what].dtype
+    np.testing.assert_array_equal(np.asarray(got[what], np.float32),
+                                  np.asarray(want[what], np.float32))
+    if routing == "no_held_pair":
+        assert not np.asarray(got[what], np.float32).any()
+    else:
+        assert np.asarray(got[what], np.float32).any()
 
 
 # ------------------------------------------------- what the seam shares
@@ -344,6 +475,56 @@ def test_configuration_round_trips_and_names_its_kinds():
     for scope in ("sparseattention.l0_attn", "moe.l1_moe", "indexer",
                   "select", "experts", "rmsnorm.norm_f", "loss.head"):
         assert scope in text, scope
+
+
+def test_the_experts_of_the_lowered_step_are_loops_under_both_names():
+    """What `train_moe_device_ms` and `train_moe_roofline` read is a name
+    on an operation's path: the walk's forward loop, the one the segment
+    computes again and the hand-written backward's are all under
+    `moe.<vertex>` and `experts`, and no `cond` is (a `while`'s own
+    condition is `while/cond`)."""
+    text = ComputationGraph(conf_of()).init().lower_step(
+        mds_of(batch_of(0))).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*/[^"]*)"', text))
+    experts = {p for p in paths if "/experts/" in p}
+    assert not [p for p in experts
+                if re.search(r"(?<!while)/cond(/|$)", p)]
+    # a product in an outlined function would read a path of its own
+    products = {p for p in paths if p.endswith("/ragged_dot_general")}
+    assert products and products <= experts
+    for vertex in ("l0_moe", "l1_moe"):
+        mine = {p for p in products if f"moe.{vertex}" in p.split(
+            "/experts/")[0]}
+        assert {p.split("/experts/")[1] for p in mine} == {
+            "while/body/ragged_dot_general",                # a forward
+            "while/body/jvp()/ragged_dot_general",          # the block again
+            "while/body/transpose(jvp())/ragged_dot_general"}, vertex
+        assert any(p.startswith("jit(step)/transpose(") for p in mine)
+    assert all("moe.l" in p for p in experts)
+
+
+SKEWED = dict(MODEL, num_hidden_layers=1,
+              deployment={"router_width": 16, "first_held": 0})
+
+
+@pytest.mark.parametrize("routing, blocks_run", [("even", 1.0),
+                                                 ("every_pair_held", 2.0)])
+def test_the_gauge_says_how_many_blocks_the_last_step_ran(routing,
+                                                          blocks_run):
+    """320 tokens, 2 of 16 experts each, 4 held: the default block is 512
+    of the 640 sorted pairs, 2 in the static worst case. Seeded routing
+    holds about 160 pairs here: one trip. A router of zeros ties every
+    expert and `top_k` takes experts 0 and 1 for every token: all 640
+    pairs on held experts, both blocks."""
+    w = weights(SKEWED)
+    if routing == "every_pair_held":
+        w["l0_moe"]["Wr"] = jnp.zeros_like(w["l0_moe"]["Wr"])
+    net = trainer(w, num_experts=16, n_layers=1, first_held=0)
+    net.fit(mds_of(batch_of(0, SKEWED, t=160)))
+    said = net.publish_layer_gauges()
+    held = 4 * said["moe.l0_moe.held_pairs_mean"]
+    assert said["moe.l0_moe.blocks_run"] == blocks_run == -(-held // 512)
+    assert (held == 640) == (routing == "every_pair_held")
 
 
 RESNET_STEP_SHA256 = \
