@@ -1,7 +1,7 @@
 from .attention import SelfAttentionLayer
 from .base import LAYER_REGISTRY, LayerConf, register_layer
-from .decoder import (LMHeadLayer, MoELayer, RMSNormLayer,
-                      SparseAttentionLayer, TokenEmbeddingLayer)
+from .decoder import (AttentionLayer, GatedMLPLayer, LMHeadLayer, MoELayer,
+                      RMSNormLayer, SparseAttentionLayer, TokenEmbeddingLayer)
 from .convolution import (ConvolutionLayer, GlobalPoolingLayer,
                           SubsamplingLayer, ZeroPaddingLayer)
 from .feedforward import (ActivationLayer, AutoEncoder, DenseLayer,
@@ -23,7 +23,8 @@ __all__ = [
     "GlobalPoolingLayer", "BatchNormalization", "LocalResponseNormalization",
     "BaseRecurrentLayer", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
     "SelfAttentionLayer", "TokenEmbeddingLayer", "RMSNormLayer",
-    "SparseAttentionLayer", "MoELayer", "LMHeadLayer", "RBM", "VariationalAutoencoder",
+    "SparseAttentionLayer", "AttentionLayer", "GatedMLPLayer", "MoELayer",
+    "LMHeadLayer", "RBM", "VariationalAutoencoder",
     "BernoulliReconstructionDistribution",
     "GaussianReconstructionDistribution",
 ]

@@ -1,6 +1,6 @@
-"""Layers of a sparse-attention mixture-of-experts decoder, for the containers.
+"""Layers of a mixture-of-experts decoder, for the containers.
 
-Beyond-reference capability (the reference predates all of it). Five layer
+Beyond-reference capability (the reference predates all of it). Seven layer
 kinds, each traced under its own `<kind>.<vertex>` scope by the container:
 
 - `tokenembedding`: ids [B, T] -> rows of a table; a second input
@@ -15,8 +15,19 @@ kinds, each traced under its own `<kind>.<vertex>` scope by the container:
   own (KL from the main attention's probabilities, summed over heads, to the
   indexer's softmax over the selected keys), which the layer returns through
   its state's `layer_loss` entry; the container adds it to the score.
+- `attention`: dense grouped-query attention, plain causal or inside a
+  `window` (key j visible to query i iff 0 <= i - j < window), over the
+  same kernels as `sparseattention` with the mask made from positions
+  inside them (inner scope `attend_full` or `attend_window`); a rotary turn
+  of the first `rotary_dim` slots of a head (inner scope `rotary`), plain
+  or YaRN-scaled; a per-head sigmoid gate on the attention's output, read
+  from the layer's input (inner scope `gate`).
+- `gatedmlp`: (SiLU(x Wg) * (x Wu)) Wd, the dense layer of a decoder.
 - `moe`: router over ALL experts, the top k a token, and this chip's share
-  of the experts (`parallel/moe.py` `held_experts_ffn`: nothing dropped).
+  of the experts (`parallel/moe.py` `held_experts_ffn`: nothing dropped);
+  with `shared_width`, a shared expert that every token passes, added
+  unscaled (inner scope `shared`); `routed_scale` multiplies the routed
+  weights.
 - `lmhead`: logits over a vocabulary (slice) and the masked mean
   cross-entropy over a sequence with integer labels, in token chunks so that
   no [tokens, vocabulary] array outlives a chunk.
@@ -276,6 +287,132 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
 
 
 # ---------------------------------------------------------------------------
+# dense attention, plain causal or windowed
+# ---------------------------------------------------------------------------
+def yarn_correction_range(rotary_dim, theta, original_max, beta_fast,
+                          beta_slow):
+    """(low, high): the slots between which YaRN's ramp runs, floored and
+    ceiled, clamped to the slots there are (`transformers`'
+    `_compute_yarn_parameters`, `truncate` true)."""
+    slot = lambda turns: (rotary_dim * math.log(
+        original_max / (turns * 2 * math.pi))) / (2 * math.log(theta))
+    return (max(math.floor(slot(beta_fast)), 0),
+            min(math.ceil(slot(beta_slow)), rotary_dim - 1))
+
+
+def rotary_inv_freq(rotary_dim, theta, yarn=None):
+    """The rotary_dim / 2 inverse frequencies (float32) and the factor cos
+    and sin are multiplied by. `yarn` (factor, original_max_position_
+    embeddings, beta_fast, beta_slow, attention_factor) blends each
+    frequency with its `factor`-th along a ramp over the slots."""
+    inv = theta ** (-(2.0 * jnp.arange(rotary_dim // 2, dtype=jnp.float32))
+                    / rotary_dim)
+    if yarn is None:
+        return inv, 1.0
+    factor, original_max, beta_fast, beta_slow, attention_factor = yarn
+    low, high = yarn_correction_range(rotary_dim, theta, original_max,
+                                      beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low + (0.001 if low == high else 0.0)), 0, 1)
+    return inv / factor * ramp + inv * (1 - ramp), attention_factor
+
+
+@register_layer("attention")
+@dataclass
+class AttentionLayer(_StatefulSequenceLayer):
+    """Grouped-query attention over positions 0 .. T-1: causal, inside
+    `window` where it is set. `n_heads` may differ from layer to layer of a
+    model over the same `n_kv_heads`."""
+    n_in: int = None
+    n_out: int = None
+    n_heads: int = 48
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    window: int = None          # None: every key up to the query
+    rope_theta: float = 1e4
+    rotary_dim: int = None      # the slots turned; None: all of a head's
+    yarn: tuple = None          # see `rotary_inv_freq`
+    init_std: float = 0.02
+
+    def init_state(self):
+        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
+
+    def gauges(self, state):
+        return dict(state)
+
+    def init_params(self, key, dtype=jnp.float32):
+        D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
+        k = jax.random.split(key, 5)
+        mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        return {"Wq": mk(k[0], (D, H * Dh)), "Wk": mk(k[1], (D, KV * Dh)),
+                "Wv": mk(k[2], (D, KV * Dh)), "Wo": mk(k[3], (H * Dh, D)),
+                "Wgate": mk(k[4], (D, H))}
+
+    def turn(self, x, positions):
+        """x [B, T, heads, Dh]: the first `rotary_dim` slots turned by
+        position (pairs (i, i + rotary_dim / 2)), the rest passed on."""
+        n = self.rotary_dim or self.head_dim
+        inv, factor = rotary_inv_freq(
+            n, self.rope_theta, self.yarn and tuple(self.yarn))
+        ang = positions.astype(jnp.float32)[..., None] * inv
+        turned = rotate_half(x[..., :n], jnp.cos(ang) * factor,
+                             jnp.sin(ang) * factor)
+        return turned if n == self.head_dim else jnp.concatenate(
+            [turned, x[..., n:]], -1)
+
+    def forward_with_state(self, params, x, state, *, train=False, rng=None,
+                           mask=None):
+        from ....ops.sparse_attention import (grid_steps_per_tile,
+                                              masked_attention)
+        B, T, _ = x.shape
+        H, KV, Dh = self.n_heads, self.n_kv_heads, self.head_dim
+        q = (x @ params["Wq"]).reshape(B, T, H, Dh)
+        k = (x @ params["Wk"]).reshape(B, T, KV, Dh)
+        v = (x @ params["Wv"]).reshape(B, T, KV, Dh)
+        with jax.named_scope("rotary"):
+            pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+            q, k = self.turn(q, pos), self.turn(k, pos)
+        heads = lambda a: jnp.moveaxis(a, 1, 2)         # [B, heads, T, Dh]
+        with jax.named_scope(
+                "attend_full" if self.window is None else "attend_window"):
+            o, _ = masked_attention(heads(q), heads(k), heads(v), None,
+                                    1.0 / math.sqrt(Dh), window=self.window)
+        o = jnp.moveaxis(o, 1, 2)                       # [B, T, H, Dh]
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(jnp.dot(
+                x, params["Wgate"], preferred_element_type=jnp.float32))
+            o = (o * g[..., None]).astype(x.dtype)
+        # the kernels' schedule is a function of T: a constant of the trace
+        return o.reshape(B, T, H * Dh) @ params["Wo"], {
+            "attend_grid_steps_per_tile": jnp.float32(grid_steps_per_tile(
+                T, window=self.window))}
+
+
+@register_layer("gatedmlp")
+@dataclass
+class GatedMLPLayer(_SequenceLayer):
+    n_in: int = None
+    n_out: int = None
+    width: int = 8192
+    init_std: float = 0.02
+
+    def init_params(self, key, dtype=jnp.float32):
+        k = jax.random.split(key, 3)
+        mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        return {"Wg": mk(k[0], (self.n_in, self.width)),
+                "Wu": mk(k[1], (self.n_in, self.width)),
+                "Wd": mk(k[2], (self.width, self.n_in))}
+
+    def forward(self, params, x, *, train=False, rng=None, mask=None,
+                state=None):
+        return gated_mlp(x, params["Wg"], params["Wu"], params["Wd"])
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+# ---------------------------------------------------------------------------
 # experts
 # ---------------------------------------------------------------------------
 @register_layer("moe")
@@ -283,7 +420,10 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
 class MoELayer(_StatefulSequenceLayer):
     """Routes over `n_experts`, `experts_per_token` a token; holds experts
     `first_held .. first_held + experts_held - 1` (all of them by default)
-    and computes their part of the result, every routed pair of it."""
+    and computes their part of the result, every routed pair of it. The
+    routed weights are multiplied by `routed_scale`; with `shared_width` a
+    shared expert of that width, which every chip of a deployment computes
+    alike for its own tokens, is added unscaled."""
     n_in: int = None
     n_out: int = None
     n_experts: int = 128
@@ -292,6 +432,8 @@ class MoELayer(_StatefulSequenceLayer):
     norm_topk_prob: bool = True
     experts_held: int = None
     first_held: int = 0
+    shared_width: int = None
+    routed_scale: float = 1.0
     init_std: float = 0.02
 
     def _held(self):
@@ -313,9 +455,15 @@ class MoELayer(_StatefulSequenceLayer):
         D, F, G = self.n_in, self.expert_width, self._held()
         k = jax.random.split(key, 4)
         mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
-        return {"Wr": mk(k[0], (D, self.n_experts)),
-                "Wg": mk(k[1], (G, D, F)), "Wu": mk(k[2], (G, D, F)),
-                "Wd": mk(k[3], (G, F, D))}
+        p = {"Wr": mk(k[0], (D, self.n_experts)),
+             "Wg": mk(k[1], (G, D, F)), "Wu": mk(k[2], (G, D, F)),
+             "Wd": mk(k[3], (G, F, D))}
+        if self.shared_width:
+            S = self.shared_width
+            k = jax.random.split(jax.random.fold_in(key, 1), 3)
+            p.update(Sg=mk(k[0], (D, S)), Su=mk(k[1], (D, S)),
+                     Sd=mk(k[2], (S, D)))
+        return p
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
@@ -326,9 +474,15 @@ class MoELayer(_StatefulSequenceLayer):
             experts, gates = route_all(params["Wr"], tokens,
                                        self.experts_per_token,
                                        self.norm_topk_prob)
+            if self.routed_scale != 1.0:
+                gates = gates * self.routed_scale
         y, counts, n_run = held_experts_ffn(
             tokens, experts, gates, params["Wg"], params["Wu"], params["Wd"],
             self.first_held, self.n_experts)
+        if self.shared_width:
+            with jax.named_scope("shared"):
+                y = y + gated_mlp(tokens, params["Sg"], params["Su"],
+                                  params["Sd"])
         counts = counts.astype(jnp.float32)
         return y.astype(x.dtype).reshape(B, T, D), {
             "held_pairs": counts,

@@ -1,4 +1,5 @@
-"""Attention under a per-pair selection mask: Pallas TPU kernels.
+"""Attention under a per-pair mask, given or made from positions: Pallas TPU
+kernels.
 
 The main attention of a `sparseattention` layer
 (`nn/conf/layers/decoder.py`) reads, for each query, only the keys its
@@ -22,13 +23,20 @@ chosen by the block index map, nothing is repeated in HBM.
   `lax.top_k` at k = 2048 of 8192 is a full sort on this chip, 2.7 ms for
   512 rows, and was 30% of the step.
 
+The same three kernels serve an `attention` layer's plain causal and
+windowed attention (`mask=None`): the tile's mask is then made inside the
+kernel from the block indices and an iota (key j visible to query i iff
+j <= i and, under a `window`, i - j < window), and only on the tiles that
+the diagonal or the band's far edge crosses; no [T, T] array exists.
+
 The mask is causal, so `masked_attention`'s three kernels walk only the
-(query block, key block) tiles with a pair on or under the diagonal: their
-grid is (batch x head, step), and `tile_schedule` lists each step's tile in
-int32 tables that `pltpu.PrefetchScalarGridSpec` hands to the index maps and
-to the kernel (a run's first step clears the accumulators, its last writes
-the result). No step is empty and no block is fetched unused; before PR 29
-the grid was the rectangle and 47% of its steps failed a `pl.when`.
+(query block, key block) tiles with a visible pair (on or under the
+diagonal and, under a window, inside the band): their grid is (batch x head,
+step), and `tile_schedule` lists each step's tile in int32 tables that
+`pltpu.PrefetchScalarGridSpec` hands to the index maps and to the kernel (a
+run's first step clears the accumulators, its last writes the result). No
+step is empty and no block is fetched unused; before PR 29 the grid was the
+rectangle and 47% of its steps failed a `pl.when`.
 `head_summed_probs` keeps the rectangle: its skipped steps write the zeros
 of a full [T, T] result. Inside the causal part every tile is computed,
 whatever its density. `work_keye.py` of the benchmark counts the SELECTED
@@ -54,10 +62,15 @@ NEG = -1e30      # a masked score; finite, so a row that has met no key yet
 FLOOR = -1e20    # where the forward's running maximum starts: under every
 #                  real score and so far above NEG that exp(NEG - m) is 0 by
 #                  itself; a row that has met no key keeps l = 0
-BLOCK = 1024     # query and key block of masked_attention's three kernels:
-#                  the fastest of 256 .. 2048 a side on the chip at T = 8192,
-#                  d = 128 (PERF.md section 6, PR 29; the scoped VMEM default
-#                  holds its 4 MB float32 tiles)
+BLOCK = 1024     # query and key block of masked_attention's three kernels,
+#                  under a mask operand and plain causal alike: the fastest
+#                  of 256 .. 2048 a side on the chip at T = 8192, d = 128
+#                  (PERF.md section 6, PR 29; the scoped VMEM default holds
+#                  its 4 MB float32 tiles) and of those measured at
+#                  T = 16384 with the mask made from positions (PR 32)
+WINDOW_BLOCK = (512, 512)     # (query, key) block under a window (PERF.md
+#                  section 6, PR 32: measured alone on the chip at
+#                  T = 16384, d = 128, window 512)
 
 
 def _params(interpret, semantics):
@@ -65,46 +78,86 @@ def _params(interpret, semantics):
         dimension_semantics=semantics)}
 
 
-def _scores(q_ref, k_ref, mask_ref, scale):
+def _scores(q_ref, k_ref, mask_ref, scale, band=None, tile=None):
+    """The tile's scaled scores, NEG where the pair is masked: by the mask
+    operand's tile, or (`mask_ref` None) by position. `band` is (block_q,
+    block_k, window or None), `tile` (query block, key block), or None for
+    a tile that keeps every pair and builds no mask."""
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    return jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG)
+    if mask_ref is not None:
+        return jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG)
+    if tile is None:
+        return s
+    (bq, bk, window), (i, j) = band, tile
+    # query position less key position, pair by pair
+    d = (i * bq - j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+         - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    seen = d >= 0 if window is None else (d >= 0) & (d < window)
+    return jnp.where(seen, s, NEG)
+
+
+def _each_tile(mask_ref, e, tile, step):
+    """Run `step`'s body once on this grid step: with a mask operand as it
+    is; by position, in one of two branches, `step(tile)` where the
+    diagonal or the band's far edge crosses the tile (CROSSED) and
+    `step(None)`, which builds no mask, where it keeps every pair. A branch
+    ends in the refs it writes; nothing the size of a tile leaves it (a
+    `lax.cond` around the select alone cost 2.8 us a tile of 4 MB)."""
+    if mask_ref is not None:
+        return step(None)
+    pl.when(e & CROSSED != 0)(lambda: step(tile))
+    pl.when(e & CROSSED == 0)(lambda: step(None))
 
 
 # ----------------------------------------------------------- tile schedule
 FIRST, LAST = 1, 2      # bits of a step's `edge`: its run's first, its last
+CROSSED = 4             # the diagonal or the band's far edge crosses the
+#                         tile: some of its pairs are masked by position
 
 
-def tile_schedule(T, bq, bk, heads=1):
+def tile_schedule(T, bq, bk, heads=1, window=None):
     """The tiles that `masked_attention`'s kernels visit at query blocks of
-    `bq` and key blocks of `bk`: those with a pair on or under the diagonal,
-    each once, and no other. `grid_steps` is the length of the kernels'
-    second grid axis a head (the first walks batch x head), and
-    `computing_steps` how many of those steps hold such a pair: all of
-    them. The tables are int32 numpy arrays, one entry a step:
+    `bq` and key blocks of `bk`: those with a visible pair (key j <= query
+    i and, under a `window`, i - j < window), each once, and no other.
+    `grid_steps` is the length of the kernels' second grid axis a head (the
+    first walks batch x head), and `computing_steps` how many of those steps
+    hold such a pair: all of them. The tables are int32 numpy arrays, one
+    entry a step:
 
     - `by_query` (i, j, edge): query block by query block, its key blocks
-      0 .. last(i) in order (forward, dQ: one run a query block);
+      first(i) .. last(i) in order (forward, dQ: one run a query block);
     - `by_key` (j, r, i, edge): key block by key block, inside it head r of
       `heads` (a key/value head's group of query heads), inside that the
-      query blocks first(j) .. nq-1 (dK/dV: one run a key block, so the sum
-      over the group's heads stays in VMEM).
+      query blocks first(j) .. last(j) (dK/dV: one run a key block, so the
+      sum over the group's heads stays in VMEM).
 
-    `edge` marks a run's first step (FIRST: clear the accumulators) and its
-    last (LAST: write the result)."""
+    `edge` marks a run's first step (FIRST: clear the accumulators), its
+    last (LAST: write the result) and a tile with a masked pair (CROSSED:
+    the diagonal or the band's far edge passes through it)."""
     nq, nk = T // bq, T // bk
-    last = lambda i: (i * bq + bq - 1) // bk     # the last key block i sees
-    first = lambda j: (j * bk) // bq             # the first that sees j
-    by_query = [(i, j, FIRST * (j == 0) | LAST * (j == last(i)))
-                for i in range(nq) for j in range(last(i) + 1)]
-    by_key = [(j, r, i, FIRST * (r == 0 and i == first(j))
-               | LAST * (r == heads - 1 and i == nq - 1))
-              for j in range(nk) for r in range(heads)
-              for i in range(first(j), nq)]
+    far = T if window is None else window     # i - j < far is always asked
+    # the key blocks query block i sees, the query blocks that see j
+    keys = lambda i: range(max(0, i * bq - far + 1) // bk,
+                           (i * bq + bq - 1) // bk + 1)
+    queries = lambda j: range(j * bk // bq,
+                              min(T - 1, j * bk + bk + far - 2) // bq + 1)
+    # i - j over the tile spans lo .. hi; every pair is seen iff all of
+    # it lies in 0 .. far - 1
+    crossed = lambda i, j: CROSSED * (
+        i * bq - (j * bk + bk - 1) < 0 or i * bq + bq - 1 - j * bk >= far)
+    ends = lambda x, run: FIRST * (x == run[0]) | LAST * (x == run[-1])
+    by_query = [(i, j, ends(j, keys(i)) | crossed(i, j))
+                for i in range(nq) for j in keys(i)]
+    by_key = [(j, r, i, FIRST * (r == 0 and i == queries(j)[0])
+               | LAST * (r == heads - 1 and i == queries(j)[-1])
+               | crossed(i, j))
+              for j in range(nk) for r in range(heads) for i in queries(j)]
     cols = lambda rows: tuple(np.asarray(c, np.int32) for c in zip(*rows))
     return {"grid_steps": len(by_query),
-            "computing_steps": sum(j * bk <= i * bq + bq - 1
-                                   for i, j, _ in by_query),
+            "computing_steps": sum(
+                j * bk <= i * bq + bq - 1 and i * bq - (j * bk + bk - 1) < far
+                for i, j, _ in by_query),
             "by_query": cols(by_query), "by_key": cols(by_key)}
 
 
@@ -121,10 +174,20 @@ def _call(kernel, tables, grid, in_specs, out_specs, out_shape, scratch,
             *(jnp.asarray(t) for t in tables), *operands)
 
 
+def _given(mask, qkv, its, rest=()):
+    """A kernel's operands or specs in order: q, k, v, then the mask's
+    (`its`) where there is a mask operand and nothing where the kernels
+    make the mask from positions, then the rest."""
+    return [*qkv, *([its] if mask is not None else []), *rest]
+
+
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, mask_ref, o_ref,
-                lse_ref, m_ref, l_ref, acc_ref, *, scale):
-    e = edge[pl.program_id(1)]
+def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
+                band=None):
+    *mask_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+    mask_ref = mask_ref[0] if mask_ref else None
+    t = pl.program_id(1)
+    e = edge[t]
 
     @pl.when(e & FIRST != 0)
     def _init():
@@ -132,16 +195,19 @@ def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, mask_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    s = _scores(q_ref, k_ref, mask_ref, scale)
-    m_prev = m_ref[:, :1]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur)       # a masked pair: exp(NEG - m_cur) is 0
-    l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[:, :1] = m_cur
+    def step(tile):
+        s = _scores(q_ref, k_ref, mask_ref, scale, band, tile)
+        m_prev = m_ref[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)       # a masked pair: exp(NEG - m_cur) is 0
+        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:, :1] = m_cur
+
+    _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
 
     @pl.when(e & LAST != 0)
     def _emit():
@@ -154,7 +220,7 @@ def _specs(bq, bk, d, R, H):
     """Block specs of a (batch * head b, step t) grid whose step t is tile
     (i_tab[t], j_tab[t]) of `tile_schedule`'s `by_query`: q-shaped,
     k/v-shaped (the group's head), the mask's tile, a per-row column. No
-    step lies above the diagonal, so nothing is fetched that is not used;
+    step lies outside the band, so nothing is fetched that is not used;
     a block whose index the next step keeps is not fetched again."""
     vm = {"memory_space": pltpu.VMEM}
     return (pl.BlockSpec((1, bq, d), lambda b, t, i, j, e: (b, i[t], 0), **vm),
@@ -165,66 +231,86 @@ def _specs(bq, bk, d, R, H):
             pl.BlockSpec((1, bq, 1), lambda b, t, i, j, e: (b, i[t], 0), **vm))
 
 
-def _fwd(q, k, v, mask, scale, bq, bk, interpret):
-    """q [B*H, T, d], k/v [B*KV, T, d], mask int8 [B, T, T]."""
+def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None):
+    """q [B*H, T, d], k/v [B*KV, T, d], mask int8 [B, T, T] or None (by
+    position: causal, inside `window`)."""
     BH, T, d = q.shape
     q_spec, kv_spec, mask_spec, row_spec = _specs(
-        bq, bk, d, BH // k.shape[0], BH // mask.shape[0])
-    sched = tile_schedule(T, bq, bk)
+        bq, bk, d, BH // k.shape[0],
+        1 if mask is None else BH // mask.shape[0])
+    sched = tile_schedule(T, bq, bk, window=window)
+    band = None if mask is not None else (bq, bk, window)
     return _call(
-        functools.partial(_fwd_kernel, scale=scale), sched["by_query"],
-        (BH, sched["grid_steps"]), [q_spec, kv_spec, kv_spec, mask_spec],
+        functools.partial(_fwd_kernel, scale=scale, band=band),
+        sched["by_query"], (BH, sched["grid_steps"]),
+        _given(mask, (q_spec, kv_spec, kv_spec), mask_spec),
         [q_spec, row_spec],
         [jax.ShapeDtypeStruct((BH, T, d), q.dtype),
          jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         [pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, d), jnp.float32)],
-        "sparse_attention_fwd", interpret, (q, k, v, mask))
+        "sparse_attention_fwd", interpret, _given(mask, (q, k, v), mask))
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, mask_ref, do_ref,
-               lse_ref, delta_ref, dq_ref, acc_ref, *, scale):
-    e = edge[pl.program_id(1)]
+def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
+               band=None):
+    *mask_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
+    mask_ref = mask_ref[0] if mask_ref else None
+    t = pl.program_id(1)
+    e = edge[t]
 
     @pl.when(e & FIRST != 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale) - lse_ref[0])
-    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0])
-    acc_ref[:] += jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    def step(tile):
+        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile)
+                    - lse_ref[0])
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        acc_ref[:] += jax.lax.dot_general(
+            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+    _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
 
     @pl.when(e & LAST != 0)
     def _emit():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, mask_ref,
-                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                scale):
-    e = edge[pl.program_id(1)]
+def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, *rest,
+                scale, band=None):
+    (*mask_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
+     dv_acc) = rest
+    mask_ref = mask_ref[0] if mask_ref else None
+    t = pl.program_id(1)
+    e = edge[t]
 
     @pl.when(e & FIRST != 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale) - lse_ref[0])
-    dv_acc[:] += jax.lax.dot_general(
-        p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0])
-    dk_acc[:] += jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    def step(tile):
+        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile)
+                    - lse_ref[0])
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        dk_acc[:] += jax.lax.dot_general(
+            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+    _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
 
     @pl.when(e & LAST != 0)
     def _emit():
@@ -232,26 +318,29 @@ def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, mask_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret):
+def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret, window=None):
     BH, T, d = q.shape
     BKV = k.shape[0]
-    H, R, KV = BH // mask.shape[0], BH // BKV, BKV // mask.shape[0]
+    B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
+    H, R, KV = BH // B, BH // BKV, BKV // B
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
                     keepdims=True)
     vm = {"memory_space": pltpu.VMEM}
-    operands = (q, k, v, mask, do, lse, delta)
+    band = None if mask is not None else (bq, bk, window)
+    operands = _given(mask, (q, k, v), mask, (do, lse, delta))
     q_spec, kvq_spec, mask_spec, row_spec = _specs(bq, bk, d, R, H)
-    sched = tile_schedule(T, bq, bk)
+    sched = tile_schedule(T, bq, bk, window=window)
     dq = _call(
-        functools.partial(_dq_kernel, scale=scale), sched["by_query"],
-        (BH, sched["grid_steps"]),
-        [q_spec, kvq_spec, kvq_spec, mask_spec, q_spec, row_spec, row_spec],
+        functools.partial(_dq_kernel, scale=scale, band=band),
+        sched["by_query"], (BH, sched["grid_steps"]),
+        _given(mask, (q_spec, kvq_spec, kvq_spec), mask_spec,
+               (q_spec, row_spec, row_spec)),
         q_spec, jax.ShapeDtypeStruct((BH, T, d), q.dtype),
         [pltpu.VMEM((bq, d), jnp.float32)], "sparse_attention_dq", interpret,
         operands)
     # step t of key/value head g: key block j[t], head r[t] of g's group,
     # query block i[t] (`tile_schedule`'s `by_key`)
-    sched = tile_schedule(T, bq, bk, heads=R)
+    sched = tile_schedule(T, bq, bk, heads=R, window=window)
     qh_spec = pl.BlockSpec(
         (1, bq, d), lambda g, t, j, r, i, e: (g * R + r[t], i[t], 0), **vm)
     rowh_spec = pl.BlockSpec(
@@ -259,12 +348,13 @@ def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret):
     kv_spec = pl.BlockSpec(
         (1, bk, d), lambda g, t, j, r, i, e: (g, j[t], 0), **vm)
     dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale), sched["by_key"],
-        (BKV, R * sched["grid_steps"]),
-        [qh_spec, kv_spec, kv_spec,
-         pl.BlockSpec((1, bq, bk),
-                      lambda g, t, j, r, i, e: (g // KV, i[t], j[t]), **vm),
-         qh_spec, rowh_spec, rowh_spec],
+        functools.partial(_dkv_kernel, scale=scale, band=band),
+        sched["by_key"], (BKV, R * sched["grid_steps"]),
+        _given(mask, (qh_spec, kv_spec, kv_spec),
+               pl.BlockSpec((1, bq, bk),
+                            lambda g, t, j, r, i, e: (g // KV, i[t], j[t]),
+                            **vm),
+               (qh_spec, rowh_spec, rowh_spec)),
         [kv_spec, kv_spec],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -274,15 +364,20 @@ def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret):
 
 
 # --------------------------------------------------------------- public API
-def _blocks(T, block_q, block_k):
-    return _divisor_block(T, block_q), _divisor_block(T, block_k)
+def _blocks(T, block_q, block_k, window=None):
+    """The kernels' block shape at T: what was asked for, else the shape
+    measured for the schedule (the causal walk, or the band under a
+    window), each side cut to a divisor of T."""
+    bq, bk = (BLOCK, BLOCK) if window is None else WINDOW_BLOCK
+    return _divisor_block(T, block_q or bq), _divisor_block(T, block_k or bk)
 
 
-def grid_steps_per_tile(T, block_q=BLOCK, block_k=BLOCK):
+def grid_steps_per_tile(T, block_q=None, block_k=None, window=None):
     """Grid steps over computing steps of `masked_attention`'s kernels at T:
     1.0 when no step is empty (PR 28's rectangular grid of 512 x 512 read
     256 / 136 = 1.88 at T = 8192)."""
-    sched = tile_schedule(T, *_blocks(T, block_q, block_k))
+    sched = tile_schedule(T, *_blocks(T, block_q, block_k, window),
+                          window=window)
     return sched["grid_steps"] / sched["computing_steps"]
 
 
@@ -290,22 +385,27 @@ def _flat(a):
     return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def masked_attention(q, k, v, mask, scale, block_q=BLOCK, block_k=BLOCK,
-                     interpret=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def masked_attention(q, k, v, mask, scale, block_q=None, block_k=None,
+                     interpret=None, window=None):
     """softmax over the keys `mask` keeps of q k^T * scale, times v.
     q [B, H, T, d]; k, v [B, KV, T, d] (H a multiple of KV); mask int8
     [B, T, T], nonzero where query t reads key s, causal (s <= t) and with
-    at least one key a query. A T no longer than a block is one tile.
-    Returns (o [B, H, T, d], lse [B, H, T] f32)."""
-    return _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret)[0]
+    at least one key a query; or None: query t reads the keys s <= t and,
+    under a `window`, t - s < window, the mask made inside the kernels
+    from positions. A T no longer than a block is one tile. Returns
+    (o [B, H, T, d], lse [B, H, T] f32)."""
+    return _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret,
+                       window)[0]
 
 
-def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret):
+def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret, window):
+    if mask is not None and window is not None:
+        raise ValueError("a mask operand carries its own window")
     B, H, T, d = q.shape
-    bq, bk = _blocks(T, block_q, block_k)
+    bq, bk = _blocks(T, block_q, block_k, window)
     o, lse = _fwd(_flat(q), _flat(k), _flat(v), mask, scale, bq, bk,
-                  _resolve_interpret(interpret))
+                  _resolve_interpret(interpret), window)
     # named, so that a rematerialising caller can keep them (with the mask
     # it made) and not run the forward kernel again for the backward
     out = (checkpoint_name(o.reshape(q.shape), KEEP),
@@ -313,13 +413,13 @@ def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret):
     return out, (q, k, v, mask, *out)
 
 
-def _masked_bwd(scale, block_q, block_k, interpret, res, g):
+def _masked_bwd(scale, block_q, block_k, interpret, window, res, g):
     q, k, v, mask, o, lse = res
     B, H, T, d = q.shape
-    bq, bk = _blocks(T, block_q, block_k)
+    bq, bk = _blocks(T, block_q, block_k, window)
     dq, dk, dv = _bwd(_flat(q), _flat(k), _flat(v), mask, _flat(o),
                       lse.reshape(B * H, T, 1), _flat(g[0]), scale, bq, bk,
-                      _resolve_interpret(interpret))
+                      _resolve_interpret(interpret), window)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), None
 
 

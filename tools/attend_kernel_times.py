@@ -1,9 +1,15 @@
 """One call of each masked-attention kernel (ops/sparse_attention.py: forward,
 dQ, dK/dV) timed alone on the chip at a cell's shape, block shape by block
 shape: the numbers PERF.md gives for "a call", and what the module's `BLOCK`
-was chosen from. One JSON line a (kernel, block shape).
+and `WINDOW_BLOCK` were chosen from. One JSON line a (kernel, block shape).
+`--schedule mask` (the default) is `train-vl8k`'s: a mask operand at
+T = 8192, 32 / 4 heads; `causal` and `window` are `train-lc16k`'s
+full and window layers: the mask made from positions at T = 16384, 48 or
+64 query heads over 8 (`--T`, `--heads`, `--kv`, `--window` override).
 
     chiprun -- python tools/attend_kernel_times.py                  # this tree
+    chiprun -- python tools/attend_kernel_times.py --schedule window \\
+        --blocks 256x256,256x512,512x512
     chiprun -- python tools/attend_kernel_times.py \\
         --module .chip_tree/parent/deeplearning4j_tpu/ops/sparse_attention.py
 
@@ -27,8 +33,11 @@ sys.path.insert(0, ROOT)
 import jax                                                        # noqa: E402
 import jax.numpy as jnp                                           # noqa: E402
 
-# one sequence of keye-vl-2.0-30b-a3b's attention, as a layer's row calls it
-HEADS, KV, D, TOPK = 32, 4, 128, 2048
+# one sequence of a cell's attention, as a layer's row calls it: (T, query
+# heads, key/value heads, window) by schedule
+D, TOPK = 128, 2048
+SHAPES = {"mask": (8192, 32, 4, None), "causal": (16384, 48, 8, None),
+          "window": (16384, 64, 8, 512)}
 REPS, SETS = 20, 5      # calls a timing, timings a median
 
 
@@ -51,33 +60,39 @@ def kernels(mod, a):
     nobody reads takes its kernel out of the program."""
     for block in a.blocks.split(","):
         bq, bk = (int(x) for x in block.split("x"))
-        for name, fn in one_each(mod, D ** -0.5, bq, bk).items():
+        for name, fn in one_each(mod, D ** -0.5, bq, bk, a).items():
             if name in a.kernels.split(","):
                 yield name, bq, bk, jax.jit(fn)
 
 
-def one_each(mod, scale, bq, bk):
+def one_each(mod, scale, bq, bk, a):
     flat = mod._flat
+    # a mask operand, or (by position) none and the window
+    by = {} if a.schedule == "mask" else {"window": a.window}
+    given = (lambda m: m) if a.schedule == "mask" else (lambda m: None)
 
     def bwd(q, k, v, mask, o, lse, do):
         B, H, T, _ = q.shape
-        return mod._bwd(flat(q), flat(k), flat(v), mask, flat(o),
+        return mod._bwd(flat(q), flat(k), flat(v), given(mask), flat(o),
                         lse.reshape(B * H, T, 1), flat(do), scale, bq, bk,
-                        False)
+                        False, **by)
 
     return {
         "fwd": lambda q, k, v, mask, o, lse, do: mod._fwd(
-            flat(q), flat(k), flat(v), mask, scale, bq, bk, False),
+            flat(q), flat(k), flat(v), given(mask), scale, bq, bk, False,
+            **by),
         "dq": lambda *a: bwd(*a)[0],
         "dkv": lambda *a: bwd(*a)[1:]}
 
 
 def shapes(a, sharding=None):
-    B, H, T, d = 1, HEADS, a.T, D
+    B, H, KV, T, d = 1, a.heads, a.kv, a.T, D
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     bf = jnp.bfloat16
+    # by position there is no mask: a [1, 1, 1] stands in its place
+    M = T if a.schedule == "mask" else 1
     return (s((B, H, T, d), bf), s((B, KV, T, d), bf), s((B, KV, T, d), bf),
-            s((B, T, T), jnp.int8), s((B, H, T, d), bf),
+            s((B, M, M), jnp.int8), s((B, H, T, d), bf),
             s((B, H, T), jnp.float32), s((B, H, T, d), bf))
 
 
@@ -90,6 +105,8 @@ def arrays(a, seed=0):
     sh = shapes(a)
     q, k, v, do = (jax.random.normal(kk, s.shape, s.dtype)
                    for kk, s in zip(ks, (sh[0], sh[1], sh[2], sh[6])))
+    if a.schedule != "mask":
+        return q, k, v, jnp.ones(sh[3].shape, jnp.int8), do
     t = jnp.arange(a.T)
     u = jax.random.uniform(ks[4], (a.T, a.T))
     keep = (t[None, :] <= t[:, None]) & (
@@ -118,12 +135,21 @@ def main():
     p.add_argument("--module", default=None)
     p.add_argument("--blocks", default="512x512,1024x512,512x1024,1024x1024")
     p.add_argument("--kernels", default="fwd,dq,dkv")
-    p.add_argument("--T", type=int, default=8192)
+    p.add_argument("--schedule", choices=sorted(SHAPES), default="mask")
+    p.add_argument("--T", type=int, default=None)
+    p.add_argument("--heads", type=int, default=None)
+    p.add_argument("--kv", type=int, default=None)
+    p.add_argument("--window", type=int, default=None)
     p.add_argument("--compile-only", action="store_true")
     a = p.parse_args()
+    for name, default in zip(("T", "heads", "kv", "window"),
+                             SHAPES[a.schedule]):
+        if getattr(a, name) is None:
+            setattr(a, name, default)
     mod = load(a.module)
     say = lambda **kw: print(json.dumps(
-        {"module": a.module or "this tree", "T": a.T, **kw}), flush=True)
+        {"module": a.module or "this tree", "schedule": a.schedule,
+         "T": a.T, "heads": a.heads, "window": a.window, **kw}), flush=True)
 
     if a.compile_only:
         from jax.experimental import topologies
@@ -145,8 +171,11 @@ def main():
               "refusing to measure", file=sys.stderr)
         return 4
     q, k, v, mask, do = arrays(a)
-    o, lse = jax.jit(lambda *x: mod.masked_attention(*x, D ** -0.5))(
-        q, k, v, mask)
+    o, lse = jax.jit(lambda q, k, v, mask: (
+        mod.masked_attention(q, k, v, mask, D ** -0.5)
+        if a.schedule == "mask" else
+        mod.masked_attention(q, k, v, None, D ** -0.5, window=a.window)))(
+            q, k, v, mask)
     operands = (q, k, v, mask, o, lse, do)
     for name, bq, bk, fn in kernels(mod, a):
         try:

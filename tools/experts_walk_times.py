@@ -1,8 +1,11 @@
-"""One `moe` layer of keye-vl-2.0-30b-a3b (router, sort, the held experts'
-walk over blocks; parallel/moe.py `route_all` + `held_experts_ffn`), forward
-and backward, timed alone on the chip at the cell's shape, block size by
-block size: what PERF.md gives for "a layer-step" of the experts, and what
-the default block size was kept from. One JSON line a block size.
+"""One `moe` layer (router, sort, the held experts' walk over blocks;
+parallel/moe.py `route_all` + `held_experts_ffn`), forward and backward,
+timed alone on the chip at a cell's shape, block size by block size: what
+PERF.md gives for "a layer-step" of the experts, and what the default block
+size was kept from. One JSON line a block size. `--shape vl8k` (the
+default) is keye-vl-2.0-30b-a3b's layer, `--shape lc16k` laguna-xs.2's
+routed experts (32 of 256 held, width 512; the shared expert is not the
+walk's).
 
     chiprun -- python tools/experts_walk_times.py                  # this tree
     chiprun -- python tools/experts_walk_times.py --block-rows 0 \\
@@ -31,8 +34,12 @@ sys.path.insert(0, ROOT)
 import jax                                                        # noqa: E402
 import jax.numpy as jnp                                           # noqa: E402
 
-# train-vl8k: 2 rows of 8,192 tokens, 8 of 128 experts a token, 16 held
-N, D, F, E, K, G, FIRST = 16_384, 2048, 768, 128, 8, 16, 0
+# (tokens a step, width, expert width, experts, experts a token, held):
+# train-vl8k 2 rows of 8,192; train-lc16k 1 row of 16,384
+SHAPES = {"vl8k": (16_384, 2048, 768, 128, 8, 16),
+          "lc16k": (16_384, 2048, 512, 256, 8, 32)}
+N, D, F, E, K, G = SHAPES["vl8k"]
+FIRST = 0
 REPS, SETS = 10, 5      # calls a timing, timings a median
 
 
@@ -108,12 +115,16 @@ def main():
     p.add_argument("--module", default=None)
     p.add_argument("--block-rows", default="0,8192,4096")
     p.add_argument("--skew", action="store_true")
+    p.add_argument("--shape", choices=sorted(SHAPES), default="vl8k")
     p.add_argument("--compile-only", action="store_true")
     a = p.parse_args()
+    global N, D, F, E, K, G
+    N, D, F, E, K, G = SHAPES[a.shape]
     mod = load(a.module)
     sizes = [int(b) for b in a.block_rows.split(",")]
     say = lambda **kw: print(json.dumps(
-        {"module": a.module or "this tree", "skew": a.skew, **kw}),
+        {"module": a.module or "this tree", "shape": a.shape,
+         "skew": a.skew, **kw}),
         flush=True)
 
     if a.compile_only:
