@@ -134,6 +134,13 @@ class LayerConf:
         left (ComputationGraph.publish_layer_gauges)."""
         return {}
 
+    def remat_keeps(self):
+        """Checkpoint names (`jax.ad_checkpoint.checkpoint_name`) of arrays
+        this layer's forward names and a rematerialised segment holding it
+        keeps for the backward; all else is computed again
+        (ComputationGraph._remat_plan). A property of the kind."""
+        return ()
+
     # ------------------------------------------------------------------
     # Regularization score contribution (reference BaseLayer.calcL1/calcL2)
     # ------------------------------------------------------------------

@@ -340,6 +340,14 @@ class AttentionLayer(_StatefulSequenceLayer):
     def gauges(self, state):
         return dict(state)
 
+    def remat_keeps(self):
+        # the kernel's o and lse, which `masked_attention` names: its
+        # backward's operands, so the container's segment does not run the
+        # forward kernel a second time (`sparseattention` keeps them across
+        # its own row's checkpoint and names nothing here)
+        from ....ops.sparse_attention import KEEP
+        return (KEEP,)
+
     def init_params(self, key, dtype=jnp.float32):
         D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
         k = jax.random.split(key, 5)

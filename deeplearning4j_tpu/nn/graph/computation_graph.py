@@ -211,27 +211,39 @@ class ComputationGraph(Trainer):
 
     def _remat_plan(self):
         """Segment the topological order at element-wise (residual-add)
-        vertex boundaries. Returns (segment-id per vertex, n_segments)."""
+        vertex boundaries. Returns (segment-id per vertex, n_segments,
+        {segment-id: the checkpoint names its layers keep across the
+        segment's forward and backward (`LayerConf.remat_keeps`)}, the
+        segments that keep nothing left out). The gauge
+        `train.remat_kept_segments` says how many checkpoints carry such a
+        policy (the final segment has no checkpoint)."""
         if self._remat_plan_cache is None:
             from ..conf.graph_vertices import ElementWiseVertex
-            seg, s = {}, 0
+            seg, s, keeps = {}, 0, {}
             for name in self.conf.topological_order:
                 seg[name] = s
                 spec = self.conf.vertices[name]
+                names = spec.conf.remat_keeps() if spec.is_layer else ()
+                if names:
+                    keeps[s] = tuple(sorted({*keeps.get(s, ()), *names}))
                 if (not spec.is_layer
                         and isinstance(spec.conf, ElementWiseVertex)):
                     s += 1
-            self._remat_plan_cache = (seg, s + 1)
+            keeps.pop(s, None)
+            self._remat_plan_cache = (seg, s + 1, keeps)
+            obs.default_registry().gauge("train.remat_kept_segments").set(
+                len(keeps))
         return self._remat_plan_cache
 
     def _apply_graph_remat(self, params, state, inputs, *, train, rng):
         """`_apply_graph` with each residual segment under `jax.checkpoint`:
         only segment-boundary activations become autodiff residuals; the
         interior (conv outputs, BN normalized, ReLU) is recomputed during
-        the backward. Only reached for mask-free, carry-free graphs (the
+        the backward, but for what a layer names for keeping
+        (`_remat_plan`). Only reached for mask-free, carry-free graphs (the
         CNN shape this lever targets)."""
         cdt = self.compute_dtype
-        seg_of, n_seg = self._remat_plan()
+        seg_of, n_seg, keeps = self._remat_plan()
         order = self.conf.topological_order
         segments = [[] for _ in range(n_seg)]
         for name in order:
@@ -289,8 +301,13 @@ class ComputationGraph(Trainer):
                 return [local[o] for o in _outs], st_new
 
             # the final segment (head + loss inputs) gains nothing from
-            # recompute — its residuals back the loss directly
-            call = jax.checkpoint(seg_fn) if si < n_seg - 1 else seg_fn
+            # recompute — its residuals back the loss directly. Where a
+            # layer names arrays for keeping, the segment recomputes all
+            # but those (no policy is `jax.checkpoint(seg_fn)`)
+            policy = (jax.checkpoint_policies.save_only_these_names(
+                *keeps[si]) if si in keeps else None)
+            call = (jax.checkpoint(seg_fn, policy=policy)
+                    if si < n_seg - 1 else seg_fn)
             outs, st_new = call({n: params[n] for n in layer_names},
                                 {n: state[n] for n in stateful},
                                 [acts[i] for i in ext_in])

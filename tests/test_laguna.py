@@ -406,9 +406,13 @@ def test_configuration_round_trips_and_names_its_kinds():
     assert attn["l0_attn"].yarn and not attn["l1_attn"].yarn
 
 
+def paths_of(text):
+    return set(re.findall(r'loc\("([^"]*/[^"]*)"', text))
+
+
 def test_the_inner_scopes_are_on_the_lowered_step_forward_and_backward(
         lowered):
-    paths = set(re.findall(r'loc\("([^"]*/[^"]*)"', lowered))
+    paths = paths_of(lowered)
     for scope, kind, vertices in (
             ("attend_full", "attention", ("l0_attn", "l4_attn")),
             ("attend_window", "attention", ("l1_attn", "l2_attn", "l3_attn")),
@@ -427,6 +431,73 @@ def test_the_inner_scopes_are_on_the_lowered_step_forward_and_backward(
         full = re.search(r"attention\.l[04]_attn", p) is not None
         assert ("/attend_full/" in p) == full, p
         assert ("/attend_window/" in p) == (not full), p
+
+
+def test_a_segment_keeps_its_attention_kernels_output_and_recomputes_the_rest(
+        lowered, monkeypatch):
+    """`attention` names the kernel's o and lse for keeping
+    (`remat_keeps`), so the forward kernel of each layer is in the step's
+    forward once and not again in its segment's rematerialisation, where
+    rotary, gate and the projections still are."""
+    paths = paths_of(lowered)
+    again = {p for p in paths if "/rematted_computation/" in p}
+    for i in range(5):
+        mine = {p for p in paths if f"attention.l{i}_attn/" in p
+                or f"attention.l{i}_attn)/" in p}
+        fwd = {p for p in mine if p.endswith("sparse_attention_fwd/pallas_call")}
+        assert len(fwd) == 1 and not fwd & again, fwd
+        assert "transpose(" not in next(iter(fwd))
+        for kernel in ("sparse_attention_dq", "sparse_attention_dkv"):
+            assert any(p.endswith(f"{kernel}/pallas_call") for p in mine)
+        for what in ("/rotary/", "/gate/", "/dot_general"):
+            assert any(what in p for p in mine & again), (i, what)
+    # the parent's text, where the layer names nothing: the kernel twice
+    monkeypatch.setattr(decoder.AttentionLayer, "remat_keeps", lambda self: ())
+    text = ComputationGraph(conf_of()).init().lower_step(
+        mds_of(batch_of(0))).as_text(debug_info=True)
+    twice = {p for p in paths_of(text)
+             if p.endswith("sparse_attention_fwd/pallas_call")}
+    assert len(twice) == 10
+    assert sum("/rematted_computation/" in p for p in twice) == 5
+
+
+@pytest.mark.parametrize("layer", [0, 1], ids=["full", "window"])
+def test_fit_under_the_keeping_policy_is_fit_under_plain_rematerialisation(
+        layer, monkeypatch):
+    """Three steps of `fit` on one layer of the pattern (0: full attention
+    and the dense MLP; 1: window attention and the experts): losses and
+    parameters are the bits that `jax.checkpoint(seg_fn)` alone leaves,
+    the kept o and lse being what the second run of the kernel wrote."""
+    w = weights()
+
+    def three_steps():
+        net = ComputationGraph(conf_of(layers=[layer])).init()
+        net._params = {n: jax.tree.map(jnp.array, w.get(n, d))
+                       for n, d in net._params.items()}
+        losses = []
+        for i in range(3):
+            net.fit(mds_of(batch_of(i)))
+            losses.append(np.asarray(net._score))
+        return net, losses, jax.tree.map(np.asarray, net._params)
+
+    kept, losses, params = three_steps()
+    assert kept._remat_plan()[2] == {0: (sa.KEEP,)}
+    monkeypatch.setattr(decoder.AttentionLayer, "remat_keeps", lambda self: ())
+    plain, plain_losses, plain_params = three_steps()
+    assert plain._remat_plan()[2] == {}
+    np.testing.assert_array_equal(losses, plain_losses)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(plain_params)):
+        np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(params[f"l{layer}_attn"]["Wq"],
+                              np.asarray(w[f"l{layer}_attn"]["Wq"]))
+
+
+def test_the_gauge_counts_the_segments_that_keep():
+    from deeplearning4j_tpu import obs
+    gauge = obs.default_registry().gauge("train.remat_kept_segments")
+    net = ComputationGraph(conf_of()).init()
+    assert net._remat_plan()[2] == {i: (sa.KEEP,) for i in (0, 2, 4, 6, 8)}
+    assert gauge.value == 5
 
 
 def test_no_square_array_of_the_sequence_is_in_the_lowered_step(monkeypatch):

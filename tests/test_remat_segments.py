@@ -1,15 +1,23 @@
 """Segment gradient checkpointing (ComputationGraph remat_segments) —
 the structural bytes/step lever for HBM-bound CNN training (PERF.md r4).
 Numerics must be IDENTICAL to the default path: remat changes what the
-backward stores, never what it computes."""
+backward stores, never what it computes. A segment keeps what a layer of
+it names for keeping (`LayerConf.remat_keeps`: the `attention` kind alone,
+tests/test_laguna.py); every other kind's segments lower as they did."""
+import hashlib
+import os
+import sys
+
 import numpy as np
 import pytest
 
 jax = __import__("jax")
 jnp = jax.numpy
 
-from deeplearning4j_tpu import InputType, NeuralNetConfiguration
-from deeplearning4j_tpu.datasets.dataset import DataSet
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from deeplearning4j_tpu import InputType, NeuralNetConfiguration, obs
+from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.nn.conf.graph_vertices import ElementWiseVertex
 from deeplearning4j_tpu.nn.conf.layers import (ActivationLayer,
                                                BatchNormalization,
@@ -59,7 +67,8 @@ def _data(seed=0):
 class TestRematSegments:
     def test_plan_segments_at_adds(self):
         net = ComputationGraph(_residual_conf(), remat_segments=True).init()
-        seg_of, n_seg = net._remat_plan()
+        seg_of, n_seg, keeps = net._remat_plan()
+        assert keeps == {}                      # no kind here names any
         assert n_seg == 3                       # two adds -> three segments
         assert seg_of["b0_c1"] == 0
         assert seg_of["b0_out"] == 1            # first vertex after add 0
@@ -102,5 +111,64 @@ class TestRematSegments:
         conf = resnet50_conf(height=32, width=32, num_classes=4,
                              data_type="float32")
         net = ComputationGraph(conf, remat_segments=True)
-        _, n_seg = net._remat_plan()
+        _, n_seg, _ = net._remat_plan()
         assert n_seg == 17                      # 16 bottleneck adds + head
+
+
+def _tiny_keye():
+    """The rehearsal's Keye (benchmarks/configs/tiny-keye.json) and a batch
+    of two rows of T = 128, the first 16 positions an image's."""
+    from benchmarks.drivers import train_vl
+    from benchmarks.harness import loader
+    rows, t = 2, 128
+    mds = MultiDataSet(
+        [jnp.zeros((rows, t), jnp.int32),
+         jnp.zeros((rows, 16, 64), jnp.bfloat16),
+         jnp.zeros((rows, t, 3), jnp.int32)],
+        [jnp.zeros((rows, t), jnp.int32)],
+        labels_masks=[jnp.ones((rows, t), jnp.float32)])
+    return train_vl.build(loader.load_json("configs", "tiny-keye.json")), mds
+
+
+def _residual_cnn():
+    x, y = _data()
+    return (ComputationGraph(_residual_conf(), remat_segments=True).init(),
+            DataSet(x, y))
+
+
+def _small_resnet50():
+    from deeplearning4j_tpu.models.zoo.resnet import resnet50_conf
+    conf = resnet50_conf(height=32, width=32, num_classes=10)
+    return (ComputationGraph(conf, remat_segments=True).init(),
+            DataSet(jnp.zeros((2, 32, 32, 3), jnp.bfloat16),
+                    jnp.zeros((2, 10), jnp.float32)))
+
+
+# sha256 of the step's lowered text at the parent commit of PR 33 (ed8814c),
+# under the suite's x64, as `RESNET_STEP_SHA256` in tests/test_keye_vl.py
+# holds the step without rematerialisation. A PR that changes one of these
+# steps on purpose computes its hash anew (the body of the test below).
+REMAT_STEP_SHA256 = {
+    "tiny-keye":
+        "cc192ee3539110036f7eecd734954d3b683f86270d1ce70027f57195622022ff",
+    "residual-cnn":
+        "9a7f306c2963ca5d54481136528d1580c1fdf06d524de743784f6a466d7a2cb1",
+    "resnet50":
+        "144a668f3bc9be153199e831b97730cad49fda295c0a7eb130344fb296fb304b",
+}
+GRAPHS = {"tiny-keye": _tiny_keye, "residual-cnn": _residual_cnn,
+          "resnet50": _small_resnet50}
+
+
+@pytest.mark.parametrize("name", sorted(GRAPHS))
+def test_segments_that_keep_nothing_lower_to_the_text_they_lowered_to(name):
+    """`sparseattention` (its row is its own checkpoint, with its own
+    policy), `moe` and the CNN kinds name nothing: their segments are
+    `jax.checkpoint(seg_fn)` letter for letter, the gauge reads 0."""
+    net, ds = GRAPHS[name]()
+    text = net.lower_step(ds).as_text()
+    assert net._remat_plan()[2] == {}
+    assert obs.default_registry().gauge(
+        "train.remat_kept_segments").value == 0
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        REMAT_STEP_SHA256[name]
