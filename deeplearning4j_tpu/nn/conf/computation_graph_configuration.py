@@ -19,13 +19,26 @@ from .preprocessors import InputPreProcessor
 
 class GraphVertexSpec:
     """One node in the DAG: either a LayerConf or a GraphVertexConf, plus the
-    names of its input vertices and (for layers) an optional preprocessor."""
+    names of its input vertices and (for layers) an optional preprocessor.
+    A layer vertex with `params_of` holds no parameters: its forward (and,
+    for an output, its loss) reads those of the layer vertex it names, so
+    one leaf is reached from two places, its gradient their sum, under one
+    updater state (a prediction module on the main model's own table and
+    head)."""
 
-    def __init__(self, name, conf, inputs, preprocessor=None):
+    def __init__(self, name, conf, inputs, preprocessor=None,
+                 params_of=None):
         self.name = name
         self.conf = conf
         self.inputs = list(inputs)
         self.preprocessor = preprocessor
+        self.params_of = params_of
+
+    @property
+    def params_name(self):
+        """The vertex whose parameters this one reads: itself, but where
+        it is tied."""
+        return self.params_of or self.name
 
     @property
     def is_layer(self):
@@ -86,6 +99,16 @@ class ComputationGraphConfiguration:
         for out in self.network_outputs:
             if out not in self.vertices:
                 raise ValueError(f"Network output '{out}' is not a vertex")
+        for name, spec in self.vertices.items():
+            if spec.params_of is None:
+                continue
+            owner = self.vertices.get(spec.params_of)
+            if owner is None or owner.params_of or not owner.is_layer \
+                    or not spec.is_layer:
+                raise ValueError(
+                    f"Vertex '{name}' reads the parameters of "
+                    f"'{spec.params_of}', which is no layer vertex with "
+                    f"parameters of its own")
         return order
 
     # ------------------------------------------------------------------
@@ -100,6 +123,7 @@ class ComputationGraphConfiguration:
                 "inputs": spec.inputs,
                 "preprocessor": (spec.preprocessor.to_dict()
                                  if spec.preprocessor else None),
+                **({"paramsOf": spec.params_of} if spec.params_of else {}),
             }
         return {
             "format": "deeplearning4j-tpu/ComputationGraphConfiguration",
@@ -137,7 +161,8 @@ class ComputationGraphConfiguration:
                 conf = VERTEX_REGISTRY[typ].from_dict(vd["conf"])
             pp = (InputPreProcessor.from_dict(vd["preprocessor"])
                   if vd.get("preprocessor") else None)
-            vertices[name] = GraphVertexSpec(name, conf, vd["inputs"], pp)
+            vertices[name] = GraphVertexSpec(name, conf, vd["inputs"], pp,
+                                             vd.get("paramsOf"))
         its = d.get("inputTypes")
         return ComputationGraphConfiguration(
             inputs=d["networkInputs"], vertices=vertices,
@@ -197,6 +222,7 @@ class GraphBuilder:
         self._outputs = []
         self._input_types = None
         self._preprocessors = {}  # vertex name -> preproc (explicit)
+        self._params_of = {}      # vertex name -> the vertex it is tied to
         self._backprop = True
         self._pretrain = False
         self._backprop_type = "standard"
@@ -210,7 +236,10 @@ class GraphBuilder:
 
     addInputs = add_inputs
 
-    def add_layer(self, name, layer, *inputs, preprocessor=None):
+    def add_layer(self, name, layer, *inputs, preprocessor=None,
+                  params_of=None):
+        """`params_of` names a layer vertex of the same shapes whose
+        parameters this one reads in place of its own (GraphVertexSpec)."""
         if not isinstance(layer, LayerConf):
             raise TypeError(f"add_layer expects a LayerConf, got {type(layer)}")
         self._check_name(name)
@@ -219,6 +248,8 @@ class GraphBuilder:
         self._vertices[str(name)] = (layer, [str(i) for i in inputs])
         if preprocessor is not None:
             self._preprocessors[str(name)] = preprocessor
+        if params_of is not None:
+            self._params_of[str(name)] = str(params_of)
         return self
 
     addLayer = add_layer
@@ -286,7 +317,8 @@ class GraphBuilder:
             c = (conf.apply_global_defaults(self.g)
                  if isinstance(conf, LayerConf) else conf)
             vertices[name] = GraphVertexSpec(
-                name, c, inputs, self._preprocessors.get(name))
+                name, c, inputs, self._preprocessors.get(name),
+                self._params_of.get(name))
         cfg = ComputationGraphConfiguration(
             inputs=self._inputs, vertices=vertices, outputs=self._outputs,
             global_conf=dict(self.g), input_types=self._input_types,

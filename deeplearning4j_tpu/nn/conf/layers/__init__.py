@@ -1,7 +1,8 @@
 from .attention import SelfAttentionLayer
 from .base import LAYER_REGISTRY, LayerConf, register_layer
-from .decoder import (AttentionLayer, GatedMLPLayer, LMHeadLayer, MoELayer,
-                      RMSNormLayer, SparseAttentionLayer, TokenEmbeddingLayer)
+from .decoder import (AttentionLayer, GatedMLPLayer, LatentAttentionLayer,
+                      LMHeadLayer, MoELayer, ProjectionLayer, RMSNormLayer,
+                      SparseAttentionLayer, TokenEmbeddingLayer)
 from .convolution import (ConvolutionLayer, GlobalPoolingLayer,
                           SubsamplingLayer, ZeroPaddingLayer)
 from .feedforward import (ActivationLayer, AutoEncoder, DenseLayer,
@@ -23,7 +24,8 @@ __all__ = [
     "GlobalPoolingLayer", "BatchNormalization", "LocalResponseNormalization",
     "BaseRecurrentLayer", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
     "SelfAttentionLayer", "TokenEmbeddingLayer", "RMSNormLayer",
-    "SparseAttentionLayer", "AttentionLayer", "GatedMLPLayer", "MoELayer",
+    "SparseAttentionLayer", "AttentionLayer", "LatentAttentionLayer",
+    "ProjectionLayer", "GatedMLPLayer", "MoELayer",
     "LMHeadLayer", "RBM", "VariationalAutoencoder",
     "BernoulliReconstructionDistribution",
     "GaussianReconstructionDistribution",

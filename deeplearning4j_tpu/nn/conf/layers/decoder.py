@@ -1,6 +1,6 @@
 """Layers of a mixture-of-experts decoder, for the containers.
 
-Beyond-reference capability (the reference predates all of it). Seven layer
+Beyond-reference capability (the reference predates all of it). Nine layer
 kinds, each traced under its own `<kind>.<vertex>` scope by the container:
 
 - `tokenembedding`: ids [B, T] -> rows of a table; a second input
@@ -22,15 +22,28 @@ kinds, each traced under its own `<kind>.<vertex>` scope by the container:
   of the first `rotary_dim` slots of a head (inner scope `rotary`), plain
   or YaRN-scaled; a per-head sigmoid gate on the attention's output, read
   from the layer's input (inner scope `gate`).
+- `latentattention`: multi-head latent attention, expanded (training): the
+  queries through a low-rank chain with an RMSNorm on the latent, keys and
+  values through another whose latent is what a server would cache (inner
+  scope `latent`, Wo with them); a head scores over `qk_nope_head_dim` slots
+  of its own and `qk_rope_head_dim` slots against ONE rotary key that all
+  heads share, turned as interleaved pairs (inner scope `rotary`), and sums
+  values `v_head_dim` wide (inner scope `attend_latent`:
+  `ops/sparse_attention.py shared_key_attention`).
 - `gatedmlp`: (SiLU(x Wg) * (x Wu)) Wd, the dense layer of a decoder.
 - `moe`: router over ALL experts, the top k a token, and this chip's share
   of the experts (`parallel/moe.py` `held_experts_ffn`: nothing dropped);
   with `shared_width`, a shared expert that every token passes, added
   unscaled (inner scope `shared`); `routed_scale` multiplies the routed
-  weights.
+  weights. `scoring` "sigmoid" scores each expert alone, and with
+  `bias_update_rate` the top k are chosen by score PLUS a bias that is the
+  layer's state, not a parameter: no gradient reaches it, each training
+  forward moves it towards an even load.
+- `projection`: x W, no bias (a multi-token prediction module's `W_eh`).
 - `lmhead`: logits over a vocabulary (slice) and the masked mean
   cross-entropy over a sequence with integer labels, in token chunks so that
-  no [tokens, vocabulary] array outlives a chunk.
+  no [tokens, vocabulary] array outlives a chunk; with `loss_weight` the
+  container weights its loss and reports it in the layer's state.
 
 Layout [batch, time, features], as the recurrent and attention layers.
 """
@@ -396,6 +409,112 @@ class AttentionLayer(_StatefulSequenceLayer):
                 T, window=self.window))}
 
 
+@register_layer("latentattention")
+@dataclass
+class LatentAttentionLayer(_StatefulSequenceLayer):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    2.1) over positions 0 .. T-1, causal, run expanded: every head's keys
+    and values are made from the latent, as training does. Its fields are
+    the published config's keys."""
+    n_in: int = None
+    n_out: int = None
+    n_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 32e6
+    eps: float = 1e-6
+    init_std: float = 0.02
+
+    def init_state(self):
+        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
+
+    def gauges(self, state):
+        return dict(state)
+
+    def remat_keeps(self):
+        # as `attention`: the kernel's o and lse are its backward's operands
+        from ....ops.sparse_attention import KEEP
+        return (KEEP,)
+
+    def init_params(self, key, dtype=jnp.float32):
+        D, H = self.n_in, self.n_heads
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        k = jax.random.split(key, 5)
+        mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        return {"Wq_a": mk(k[0], (D, self.q_lora_rank)),
+                "q_norm": jnp.ones((self.q_lora_rank,), dtype),
+                "Wq_b": mk(k[1], (self.q_lora_rank, H * (dn + dr))),
+                "Wkv_a": mk(k[2], (D, self.kv_lora_rank + dr)),
+                "kv_norm": jnp.ones((self.kv_lora_rank,), dtype),
+                "Wkv_b": mk(k[3], (self.kv_lora_rank, H * (dn + dv))),
+                "Wo": mk(k[4], (H * dv, D))}
+
+    def turn(self, x, positions):
+        """x [B, T, heads, qk_rope_head_dim] turned by position as
+        interleaved pairs: slots (2i, 2i + 1) by the angle
+        position * theta^(-2i / qk_rope_head_dim), in place (a rotate-half
+        lowering moves the slots, the same way in q and k: same scores)."""
+        n = self.qk_rope_head_dim
+        inv = self.rope_theta ** (
+            -(2.0 * jnp.arange(n // 2, dtype=jnp.float32)) / n)
+        ang = positions.astype(jnp.float32)[..., None, None] * inv
+        c, s = jnp.cos(ang), jnp.sin(ang)
+        pair = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // 2, 2))
+        a, b = pair[..., 0], pair[..., 1]
+        return jnp.stack([a * c - b * s, b * c + a * s], -1).reshape(
+            x.shape).astype(x.dtype)
+
+    def forward_with_state(self, params, x, state, *, train=False, rng=None,
+                           mask=None):
+        from ....ops.sparse_attention import (grid_steps_per_tile,
+                                              shared_key_attention)
+        B, T, _ = x.shape
+        H, dn, dr, dv = (self.n_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        with jax.named_scope("latent"):
+            c_q = rms_norm(x @ params["Wq_a"], params["q_norm"], self.eps)
+            q = (c_q @ params["Wq_b"]).reshape(B, T, H, dn + dr)
+            kv_a = x @ params["Wkv_a"]
+            c_kv = rms_norm(kv_a[..., :self.kv_lora_rank], params["kv_norm"],
+                            self.eps)
+            kv = (c_kv @ params["Wkv_b"]).reshape(B, T, H, dn + dv)
+        with jax.named_scope("rotary"):
+            pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+            q_rope = self.turn(q[..., dn:], pos)
+            k_rope = self.turn(kv_a[..., None, self.kv_lora_rank:], pos)
+        heads = lambda a: jnp.moveaxis(a, 1, 2)         # [B, heads, T, d]
+        with jax.named_scope("attend_latent"):
+            o, _ = shared_key_attention(
+                heads(q[..., :dn]), heads(kv[..., :dn]), heads(kv[..., dn:]),
+                heads(q_rope), heads(k_rope), 1.0 / math.sqrt(dn + dr))
+        with jax.named_scope("latent"):
+            out = jnp.moveaxis(o, 1, 2).reshape(B, T, H * dv) @ params["Wo"]
+        # the kernels' schedule is a function of T: a constant of the trace
+        return out, {"attend_grid_steps_per_tile": jnp.float32(
+            grid_steps_per_tile(T))}
+
+
+@register_layer("projection")
+@dataclass
+class ProjectionLayer(_SequenceLayer):
+    """x W, [B, T, n_in] -> [B, T, n_out], no bias."""
+    n_in: int = None
+    n_out: int = None
+    init_std: float = 0.02
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"W": _normal(key, (self.n_in, self.n_out), self.init_std,
+                             dtype)}
+
+    def forward(self, params, x, *, train=False, rng=None, mask=None,
+                state=None):
+        return x @ params["W"]
+
+
 @register_layer("gatedmlp")
 @dataclass
 class GatedMLPLayer(_SequenceLayer):
@@ -431,7 +550,13 @@ class MoELayer(_StatefulSequenceLayer):
     and computes their part of the result, every routed pair of it. The
     routed weights are multiplied by `routed_scale`; with `shared_width` a
     shared expert of that width, which every chip of a deployment computes
-    alike for its own tokens, is added unscaled."""
+    alike for its own tokens, is added unscaled. `scoring` is the router's:
+    "softmax" over all experts, or "sigmoid" of each. With
+    `bias_update_rate` gamma (DeepSeek-V3, arXiv:2412.19437 section 2.1.2)
+    the top k are those of score + b while the weights stay the scores'; b
+    [n_experts] is state, zero at first, and every training forward leaves
+    b + gamma sign(mean(c) - c), c its own tokens' pairs by expert (a
+    deployment adds the other chips' counts before the sign)."""
     n_in: int = None
     n_out: int = None
     n_experts: int = 128
@@ -442,22 +567,30 @@ class MoELayer(_StatefulSequenceLayer):
     first_held: int = 0
     shared_width: int = None
     routed_scale: float = 1.0
+    scoring: str = "softmax"
+    bias_update_rate: float = None
     init_std: float = 0.02
 
     def _held(self):
         return self.experts_held or self.n_experts
 
     def init_state(self):
-        return {"held_pairs": jnp.zeros((self._held(),), jnp.float32),
-                "absent_pairs": jnp.zeros((), jnp.float32),
-                "blocks_run": jnp.zeros((), jnp.float32)}
+        state = {"held_pairs": jnp.zeros((self._held(),), jnp.float32),
+                 "absent_pairs": jnp.zeros((), jnp.float32),
+                 "blocks_run": jnp.zeros((), jnp.float32)}
+        if self.bias_update_rate is not None:
+            state["bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+        return state
 
     def gauges(self, state):
         held = state["held_pairs"]
-        return {"held_pairs_max": jnp.max(held),
-                "held_pairs_mean": jnp.mean(held),
-                "absent_pairs": state["absent_pairs"],
-                "blocks_run": state["blocks_run"]}
+        out = {"held_pairs_max": jnp.max(held),
+               "held_pairs_mean": jnp.mean(held),
+               "absent_pairs": state["absent_pairs"],
+               "blocks_run": state["blocks_run"]}
+        if "bias" in state:
+            out["bias_abs_max"] = jnp.max(jnp.abs(state["bias"]))
+        return out
 
     def init_params(self, key, dtype=jnp.float32):
         D, F, G = self.n_in, self.expert_width, self._held()
@@ -478,12 +611,21 @@ class MoELayer(_StatefulSequenceLayer):
         from ....parallel.moe import held_experts_ffn, route_all
         B, T, D = x.shape
         tokens = x.reshape(B * T, D)
+        biased = self.bias_update_rate is not None
         with jax.named_scope("router"):
-            experts, gates = route_all(params["Wr"], tokens,
-                                       self.experts_per_token,
-                                       self.norm_topk_prob)
+            experts, gates = route_all(
+                params["Wr"], tokens, self.experts_per_token,
+                self.norm_topk_prob, self.scoring,
+                state["bias"] if biased else None)
             if self.routed_scale != 1.0:
                 gates = gates * self.routed_scale
+            more = {}
+            if biased:
+                more["bias"] = state["bias"] if not train else (
+                    state["bias"] + self.bias_update_rate * jnp.sign(
+                        B * T * self.experts_per_token / self.n_experts
+                        - jnp.bincount(experts.reshape(-1),
+                                       length=self.n_experts)))
         y, counts, n_run = held_experts_ffn(
             tokens, experts, gates, params["Wg"], params["Wu"], params["Wd"],
             self.first_held, self.n_experts)
@@ -495,7 +637,7 @@ class MoELayer(_StatefulSequenceLayer):
         return y.astype(x.dtype).reshape(B, T, D), {
             "held_pairs": counts,
             "absent_pairs": B * T * self.experts_per_token - jnp.sum(counts),
-            "blocks_run": n_run.astype(jnp.float32)}
+            "blocks_run": n_run.astype(jnp.float32), **more}
 
 
 # ---------------------------------------------------------------------------
@@ -511,6 +653,19 @@ class LMHeadLayer(_SequenceLayer):
     n_out: int = None
     token_chunk: int = 2048
     init_std: float = 0.02
+    loss_weight: float = None   # set: the container multiplies this
+    #                             output's loss by it and leaves the
+    #                             unweighted loss in the state's `loss`
+
+    def has_state(self):
+        return self.loss_weight is not None
+
+    def init_state(self):
+        return {"loss": jnp.zeros((), jnp.float32)} if self.has_state() \
+            else {}
+
+    def gauges(self, state):
+        return dict(state)
 
     def init_params(self, key, dtype=jnp.float32):
         return {"W": _normal(key, (self.n_in, self.n_out), self.init_std,
@@ -519,6 +674,9 @@ class LMHeadLayer(_SequenceLayer):
     def forward(self, params, x, *, train=False, rng=None, mask=None,
                 state=None):
         return jnp.dot(x, params["W"], preferred_element_type=jnp.float32)
+
+    def forward_with_state(self, params, x, state, **kw):
+        return self.forward(params, x, **kw), state
 
     def compute_score_per_example(self, params, x, labels, *, train=False,
                                   rng=None, mask=None):

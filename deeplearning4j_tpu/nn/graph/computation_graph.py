@@ -61,10 +61,23 @@ class ComputationGraph(Trainer):
     # What a graph of vertices supplies to the trainer (nn/trainer.py)
     # ------------------------------------------------------------------
     def _layer_names(self):
-        """Layer vertices in topological order (the flattened-params order —
-        reference ComputationGraph.init:281-345 uses topological order too)."""
+        """Layer vertices that hold parameters, in topological order (the
+        flattened-params order — reference ComputationGraph.init:281-345
+        uses topological order too). A vertex tied to another's parameters
+        (`GraphVertexSpec.params_of`) has no entry in the parameters, the
+        gradient or the updater's state: it is not here."""
         return [n for n in self.conf.topological_order
-                if self.conf.vertices[n].is_layer]
+                if self.conf.vertices[n].is_layer
+                and self.conf.vertices[n].params_of is None]
+
+    def _tied_names(self):
+        return [n for n in self.conf.topological_order
+                if self.conf.vertices[n].params_of is not None]
+
+    def _initial_state(self):
+        # a tied vertex has state of its own, as any layer
+        return {n: self.conf.vertices[n].conf.init_state()
+                for n in self._layer_names() + self._tied_names()}
 
     def _layer_items(self):
         return [(n, self.conf.vertices[n].conf) for n in self._layer_names()]
@@ -119,7 +132,8 @@ class ComputationGraph(Trainer):
             in_masks = [masks.get(i) for i in spec.inputs]
             lrng = jax.random.fold_in(rng, vi) if rng is not None else None
             out, st, c = self._forward_vertex(
-                spec, params.get(name), in_acts, in_masks, train=train,
+                spec, params.get(spec.params_name), in_acts, in_masks,
+                train=train,
                 lrng=lrng, state_entry=state.get(name),
                 carry_entry=(carries or {}).get(name)
                 if carries is not None else None,
@@ -291,7 +305,7 @@ class ComputationGraph(Trainer):
                     # same vertex dispatch as the default path — shared
                     # helper, so the two forwards cannot drift
                     out, st, _ = self._forward_vertex(
-                        spec, p_sub.get(name), in_acts,
+                        spec, p_sub.get(spec.params_name), in_acts,
                         [None] * len(in_acts), train=train, lrng=lrng,
                         state_entry=st_sub.get(name),
                         pair=self._pair_of(name, train, p_sub, local))
@@ -308,7 +322,9 @@ class ComputationGraph(Trainer):
                 *keeps[si]) if si in keeps else None)
             call = (jax.checkpoint(seg_fn, policy=policy)
                     if si < n_seg - 1 else seg_fn)
-            outs, st_new = call({n: params[n] for n in layer_names},
+            outs, st_new = call({n: params[n] for n in dict.fromkeys(
+                                     self.conf.vertices[m].params_name
+                                     for m in layer_names)},
                                 {n: state[n] for n in stateful},
                                 [acts[i] for i in ext_in])
             acts.update(zip(out_names, outs))
@@ -364,12 +380,19 @@ class ComputationGraph(Trainer):
             with jax.named_scope(f"loss.{out_name}"):
                 if spec.preprocessor is not None:
                     x = spec.preprocessor.pre_process(x)
-                p = self._cast_params(params[out_name])
+                p = self._cast_params(params[spec.params_name])
                 per_ex = layer.compute_score_per_example(
                     p, x, labels[oi], train=train, rng=lrng, mask=lmask)
                 if per_ex.dtype == jnp.bfloat16:
                     per_ex = per_ex.astype(jnp.float32)
-                total = total + jnp.mean(per_ex)
+                part = jnp.mean(per_ex)
+                # an output that carries a weight says its own loss, as it
+                # is before the weight, in its state
+                weight = getattr(layer, "loss_weight", None)
+                if weight is not None:
+                    new_state[out_name] = {"loss": part}
+                    part = weight * part
+                total = total + part
         reg = 0.0
         for n in self._layer_names():
             layer = self.conf.vertices[n].conf
@@ -389,7 +412,7 @@ class ComputationGraph(Trainer):
         {gauge name: value}."""
         registry = registry or obs.default_registry()
         out = {}
-        for n in self._layer_names():
+        for n in self._layer_names() + self._tied_names():
             layer = self.conf.vertices[n].conf
             for k, v in layer.gauges(self._model_state[n]).items():
                 name = f"{layer.layer_type}.{n}.{k}"

@@ -96,12 +96,17 @@ class Trainer:
             self._params = self._per_layer(
                 layer.init_params(keys[i + 1], self.param_dtype)
                 for i, layer in enumerate(layers))
-            self._model_state = self._per_layer(
-                layer.init_state() for layer in layers)
+            self._model_state = self._initial_state()
             self._init_updater_state()
         if parameters is not None:
             self.set_params(parameters)
         return self
+
+    def _initial_state(self):
+        """Every layer's state as it starts, in the container's layout (a
+        graph adds the vertices that hold state and no parameters)."""
+        return self._per_layer(layer.init_state()
+                               for _, layer in self._layer_items())
 
     def _init_updater_state(self):
         sd = self.conf.global_conf.get("updater_state_dtype")

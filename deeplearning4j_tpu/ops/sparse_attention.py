@@ -15,6 +15,13 @@ chosen by the block index map, nothing is repeated in HBM.
   custom VJP, the FlashAttention-2 backward in two grid passes (dQ with the
   key blocks innermost; dK/dV with the group's query heads and query blocks
   innermost, so the sum over a group's heads happens in VMEM).
+- `shared_key_attention(q, k, v, q_shared, k_shared)` -> (o, lse): the same
+  three kernels for a latent attention, whose heads score over slots of
+  their own AND over slots of ONE key that all of them share: two products
+  a tile; the key's width need not be the value's (neither v nor o is
+  padded to it); the shared key is read by index map, never repeated, and
+  its gradient, a sum over every head that reads it, is summed in VMEM
+  inside one run of the dK/dV kernel.
 - `head_summed_probs(q, k, lse, mask)` -> [B, T, T]: the probabilities of
   all heads added up, pair by pair: the target of the indexer's loss.
 - `at_least_kth(scores, k)` -> int8 [R, S]: which entries of each row are at
@@ -78,13 +85,19 @@ def _params(interpret, semantics):
         dimension_semantics=semantics)}
 
 
-def _scores(q_ref, k_ref, mask_ref, scale, band=None, tile=None):
+def _scores(q_ref, k_ref, mask_ref, scale, band=None, tile=None, shared=()):
     """The tile's scaled scores, NEG where the pair is masked: by the mask
     operand's tile, or (`mask_ref` None) by position. `band` is (block_q,
     block_k, window or None), `tile` (query block, key block), or None for
-    a tile that keeps every pair and builds no mask."""
-    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    a tile that keeps every pair and builds no mask. `shared` is (q2_ref,
+    k2_ref), further slots of the same pair's product whose key several
+    key/value heads share: a second product of the tile, added before the
+    scale."""
+    qk = lambda a, b: jax.lax.dot_general(
+        a[0], b[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    s = (qk(q_ref, k_ref) + qk(*shared) if shared
+         else qk(q_ref, k_ref)) * scale
     if mask_ref is not None:
         return jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG)
     if tile is None:
@@ -114,9 +127,12 @@ def _each_tile(mask_ref, e, tile, step):
 FIRST, LAST = 1, 2      # bits of a step's `edge`: its run's first, its last
 CROSSED = 4             # the diagonal or the band's far edge crosses the
 #                         tile: some of its pairs are masked by position
+OWN_FIRST, OWN_LAST = 8, 16     # `by_key` under a shared key: the first and
+#                         last step of ONE key/value head's run inside the
+#                         run of the shared key's block
 
 
-def tile_schedule(T, bq, bk, heads=1, window=None):
+def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
     """The tiles that `masked_attention`'s kernels visit at query blocks of
     `bq` and key blocks of `bk`: those with a visible pair (key j <= query
     i and, under a `window`, i - j < window), each once, and no other.
@@ -134,7 +150,11 @@ def tile_schedule(T, bq, bk, heads=1, window=None):
 
     `edge` marks a run's first step (FIRST: clear the accumulators), its
     last (LAST: write the result) and a tile with a masked pair (CROSSED:
-    the diagonal or the band's far edge passes through it)."""
+    the diagonal or the band's far edge passes through it). With `own`
+    (`shared_key_attention`: `heads` query heads read one shared key, each
+    `own` of them one key/value head of their own) `by_key` also marks
+    where a key/value head's own run inside the shared block's begins and
+    ends (OWN_FIRST, OWN_LAST)."""
     nq, nk = T // bq, T // bk
     far = T if window is None else window     # i - j < far is always asked
     # the key blocks query block i sees, the query blocks that see j
@@ -149,9 +169,12 @@ def tile_schedule(T, bq, bk, heads=1, window=None):
     ends = lambda x, run: FIRST * (x == run[0]) | LAST * (x == run[-1])
     by_query = [(i, j, ends(j, keys(i)) | crossed(i, j))
                 for i in range(nq) for j in keys(i)]
+    owns = lambda r, i, run: 0 if own is None else (
+        OWN_FIRST * (r % own == 0 and i == run[0])
+        | OWN_LAST * (r % own == own - 1 and i == run[-1]))
     by_key = [(j, r, i, FIRST * (r == 0 and i == queries(j)[0])
                | LAST * (r == heads - 1 and i == queries(j)[-1])
-               | crossed(i, j))
+               | crossed(i, j) | owns(r, i, queries(j)))
               for j in range(nk) for r in range(heads) for i in queries(j)]
     cols = lambda rows: tuple(np.asarray(c, np.int32) for c in zip(*rows))
     return {"grid_steps": len(by_query),
@@ -183,9 +206,12 @@ def _given(mask, qkv, its, rest=()):
 
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
-                band=None):
-    *mask_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
-    mask_ref = mask_ref[0] if mask_ref else None
+                band=None, shared=False):
+    *rest, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+    if shared:
+        *rest, q2_ref, k2_ref = rest
+    more = (q2_ref, k2_ref) if shared else ()
+    mask_ref = rest[0] if rest else None
     t = pl.program_id(1)
     e = edge[t]
 
@@ -196,7 +222,7 @@ def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def step(tile):
-        s = _scores(q_ref, k_ref, mask_ref, scale, band, tile)
+        s = _scores(q_ref, k_ref, mask_ref, scale, band, tile, more)
         m_prev = m_ref[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
@@ -216,47 +242,65 @@ def _fwd_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
         lse_ref[0] = m_ref[:, :1] + jnp.log(l_fin)
 
 
-def _specs(bq, bk, d, R, H):
+def _specs(bq, bk, d, R, H, dv, R2=None, d2=None):
     """Block specs of a (batch * head b, step t) grid whose step t is tile
     (i_tab[t], j_tab[t]) of `tile_schedule`'s `by_query`: q-shaped,
-    k/v-shaped (the group's head), the mask's tile, a per-row column. No
-    step lies outside the band, so nothing is fetched that is not used;
-    a block whose index the next step keeps is not fetched again."""
+    k-shaped (the group's head), the mask's tile, a per-row column, then v-
+    and o-shaped (the summed width `dv` need not be the scored width `d`)
+    and, under a shared key of width `d2` that `R2` query heads read, its
+    query- and key-shaped ones. No step lies outside the band, so nothing
+    is fetched that is not used; a block whose index the next step keeps is
+    not fetched again."""
     vm = {"memory_space": pltpu.VMEM}
-    return (pl.BlockSpec((1, bq, d), lambda b, t, i, j, e: (b, i[t], 0), **vm),
-            pl.BlockSpec((1, bk, d),
-                         lambda b, t, i, j, e: (b // R, j[t], 0), **vm),
+    rows = lambda w: pl.BlockSpec(
+        (1, bq, w), lambda b, t, i, j, e: (b, i[t], 0), **vm)
+    keys = lambda w, rep: pl.BlockSpec(
+        (1, bk, w), lambda b, t, i, j, e: (b // rep, j[t], 0), **vm)
+    return (rows(d), keys(d, R),
             pl.BlockSpec((1, bq, bk),
                          lambda b, t, i, j, e: (b // H, i[t], j[t]), **vm),
-            pl.BlockSpec((1, bq, 1), lambda b, t, i, j, e: (b, i[t], 0), **vm))
+            rows(1), keys(dv, R), rows(dv),
+            *((rows(d2), keys(d2, R2)) if d2 else ()))
 
 
-def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None):
-    """q [B*H, T, d], k/v [B*KV, T, d], mask int8 [B, T, T] or None (by
-    position: causal, inside `window`)."""
+def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None, shared=()):
+    """q [B*H, T, d], k [B*KV, T, d], v [B*KV, T, dv], mask int8 [B, T, T]
+    or None (by position: causal, inside `window`); `shared` (q2 [B*H, T,
+    d2], k2 [B*KS, T, d2]): the slots scored against a key that several
+    key/value heads share."""
     BH, T, d = q.shape
-    q_spec, kv_spec, mask_spec, row_spec = _specs(
-        bq, bk, d, BH // k.shape[0],
-        1 if mask is None else BH // mask.shape[0])
+    dv = v.shape[-1]
+    q_spec, k_spec, mask_spec, row_spec, v_spec, o_spec, *shared_specs = \
+        _specs(bq, bk, d, BH // k.shape[0],
+               1 if mask is None else BH // mask.shape[0], dv,
+               *((BH // shared[1].shape[0], shared[1].shape[-1])
+                 if shared else ()))
     sched = tile_schedule(T, bq, bk, window=window)
     band = None if mask is not None else (bq, bk, window)
     return _call(
-        functools.partial(_fwd_kernel, scale=scale, band=band),
+        functools.partial(_fwd_kernel, scale=scale, band=band,
+                          shared=bool(shared)),
         sched["by_query"], (BH, sched["grid_steps"]),
-        _given(mask, (q_spec, kv_spec, kv_spec), mask_spec),
-        [q_spec, row_spec],
-        [jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+        _given(mask, (q_spec, k_spec, v_spec), mask_spec, shared_specs),
+        [o_spec, row_spec],
+        [jax.ShapeDtypeStruct((BH, T, dv), q.dtype),
          jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         [pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32),
-         pltpu.VMEM((bq, d), jnp.float32)],
-        "sparse_attention_fwd", interpret, _given(mask, (q, k, v), mask))
+         pltpu.VMEM((bq, dv), jnp.float32)],
+        "sparse_attention_fwd", interpret,
+        _given(mask, (q, k, v), mask, shared))
 
 
 # ----------------------------------------------------------------- backward
 def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
-               band=None):
-    *mask_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
+               band=None, shared=False):
+    if shared:
+        *rest, q2_ref, k2_ref, dq_ref, dq2_ref, acc_ref, acc2_ref = rest
+    else:
+        *rest, dq_ref, acc_ref = rest
+    more = (q2_ref, k2_ref) if shared else ()
+    *mask_ref, do_ref, lse_ref, delta_ref = rest
     mask_ref = mask_ref[0] if mask_ref else None
     t = pl.program_id(1)
     e = edge[t]
@@ -264,9 +308,11 @@ def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
     @pl.when(e & FIRST != 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        if shared:
+            acc2_ref[:] = jnp.zeros_like(acc2_ref)
 
     def step(tile):
-        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile)
+        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile, more)
                     - lse_ref[0])
         dp = jax.lax.dot_general(do_ref[0], v_ref[0],
                                  (((1,), (1,)), ((), ())),
@@ -275,29 +321,51 @@ def _dq_kernel(i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        if shared:
+            acc2_ref[:] += jax.lax.dot_general(
+                ds.astype(k2_ref.dtype), k2_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
 
     _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
 
     @pl.when(e & LAST != 0)
     def _emit():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        if shared:
+            dq2_ref[0] = acc2_ref[:].astype(dq2_ref.dtype)
 
 
 def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, *rest,
-                scale, band=None):
-    (*mask_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-     dv_acc) = rest
+                scale, band=None, shared=False):
+    """One run a key block: dK and dV summed over the query heads that read
+    the key/value head, in VMEM. Under a shared key the run is the SHARED
+    key's block: all its query heads pass, dK2 is summed over them all, and
+    each key/value head's dK and dV over its own stretch of the run
+    (OWN_FIRST .. OWN_LAST)."""
+    if shared:
+        (*rest, q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc,
+         dk2_acc) = rest
+    else:
+        *rest, dk_ref, dv_ref, dk_acc, dv_acc = rest
+    more = (q2_ref, k2_ref) if shared else ()
+    *mask_ref, do_ref, lse_ref, delta_ref = rest
     mask_ref = mask_ref[0] if mask_ref else None
     t = pl.program_id(1)
     e = edge[t]
+    first, last = (OWN_FIRST, OWN_LAST) if shared else (FIRST, LAST)
 
-    @pl.when(e & FIRST != 0)
+    @pl.when(e & first != 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    if shared:
+        @pl.when(e & FIRST != 0)
+        def _init_shared():
+            dk2_acc[:] = jnp.zeros_like(dk2_acc)
+
     def step(tile):
-        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile)
+        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile, more)
                     - lse_ref[0])
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
@@ -309,58 +377,80 @@ def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, *rest,
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        if shared:
+            dk2_acc[:] += jax.lax.dot_general(
+                ds.astype(q2_ref.dtype), q2_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
 
     _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
 
-    @pl.when(e & LAST != 0)
+    @pl.when(e & last != 0)
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if shared:
+        @pl.when(e & LAST != 0)
+        def _emit_shared():
+            dk2_ref[0] = dk2_acc[:].astype(dk2_ref.dtype)
 
-def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret, window=None):
+
+def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret, window=None,
+         shared=()):
     BH, T, d = q.shape
-    BKV = k.shape[0]
+    BKV, dv = k.shape[0], v.shape[-1]
     B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
     H, R, KV = BH // B, BH // BKV, BKV // B
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
                     keepdims=True)
     vm = {"memory_space": pltpu.VMEM}
     band = None if mask is not None else (bq, bk, window)
-    operands = _given(mask, (q, k, v), mask, (do, lse, delta))
-    q_spec, kvq_spec, mask_spec, row_spec = _specs(bq, bk, d, R, H)
+    operands = _given(mask, (q, k, v), mask, (do, lse, delta, *shared))
+    BKS, d2 = shared[1].shape[::2] if shared else (BKV, None)
+    R2 = BH // BKS              # query heads a run of `by_key` passes
+    more = [d2] if shared else []       # the width of a third accumulator
+    q_spec, k_spec, mask_spec, row_spec, v_spec, o_spec, *shared_specs = \
+        _specs(bq, bk, d, R, H, dv, *((R2, d2) if shared else ()))
     sched = tile_schedule(T, bq, bk, window=window)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
     dq = _call(
-        functools.partial(_dq_kernel, scale=scale, band=band),
+        functools.partial(_dq_kernel, scale=scale, band=band,
+                          shared=bool(shared)),
         sched["by_query"], (BH, sched["grid_steps"]),
-        _given(mask, (q_spec, kvq_spec, kvq_spec), mask_spec,
-               (q_spec, row_spec, row_spec)),
-        q_spec, jax.ShapeDtypeStruct((BH, T, d), q.dtype),
-        [pltpu.VMEM((bq, d), jnp.float32)], "sparse_attention_dq", interpret,
-        operands)
-    # step t of key/value head g: key block j[t], head r[t] of g's group,
-    # query block i[t] (`tile_schedule`'s `by_key`)
-    sched = tile_schedule(T, bq, bk, heads=R, window=window)
-    qh_spec = pl.BlockSpec(
-        (1, bq, d), lambda g, t, j, r, i, e: (g * R + r[t], i[t], 0), **vm)
-    rowh_spec = pl.BlockSpec(
-        (1, bq, 1), lambda g, t, j, r, i, e: (g * R + r[t], i[t], 0), **vm)
-    kv_spec = pl.BlockSpec(
-        (1, bk, d), lambda g, t, j, r, i, e: (g, j[t], 0), **vm)
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, band=band),
-        sched["by_key"], (BKV, R * sched["grid_steps"]),
-        _given(mask, (qh_spec, kv_spec, kv_spec),
+        _given(mask, (q_spec, k_spec, v_spec), mask_spec,
+               (o_spec, row_spec, row_spec, *shared_specs)),
+        [q_spec, shared_specs[0]] if shared else q_spec,
+        [shape(q), shape(shared[0])] if shared else shape(q),
+        [pltpu.VMEM((bq, w), jnp.float32) for w in (d, *more)],
+        "sparse_attention_dq", interpret, operands)
+    # step t of key head g (the shared key's where there is one, else the
+    # key/value head): key block j[t], head r[t] of the R2 query heads that
+    # read g, query block i[t] (`tile_schedule`'s `by_key`); a key/value
+    # head's own blocks are those of query head g * R2 + r[t]
+    sched = tile_schedule(T, bq, bk, heads=R2, window=window,
+                          own=R if shared else None)
+    of_query = lambda w: pl.BlockSpec(
+        (1, bq, w), lambda g, t, j, r, i, e: (g * R2 + r[t], i[t], 0), **vm)
+    of_key = lambda w: pl.BlockSpec(
+        (1, bk, w), (lambda g, t, j, r, i, e: ((g * R2 + r[t]) // R, j[t], 0))
+        if shared else (lambda g, t, j, r, i, e: (g, j[t], 0)), **vm)
+    of_shared = lambda: pl.BlockSpec(
+        (1, bk, d2), lambda g, t, j, r, i, e: (g, j[t], 0), **vm)
+    outs = _call(
+        functools.partial(_dkv_kernel, scale=scale, band=band,
+                          shared=bool(shared)),
+        sched["by_key"], (BKS, R2 * sched["grid_steps"]),
+        _given(mask, (of_query(d), of_key(d), of_key(dv)),
                pl.BlockSpec((1, bq, bk),
                             lambda g, t, j, r, i, e: (g // KV, i[t], j[t]),
                             **vm),
-               (qh_spec, rowh_spec, rowh_spec)),
-        [kv_spec, kv_spec],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
+               (of_query(dv), of_query(1), of_query(1),
+                *((of_query(d2), of_shared()) if shared else ()))),
+        [of_key(d), of_key(dv), *([of_shared()] if shared else [])],
+        [shape(k), shape(v), *([shape(shared[1])] if shared else [])],
+        [pltpu.VMEM((bk, w), jnp.float32) for w in (d, dv, *more)],
         "sparse_attention_dkv", interpret, operands)
-    return dq, dk, dv
+    return (dq, *outs) if not shared else (dq[0], *outs[:2], dq[1], outs[2])
 
 
 # --------------------------------------------------------------- public API
@@ -389,7 +479,9 @@ def _flat(a):
 def masked_attention(q, k, v, mask, scale, block_q=None, block_k=None,
                      interpret=None, window=None):
     """softmax over the keys `mask` keeps of q k^T * scale, times v.
-    q [B, H, T, d]; k, v [B, KV, T, d] (H a multiple of KV); mask int8
+    q [B, H, T, d]; k [B, KV, T, d], v [B, KV, T, dv] (H a multiple of KV;
+    the summed width dv need not be the scored width d: o is dv wide and
+    nothing is padded); mask int8
     [B, T, T], nonzero where query t reads key s, causal (s <= t) and with
     at least one key a query; or None: query t reads the keys s <= t and,
     under a `window`, t - s < window, the mask made inside the kernels
@@ -402,15 +494,19 @@ def masked_attention(q, k, v, mask, scale, block_q=None, block_k=None,
 def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret, window):
     if mask is not None and window is not None:
         raise ValueError("a mask operand carries its own window")
-    B, H, T, d = q.shape
-    bq, bk = _blocks(T, block_q, block_k, window)
-    o, lse = _fwd(_flat(q), _flat(k), _flat(v), mask, scale, bq, bk,
-                  _resolve_interpret(interpret), window)
-    # named, so that a rematerialising caller can keep them (with the mask
-    # it made) and not run the forward kernel again for the backward
-    out = (checkpoint_name(o.reshape(q.shape), KEEP),
-           checkpoint_name(lse.reshape(B, H, T), KEEP))
+    bq, bk = _blocks(q.shape[2], block_q, block_k, window)
+    out = _kept(q, v, *_fwd(_flat(q), _flat(k), _flat(v), mask, scale, bq, bk,
+                            _resolve_interpret(interpret), window))
     return out, (q, k, v, mask, *out)
+
+
+def _kept(q, v, o, lse):
+    """The forward kernel's results in the caller's layout, o [B, H, T, dv]
+    and lse [B, H, T], NAMED, so that a rematerialising caller can keep
+    them (with the mask it made) and not run the forward kernel again for
+    the backward."""
+    return (checkpoint_name(o.reshape(q.shape[:3] + v.shape[3:]), KEEP),
+            checkpoint_name(lse.reshape(q.shape[:3]), KEEP))
 
 
 def _masked_bwd(scale, block_q, block_k, interpret, window, res, g):
@@ -424,6 +520,50 @@ def _masked_bwd(scale, block_q, block_k, interpret, window, res, g):
 
 
 masked_attention.defvjp(_masked_fwd, _masked_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def shared_key_attention(q, k, v, q_shared, k_shared, scale, block_q=None,
+                         block_k=None, interpret=None):
+    """Causal attention whose scores have a part of the head's own and a
+    part against ONE key that several heads share (a latent attention's
+    rotary key): softmax over s <= t of (q k^T + q_shared k_shared^T) *
+    scale, times v. q [B, H, T, d], k [B, KV, T, d], v [B, KV, T, dv],
+    q_shared [B, H, T, d2], k_shared [B, KS, T, d2]; KV a multiple of KS, H
+    of KV. Two products a tile over the same three kernels as
+    `masked_attention`; the shared key is never repeated in HBM, and its
+    gradient, a sum over the H / KS heads that read it, is summed in VMEM
+    inside one run of the dK/dV kernel. Returns (o [B, H, T, dv],
+    lse [B, H, T] f32)."""
+    return _shared_fwd(q, k, v, q_shared, k_shared, scale, block_q, block_k,
+                       interpret)[0]
+
+
+def _shared_fwd(q, k, v, q2, k2, scale, block_q, block_k, interpret):
+    H, T = q.shape[1:3]
+    if k.shape[1] % k2.shape[1] or H % k.shape[1]:
+        raise ValueError(f"{H} query heads over {k.shape[1]} key/value "
+                         f"heads over {k2.shape[1]} shared keys")
+    bq, bk = _blocks(T, block_q, block_k)
+    out = _kept(q, v, *_fwd(_flat(q), _flat(k), _flat(v), None, scale, bq, bk,
+                            _resolve_interpret(interpret),
+                            shared=(_flat(q2), _flat(k2))))
+    return out, (q, k, v, q2, k2, *out)
+
+
+def _shared_bwd(scale, block_q, block_k, interpret, res, g):
+    q, k, v, q2, k2, o, lse = res
+    B, H, T, _ = q.shape
+    bq, bk = _blocks(T, block_q, block_k)
+    grads = _bwd(_flat(q), _flat(k), _flat(v), None, _flat(o),
+                 lse.reshape(B * H, T, 1), _flat(g[0]), scale, bq, bk,
+                 _resolve_interpret(interpret),
+                 shared=(_flat(q2), _flat(k2)))
+    return tuple(a.reshape(b.shape) for a, b in zip(grads,
+                                                    (q, k, v, q2, k2)))
+
+
+shared_key_attention.defvjp(_shared_fwd, _shared_bwd)
 
 
 def _probs_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, *, scale, bq, bk,

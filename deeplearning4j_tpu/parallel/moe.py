@@ -248,12 +248,22 @@ def shard_moe_params(params, mesh, axis="expert"):
 # ---------------------------------------------------------------------------
 # One chip's share of an expert-parallel layer: no capacity, nothing dropped
 # ---------------------------------------------------------------------------
-def route_all(router_w, x, k, norm_topk=True):
-    """Softmax over ALL experts in float32, the top k of it, and the combine
-    weights (`norm_topk`: divided by the sum over all k winners, held here
-    or not). Returns (experts [N, k] int32, gates [N, k] float32)."""
+def route_all(router_w, x, k, norm_topk=True, scoring="softmax", bias=None):
+    """Scores over ALL experts in float32 (`scoring`: "softmax" over them,
+    or "sigmoid" of each), the top k, and the combine weights (`norm_topk`:
+    divided by the sum over all k winners, held here or not). With `bias`
+    [experts] the winners are those of score + bias and the weights stay
+    the scores' (a selection bias: no gradient reaches it). Returns
+    (experts [N, k] int32, gates [N, k] float32)."""
     logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    top_p, experts = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, -1))
+    if bias is None:
+        top_p, experts = jax.lax.top_k(scores, k)
+    else:
+        experts = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)[1]
+        top_p = jnp.take_along_axis(scores, experts, -1)
     if norm_topk:
         top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
     return experts, top_p

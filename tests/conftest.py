@@ -50,7 +50,26 @@ def pytest_addoption(parser):
         help="run the full suite including slow/multiprocess tests")
 
 
+# `test_laguna_cell.py` (PR 32) asserts that Laguna's cell and its seven
+# metrics are the LAST entries of BENCHMARK.json's lists. The driver takes a
+# new entry anywhere but at the end as a move of what was there, and a file
+# under the benchmark's `paths` is a `benchmark` PR's to edit (PERF §7 row
+# 17 (c), ROADMAP M11): until that PR relaxes the pin the test is expected to
+# fail at it. Strict, so that the entry here goes with the pin.
+# `test_joyai_cell.py` holds everything else that test asserts.
+PINNED_LAST = {
+    "tests/benchmark/test_laguna_cell.py::test_the_cells_declaration":
+        "pins Laguna's entries as the last of BENCHMARK.json's lists; "
+        "PR 34's stand after them, as the driver requires",
+}
+
+
 def pytest_collection_modifyitems(config, items):
+    for item in items:
+        if item.nodeid in PINNED_LAST:
+            item.add_marker(pytest.mark.xfail(
+                reason=PINNED_LAST[item.nodeid], strict=True,
+                raises=AssertionError))
     if (config.getoption("--full-tier")
             or os.environ.get("DL4J_TPU_FULL_TESTS", "").lower()
             in ("1", "true", "yes", "on")):

@@ -5,7 +5,13 @@ and `WINDOW_BLOCK` were chosen from. One JSON line a (kernel, block shape).
 `--schedule mask` (the default) is `train-vl8k`'s: a mask operand at
 T = 8192, 32 / 4 heads; `causal` and `window` are `train-lc16k`'s
 full and window layers: the mask made from positions at T = 16384, 48 or
-64 query heads over 8 (`--T`, `--heads`, `--kv`, `--window` override).
+64 query heads over 8 (`--T`, `--heads`, `--kv`, `--window` override);
+`latent` and `latent192` are `train-mtp8k`'s latent attention at T = 8192,
+32 heads, scores 192 wide over values 128 wide, fed in the two ways there
+are: two products a tile (the head's own 128 slots, and 64 against the ONE
+rotary key all heads share: `shared_key_attention`), or one 192-wide key a
+head with the rotary key repeated 32 times in HBM (`masked_attention`, its
+gradient's sum over the heads left to XLA).
 
     chiprun -- python tools/attend_kernel_times.py                  # this tree
     chiprun -- python tools/attend_kernel_times.py --schedule window \\
@@ -36,8 +42,20 @@ import jax.numpy as jnp                                           # noqa: E402
 # one sequence of a cell's attention, as a layer's row calls it: (T, query
 # heads, key/value heads, window) by schedule
 D, TOPK = 128, 2048
+SHARED = 64             # slots of the latent attention's shared rotary key
 SHAPES = {"mask": (8192, 32, 4, None), "causal": (16384, 48, 8, None),
-          "window": (16384, 64, 8, 512)}
+          "window": (16384, 64, 8, 512), "latent": (8192, 32, 32, None),
+          "latent192": (8192, 32, 32, None)}
+
+
+def widths(a):
+    """(scored width of the head's own key, slots of a shared key)."""
+    return {"latent": (D, SHARED), "latent192": (D + SHARED, 0)}.get(
+        a.schedule, (D, 0))
+
+
+def scale_of(a):
+    return sum(widths(a)) ** -0.5
 REPS, SETS = 20, 5      # calls a timing, timings a median
 
 
@@ -55,12 +73,12 @@ def load(path):
 
 
 def kernels(mod, a):
-    """(name, bq, bk, function of (q, k, v, mask, o, lse, do)) of every
-    kernel and block shape asked for, one kernel a function: a result
+    """(name, bq, bk, function of (q, k, v, mask, o, lse, do, q2, k2)) of
+    every kernel and block shape asked for, one kernel a function: a result
     nobody reads takes its kernel out of the program."""
     for block in a.blocks.split(","):
         bq, bk = (int(x) for x in block.split("x"))
-        for name, fn in one_each(mod, D ** -0.5, bq, bk, a).items():
+        for name, fn in one_each(mod, scale_of(a), bq, bk, a).items():
             if name in a.kernels.split(","):
                 yield name, bq, bk, jax.jit(fn)
 
@@ -70,30 +88,40 @@ def one_each(mod, scale, bq, bk, a):
     # a mask operand, or (by position) none and the window
     by = {} if a.schedule == "mask" else {"window": a.window}
     given = (lambda m: m) if a.schedule == "mask" else (lambda m: None)
+    shared = (lambda q2, k2: {"shared": (flat(q2), flat(k2))}
+              ) if a.schedule == "latent" else (lambda q2, k2: {})
 
-    def bwd(q, k, v, mask, o, lse, do):
+    def bwd(q, k, v, mask, o, lse, do, q2, k2):
         B, H, T, _ = q.shape
         return mod._bwd(flat(q), flat(k), flat(v), given(mask), flat(o),
                         lse.reshape(B * H, T, 1), flat(do), scale, bq, bk,
-                        False, **by)
+                        False, **by, **shared(q2, k2))
 
+    # under a shared key dQ is (dq, dq2) and dK/dV (dk, dv, dk2)
+    dq_of = (lambda g: (g[0], g[3])) if a.schedule == "latent" else (
+        lambda g: g[0])
+    dkv_of = (lambda g: (g[1], g[2], g[4])) if a.schedule == "latent" else (
+        lambda g: g[1:])
     return {
-        "fwd": lambda q, k, v, mask, o, lse, do: mod._fwd(
+        "fwd": lambda q, k, v, mask, o, lse, do, q2, k2: mod._fwd(
             flat(q), flat(k), flat(v), given(mask), scale, bq, bk, False,
-            **by),
-        "dq": lambda *a: bwd(*a)[0],
-        "dkv": lambda *a: bwd(*a)[1:]}
+            **by, **shared(q2, k2)),
+        "dq": lambda *a: dq_of(bwd(*a)),
+        "dkv": lambda *a: dkv_of(bwd(*a))}
 
 
 def shapes(a, sharding=None):
-    B, H, KV, T, d = 1, a.heads, a.kv, a.T, D
+    B, H, KV, T = 1, a.heads, a.kv, a.T
+    d, d2 = widths(a)           # v, o and do are D wide whatever is scored
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     bf = jnp.bfloat16
-    # by position there is no mask: a [1, 1, 1] stands in its place
+    # by position there is no mask: a [1, 1, 1] stands in its place, and a
+    # [1, 1, T, 1] in a shared key's where there is none
     M = T if a.schedule == "mask" else 1
-    return (s((B, H, T, d), bf), s((B, KV, T, d), bf), s((B, KV, T, d), bf),
-            s((B, M, M), jnp.int8), s((B, H, T, d), bf),
-            s((B, H, T), jnp.float32), s((B, H, T, d), bf))
+    return (s((B, H, T, d), bf), s((B, KV, T, d), bf), s((B, KV, T, D), bf),
+            s((B, M, M), jnp.int8), s((B, H, T, D), bf),
+            s((B, H, T), jnp.float32), s((B, H, T, D), bf),
+            s((B, H if d2 else 1, T, d2 or 1), bf), s((B, 1, T, d2 or 1), bf))
 
 
 def arrays(a, seed=0):
@@ -101,17 +129,18 @@ def arrays(a, seed=0):
     (all of them where there are no more), the diagonal among them. The
     kernels' time does not depend on it: every tile under the diagonal is
     computed."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     sh = shapes(a)
-    q, k, v, do = (jax.random.normal(kk, s.shape, s.dtype)
-                   for kk, s in zip(ks, (sh[0], sh[1], sh[2], sh[6])))
+    q, k, v, do, q2, k2 = (
+        jax.random.normal(kk, s.shape, s.dtype)
+        for kk, s in zip(ks, (sh[0], sh[1], sh[2], sh[6], sh[7], sh[8])))
     if a.schedule != "mask":
-        return q, k, v, jnp.ones(sh[3].shape, jnp.int8), do
+        return q, k, v, jnp.ones(sh[3].shape, jnp.int8), do, q2, k2
     t = jnp.arange(a.T)
     u = jax.random.uniform(ks[4], (a.T, a.T))
     keep = (t[None, :] <= t[:, None]) & (
         (u * (t[:, None] + 1) < TOPK) | (t[None, :] == t[:, None]))
-    return q, k, v, keep.astype(jnp.int8)[None], do
+    return q, k, v, keep.astype(jnp.int8)[None], do, q2, k2
 
 
 def device_ms(fn, operands):
@@ -170,13 +199,15 @@ def main():
         print(f"found platform {jax.default_backend()!r}, not a TPU; "
               "refusing to measure", file=sys.stderr)
         return 4
-    q, k, v, mask, do = arrays(a)
-    o, lse = jax.jit(lambda q, k, v, mask: (
-        mod.masked_attention(q, k, v, mask, D ** -0.5)
+    q, k, v, mask, do, q2, k2 = arrays(a)
+    o, lse = jax.jit(lambda q, k, v, mask, q2, k2: (
+        mod.masked_attention(q, k, v, mask, scale_of(a))
         if a.schedule == "mask" else
-        mod.masked_attention(q, k, v, None, D ** -0.5, window=a.window)))(
-            q, k, v, mask)
-    operands = (q, k, v, mask, o, lse, do)
+        mod.shared_key_attention(q, k, v, q2, k2, scale_of(a))
+        if a.schedule == "latent" else
+        mod.masked_attention(q, k, v, None, scale_of(a), window=a.window)))(
+            q, k, v, mask, q2, k2)
+    operands = (q, k, v, mask, o, lse, do, q2, k2)
     for name, bq, bk, fn in kernels(mod, a):
         try:
             jax.block_until_ready(fn(*operands))
