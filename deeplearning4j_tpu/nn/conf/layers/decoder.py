@@ -150,13 +150,29 @@ def rotate_half(x, cos, sin):
     return jnp.concatenate([a * c - b * s, b * c + a * s], -1).astype(x.dtype)
 
 
+@jax.custom_vjp
 def index_scores(qi, ki, w):
     """I[c, s] = sum_j w[c, j] ReLU(qi[c, j] . ki[s]) in float32.
-    qi [C, HI, DI], ki [S, DI], w [C, HI] -> [C, S]."""
+    qi [C, HI, DI], ki [S, DI], w [C, HI] -> [C, S]. XLA fuses this forward
+    into one pass that keeps the HI heads' dots on chip; its transpose
+    wrote them out, f32[S, C, HI] and their signs beside them, so the
+    backward is a kernel that makes them again a block of keys at a time
+    (`ops/sparse_attention.py index_scores_bwd`) and keeps qi, ki, w alone."""
     dots = jnp.einsum("chd,sd->hcs", qi, ki,
                       preferred_element_type=jnp.float32)
     return jnp.sum(jax.nn.relu(dots)
                    * w.astype(jnp.float32).T[:, :, None], 0)
+
+
+def _index_scores_bwd(res, g):
+    # traced under the call site's scopes (`_row` opens `indexer` around the
+    # call, not inside it), so the metrics that read the scope count it
+    from ....ops.sparse_attention import index_scores_bwd
+    return index_scores_bwd(*res, g)
+
+
+index_scores.defvjp(lambda qi, ki, w: (index_scores(qi, ki, w), (qi, ki, w)),
+                    _index_scores_bwd)
 
 
 def select_keys(scores, q_pos, topk):
@@ -242,10 +258,12 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
         """One sequence: (o [T, H, Dh], the sum of its queries' KL, its
         selected pairs). Index scores and the selection a chunk of queries
         at a time, each against its causal prefix of keys (a chunk keeps
-        nothing for the backward but its inputs); then the main attention
-        over the selected pairs and the indexer's target, the probabilities
-        of all heads added up, as Pallas kernels (ops/sparse_attention.py)
-        that never write a head's [T, T] scores out."""
+        nothing for the backward but its inputs: `index_scores`' own VJP
+        makes the heads' dots again inside its kernel); then the main
+        attention over the selected pairs and the indexer's target, the
+        probabilities of all heads added up, as Pallas kernels
+        (ops/sparse_attention.py) that never write a head's [T, T] scores
+        out."""
         from ....ops.sparse_attention import (KEEP, head_summed_probs,
                                               masked_attention)
         T, KV, R, Dh = q.shape
@@ -254,7 +272,7 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
         for a in range(0, T, C):
             b = min(a + C, T)
             with jax.named_scope("indexer"):
-                s = jax.checkpoint(index_scores)(qi[a:b], ki[:b], w[a:b])
+                s = index_scores(qi[a:b], ki[:b], w[a:b])
             with jax.named_scope("select"):
                 m = select_keys(s, jnp.arange(a, b), self.topk)
             scores.append(jnp.pad(s, ((0, 0), (0, T - b))))

@@ -29,6 +29,12 @@ chosen by the block index map, nothing is repeated in HBM.
   binary search over the floats' bits that reads the row once from HBM;
   `lax.top_k` at k = 2048 of 8192 is a full sort on this chip, 2.7 ms for
   512 rows, and was 30% of the step.
+- `index_scores_bwd(qi, ki, w, g)` -> (dqi, dki, dw): the backward of the
+  indexer's scores I[c, s] = sum_h w[c, h] ReLU(qi[c, h] . ki[s]) for a
+  chunk of queries against its prefix of keys, key block by key block: each
+  head's [C, block] dots are made again in VMEM, and their three gradients
+  summed there. XLA's transposed dots wrote f32[S, C, heads] and a `pred`
+  of that shape out for every chunk (PR 35).
 
 The same three kernels serve an `attention` layer's plain causal and
 windowed attention (`mask=None`): the tile's mask is then made inside the
@@ -80,9 +86,9 @@ WINDOW_BLOCK = (512, 512)     # (query, key) block under a window (PERF.md
 #                  T = 16384, d = 128, window 512)
 
 
-def _params(interpret, semantics):
+def _params(interpret, semantics, **more):
     return {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics)}
+        dimension_semantics=semantics, **more)}
 
 
 def _scores(q_ref, k_ref, mask_ref, scale, band=None, tile=None, shared=()):
@@ -644,3 +650,101 @@ def at_least_kth(scores, k, block_rows=64, interpret=None):
         out_shape=jax.ShapeDtypeStruct((R, S), jnp.int8),
         interpret=interpret, name="sparse_attention_select",
         **_params(interpret, ("parallel",)))(scores)
+
+
+# ------------------------------------------- the index scores' backward
+INDEX_BLOCK = 512       # key block of `index_scores_bwd`: the fastest of
+#                         128 .. 2048 on the chip at C = 512, 16 heads of 64,
+#                         a row's 16 prefixes of T = 8192 (PERF.md section 6,
+#                         PR 35)
+INDEX_VMEM = 48 << 20   # the kernel's VMEM: a head's float32 tiles, the
+#                         cotangent's two buffers and the 64-wide arrays,
+#                         padded to the 128 lanes, take 18 MB at 512 keys a
+#                         block and 42 at 2,048, over the scoped default of
+#                         16 and well under the chip's 128
+
+
+def _index_bwd_kernel(qi_ref, ki_ref, w_ref, g_ref, dqi_ref, dki_ref, dw_ref,
+                      dqi_acc, dki_acc, dw_acc, *, S, bk):
+    """One key block of a chunk: head by head the dots d = qi_h ki_j^T once
+    more, and from them the three gradients' parts. dqi and dw are summed
+    over the key blocks in VMEM and written by the last step; dki_j is
+    whole in its step, for all of the chunk's queries are in the block."""
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _init():
+        dqi_acc[...] = jnp.zeros_like(dqi_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    g, ki = g_ref[...], ki_ref[...]
+    if S % bk:      # the last block's keys past S hold whatever was there
+        seen = lambda shape, axis: j * bk + jax.lax.broadcasted_iota(
+            jnp.int32, shape, axis) < S
+        g = jnp.where(seen(g.shape, 1), g, 0.0)
+        ki = jnp.where(seen(ki.shape, 0), ki, jnp.zeros_like(ki))
+    w = w_ref[...]
+    column = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    dki_acc[...] = jnp.zeros_like(dki_acc)
+
+    def head(h, carry):
+        q = qi_ref[h]
+        d = jax.lax.dot_general(q, ki, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        pos = d > 0
+        mine = column == h
+        w_h = jnp.sum(jnp.where(mine, w, 0.0), -1, keepdims=True)
+        # selects, not products with ReLU(d): a key past S may hold a NaN
+        dw_acc[...] += jnp.where(mine, jnp.sum(
+            jnp.where(pos, g * d, 0.0), -1, keepdims=True), 0.0)
+        # the operand the MXU is given: what XLA's transposed dots round
+        # the float32 cotangent to at the default precision
+        dd = jnp.where(pos, g * w_h, 0.0).astype(ki.dtype)
+        dqi_acc[h] += jax.lax.dot_general(
+            dd, ki, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dki_acc[...] += jax.lax.dot_general(
+            dd, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, qi_ref.shape[0], head, 0)
+    dki_ref[...] = dki_acc[...].astype(dki_ref.dtype)
+
+    @pl.when(j == pl.num_programs(0) - 1)
+    def _emit():
+        dqi_ref[...] = dqi_acc[...].astype(dqi_ref.dtype)
+        dw_ref[...] = dw_acc[...].astype(dw_ref.dtype)
+
+
+def index_scores_bwd(qi, ki, w, g, block_k=None, interpret=None):
+    """The gradients of I = sum_h w[:, h, None] * ReLU(qi[:, h] ki^T) in qi
+    [C, HI, DI], ki [S, DI] and w [C, HI] under the cotangent g [C, S]
+    (float32): (dqi, dki, dw) in their operands' shapes and types, every
+    sum in float32. The dots of a head against a block of `block_k` keys
+    live in VMEM only; S need not be a multiple of the block."""
+    C, HI, DI = qi.shape
+    S = ki.shape[0]
+    bk = min(block_k or INDEX_BLOCK, S)
+    interpret = _resolve_interpret(interpret)
+    vm = {"memory_space": pltpu.VMEM}
+    whole = lambda *shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape),
+                                        **vm)
+    keys = pl.BlockSpec((bk, DI), lambda j: (j, 0), **vm)
+    dqi, dki, dw = pl.pallas_call(
+        functools.partial(_index_bwd_kernel, S=S, bk=bk),
+        grid=(pl.cdiv(S, bk),),
+        in_specs=[whole(HI, C, DI), keys, whole(C, HI),
+                  pl.BlockSpec((C, bk), lambda j: (0, j), **vm)],
+        out_specs=[whole(HI, C, DI), keys, whole(C, HI)],
+        out_shape=[jax.ShapeDtypeStruct((HI, C, DI), qi.dtype),
+                   jax.ShapeDtypeStruct(ki.shape, ki.dtype),
+                   jax.ShapeDtypeStruct(w.shape, w.dtype)],
+        scratch_shapes=[pltpu.VMEM((HI, C, DI), jnp.float32),
+                        pltpu.VMEM((bk, DI), jnp.float32),
+                        pltpu.VMEM((C, HI), jnp.float32)],
+        interpret=interpret, name="sparse_attention_index_bwd",
+        **_params(interpret, ("arbitrary",), vmem_limit_bytes=INDEX_VMEM))(
+            jnp.moveaxis(qi, 1, 0), ki, w.astype(jnp.float32),
+            g.astype(jnp.float32))
+    return jnp.moveaxis(dqi, 0, 1), dki, dw

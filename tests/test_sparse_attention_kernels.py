@@ -1,9 +1,12 @@
 """The masked-attention kernels of ops/sparse_attention.py on their own
 (tests/test_keye_vl.py has them inside the layer only): forward and backward
 against a dense float32 reference under a random causal selection, tiled
-against one tile, and the tile schedule the kernels build their grid from.
+against one tile, and the tile schedule the kernels build their grid from;
+the index scores' hand-written backward (`index_scores_bwd`, PR 35) against
+`jax.vjp` of the expression it replaced, alone and through the layer.
 Interpreted on the CPU."""
 import os
+import re
 import sys
 
 import jax
@@ -14,8 +17,10 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from deeplearning4j_tpu.nn.conf.layers import decoder             # noqa: E402
 from deeplearning4j_tpu.ops.sparse_attention import (             # noqa: E402
-    FIRST, LAST, grid_steps_per_tile, masked_attention, tile_schedule)
+    FIRST, LAST, grid_steps_per_tile, index_scores_bwd, masked_attention,
+    tile_schedule)
 
 D = 16
 
@@ -155,3 +160,156 @@ def test_gauge_reads_one_grid_step_a_tile():
         "sparseattention.l0_attn.attend_grid_steps_per_tile",
         "sparseattention.l1_attn.attend_grid_steps_per_tile"]
     assert set(steps.values()) == {1.0}
+
+
+# ------------------------------------------- the index scores' backward
+def plain_index_scores(qi, ki, w):
+    """`decoder.index_scores` as the parent of PR 35 had it, whose VJP XLA
+    made: the reference of the kernel that took that VJP's place."""
+    dots = jnp.einsum("chd,sd->hcs", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots)
+                   * w.astype(jnp.float32).T[:, :, None], 0)
+
+
+def index_inputs(C, S, HI=3, DI=8, dtype=jnp.float32, seed=0):
+    """A chunk of C queries, the last C of S positions, against its prefix
+    of S keys, and a cotangent that is zero above the diagonal (the KL's
+    is: a masked pair has no gradient)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    qi = jax.random.normal(ks[0], (C, HI, DI), dtype)
+    ki = jax.random.normal(ks[1], (S, DI), dtype)
+    w = jax.random.normal(ks[2], (C, HI), dtype)
+    g = jax.random.normal(ks[3], (C, S), jnp.float32)
+    causal = jnp.arange(S)[None, :] <= S - C + jnp.arange(C)[:, None]
+    return qi, ki, w, jnp.where(causal, g, 0.0)
+
+
+# (C, S, bk): S one block, whole blocks, a ragged last block (40 = 32 + 8,
+# 80 = 2 x 32 + 16), S under the block asked for
+INDEX_CASES = [(16, 16, 16), (16, 64, 16), (16, 64, 32), (16, 40, 32),
+               (32, 80, 32), (8, 24, 64), (16, 48, 16)]
+
+
+@pytest.mark.parametrize("C,S,bk", INDEX_CASES)
+def test_index_scores_bwd_against_the_plain_expressions_vjp(C, S, bk):
+    qi, ki, w, g = index_inputs(C, S, seed=C + S + bk)
+    want = jax.vjp(plain_index_scores, qi, ki, w)[1](g)
+    got = index_scores_bwd(qi, ki, w, g, block_k=bk)
+    for name, a, b in zip(("dqi", "dki", "dw"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.abs(np.asarray(b)).max() > 1       # something to compare
+        # the blocks only reorder float32 sums
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+    # the keys a ragged last block reads past S are nobody's
+    assert np.isfinite(np.asarray(got[1])).all()
+
+
+@pytest.mark.parametrize("S,bk", [(64, 32), (40, 32)])
+def test_index_scores_bwd_a_head_whose_dots_are_all_negative(S, bk):
+    """Head 1's queries are minus the sum of |keys|' signs: every dot is
+    negative, ReLU passes nothing, and the head gets no gradient at all."""
+    qi, ki, w, g = index_inputs(16, S, seed=S)
+    ki = jnp.abs(ki) + 0.1
+    qi = qi.at[:, 1].set(-jnp.abs(qi[:, 1]) - 0.1)
+    want = jax.vjp(plain_index_scores, qi, ki, w)[1](g)
+    got = index_scores_bwd(qi, ki, w, g, block_k=bk)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got[0][:, 1]).any()        # dqi of the head
+    assert not np.asarray(got[2][:, 1]).any()        # dw of the head
+    assert np.asarray(got[2][:, 0]).any()
+
+
+def test_index_scores_bwd_in_bfloat16_rounds_the_cotangent_as_the_mxu_does():
+    """bf16 operands as the cell has them: the kernel hands the MXU
+    bf16(g w_h) where the CPU's transposed dot keeps float32, so the two
+    differ by that rounding and no more (2^-8 relative, summed over a
+    head's pairs); the results come back in bf16."""
+    qi, ki, w, g = index_inputs(32, 96, HI=4, DI=16, dtype=jnp.bfloat16)
+    want = jax.vjp(plain_index_scores, qi, ki, w)[1](g)
+    got = index_scores_bwd(qi, ki, w, g, block_k=32)
+    for name, a, b in zip(("dqi", "dki", "dw"), got, want):
+        assert a.dtype == b.dtype == jnp.bfloat16, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 2 ** -6 * np.abs(b).max(), name
+
+
+def test_index_scores_gradient_is_the_kernels_and_its_value_the_plain():
+    qi, ki, w, g = index_inputs(16, 48, seed=5)
+    out, pull = jax.vjp(decoder.index_scores, qi, ki, w)
+    np.testing.assert_array_equal(out, plain_index_scores(qi, ki, w))
+    for a, b in zip(pull(g), index_scores_bwd(qi, ki, w, g)):
+        np.testing.assert_array_equal(a, b)
+    # nothing of a head's [C, S] dots is kept for the backward: what the
+    # pullback holds is the three operands
+    assert sorted(a.shape for a in jax.tree.leaves(pull)) == sorted(
+        [qi.shape, ki.shape, w.shape])
+
+
+def test_index_scores_under_vmap_as_the_cells_probe_calls_it():
+    """`benchmarks/drivers/train_vl.py program_selection`: the last `count`
+    queries of every row against all of the row's keys, under `jax.vmap`,
+    forward only."""
+    rows, T, count = 2, 64, 16
+    parts = [index_inputs(T, T, seed=r) for r in range(rows)]
+    qi, ki, w = (jnp.stack([p[i] for p in parts]) for i in range(3))
+    got = jax.jit(jax.vmap(lambda a, b, c: decoder.index_scores(
+        a[T - count:], b, c[T - count:])))(qi, ki, w)
+    assert got.shape == (rows, count, T) and got.dtype == jnp.float32
+    for r in range(rows):
+        np.testing.assert_allclose(got[r], plain_index_scores(
+            qi[r, T - count:], ki[r], w[r, T - count:]), rtol=1e-6,
+            atol=1e-6)
+
+
+def test_the_layers_gradient_is_the_plain_expressions():
+    """`jax.grad` of the layer's output and its indexer's loss on the tiny
+    Keye graph's first attention layer, with `index_scores` as it is and
+    as the parent had it (the plain expression under `jax.checkpoint`):
+    the indexer's three matrices, which alone see the difference, and the
+    rest, which must not."""
+    from test_keye_vl import batch_of, conf_of, weights
+    conf, w, b = conf_of(), weights(), batch_of(0)
+    attn = conf.vertices["l0_attn"].conf
+    x = conf.vertices["l0_norm1"].conf.forward(
+        w["l0_norm1"], conf.vertices["embed"].conf.forward(
+            w["embed"], b["ids"], extras=(b["image"],)))
+
+    def loss(p):
+        out, state = attn.forward_with_state(
+            p, x, attn.init_state(), train=True, extras=(b["positions"],))
+        return jnp.sum(out * out) + state["layer_loss"]
+
+    got = jax.grad(loss)(w["l0_attn"])
+    ours = decoder.index_scores
+    decoder.index_scores = jax.checkpoint(plain_index_scores)
+    try:
+        want = jax.grad(loss)(w["l0_attn"])
+    finally:
+        decoder.index_scores = ours
+    assert sorted(got) == sorted(want)
+    for k in sorted(want):
+        assert np.abs(np.asarray(want[k])).max() > 0, k
+        scale = np.abs(np.asarray(want[k])).max()
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-5,
+                                   atol=2e-6 * scale, err_msg=k)
+        if k not in ("WqI", "WkI", "Ww"):
+            np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+def test_the_backward_kernel_of_the_lowered_step_is_under_indexer():
+    """What `train_indexer_device_ms` reads is a name on an operation's
+    path: `_row` opens `indexer` around the call of `index_scores`, and the
+    hand-written backward is traced under the call site's names (a scope
+    opened again inside it would read `indexer/indexer`)."""
+    from test_keye_vl import batch_of, conf_of, mds_of
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    text = ComputationGraph(conf_of()).init().lower_step(
+        mds_of(batch_of(0))).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*/[^"]*)"', text))
+    mine = {p for p in paths if "sparse_attention_index_bwd" in p}
+    assert mine and all("indexer/sparse_attention_index_bwd" in p
+                        for p in mine)
+    assert not [p for p in mine if "indexer/indexer" in p]

@@ -11,7 +11,14 @@ full and window layers: the mask made from positions at T = 16384, 48 or
 are: two products a tile (the head's own 128 slots, and 64 against the ONE
 rotary key all heads share: `shared_key_attention`), or one 192-wide key a
 head with the rotary key repeated 32 times in HBM (`masked_attention`, its
-gradient's sum over the heads left to XLA).
+gradient's sum over the heads left to XLA). `indexer` (PR 35) is not an
+attention kernel but the same cell's learned selection: one row's 16 chunks
+of 512 queries of `index_scores`, each against its prefix of keys, at
+T = 8192, 16 heads of 64: the forward as XLA fuses it (`index_fwd`), its VJP
+as XLA makes it under `jax.checkpoint`, which is what the step ran before
+PR 35 (`index_vjp_xla`), and `index_scores_bwd`, the kernel that took its
+place, at `--blocks` key blocks (`index_bwd`; "x512,x1024", the query block
+is the chunk); `device_ms` there is ALL the device's operations of a call.
 
     chiprun -- python tools/attend_kernel_times.py                  # this tree
     chiprun -- python tools/attend_kernel_times.py --schedule window \\
@@ -45,7 +52,8 @@ D, TOPK = 128, 2048
 SHARED = 64             # slots of the latent attention's shared rotary key
 SHAPES = {"mask": (8192, 32, 4, None), "causal": (16384, 48, 8, None),
           "window": (16384, 64, 8, 512), "latent": (8192, 32, 32, None),
-          "latent192": (8192, 32, 32, None)}
+          "latent192": (8192, 32, 32, None), "indexer": (8192, 16, 1, None)}
+CHUNK, INDEX_DIM = 512, 64      # the indexer's query chunk and head width
 
 
 def widths(a):
@@ -110,6 +118,59 @@ def one_each(mod, scale, bq, bk, a):
         "dkv": lambda *a: dkv_of(bwd(*a))}
 
 
+def plain_index_scores(qi, ki, w):
+    """`decoder.index_scores` as it stood before PR 35 gave it a backward
+    of its own: what XLA differentiates."""
+    dots = jnp.einsum("chd,sd->hcs", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots)
+                   * w.astype(jnp.float32).T[:, :, None], 0)
+
+
+def indexer_shapes(a, sharding=None):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    return (s((a.T, a.heads, INDEX_DIM), jnp.bfloat16),
+            s((a.T, INDEX_DIM), jnp.bfloat16), s((a.T, a.heads), jnp.bfloat16),
+            s((a.T, a.T), jnp.float32))
+
+
+def indexer_arrays(a, seed=0):
+    """Seeded qi, ki, w and a cotangent that is zero above the diagonal, as
+    the KL's is."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    qi, ki, w, g = (jax.random.normal(kk, s.shape, s.dtype)
+                    for kk, s in zip(ks, indexer_shapes(a)))
+    t = jnp.arange(a.T)
+    return qi, ki, w, jnp.where(t[None, :] <= t[:, None], g, 0.0)
+
+
+def indexer_kernels(mod, a):
+    """(name, 0, key block, function of (qi, ki, w, g)): a row's chunks one
+    after another, each result returned, as `_row` keeps each."""
+    chunks = [(c, c + CHUNK) for c in range(0, a.T, CHUNK)]
+
+    def each(fn):
+        return jax.jit(lambda qi, ki, w, g: [
+            fn(qi[lo:hi], ki[:hi], w[lo:hi], g[lo:hi, :hi])
+            for lo, hi in chunks])
+
+    def vjp_xla(qi, ki, w, g):
+        return jax.vjp(jax.checkpoint(plain_index_scores), qi, ki, w)[1](g)
+
+    wanted = a.kernels.split(",")
+    if "index_fwd" in wanted:
+        yield "index_fwd", 0, 0, each(
+            lambda qi, ki, w, g: plain_index_scores(qi, ki, w))
+    if "index_vjp_xla" in wanted:
+        yield "index_vjp_xla", 0, 0, each(vjp_xla)
+    if "index_bwd" in wanted and hasattr(mod, "index_scores_bwd"):
+        for block in a.blocks.split(","):
+            bk = int(block.split("x")[1])
+            yield "index_bwd", 0, bk, each(
+                lambda qi, ki, w, g, bk=bk: mod.index_scores_bwd(
+                    qi, ki, w, g, block_k=bk, interpret=False))
+
+
 def shapes(a, sharding=None):
     B, H, KV, T = 1, a.heads, a.kv, a.T
     d, d2 = widths(a)           # v, o and do are D wide whatever is scored
@@ -143,10 +204,13 @@ def arrays(a, seed=0):
     return q, k, v, keep.astype(jnp.int8)[None], do, q2, k2
 
 
-def device_ms(fn, operands):
+def device_ms(fn, operands, whole_call=False):
     """The kernel's own time on the device's operation line, a call: the
     median of REPS traced calls' `sparse_attention_*` events (the host's
-    clock above adds the dispatch and, for dQ, the row sums' fusion)."""
+    clock above adds the dispatch and, for dQ, the row sums' fusion). With
+    `whole_call`, every operation of a call added up (a function XLA
+    compiles to fusions of its own, or one that calls a kernel 16 times),
+    and beside it the kernels' share of that."""
     import tempfile
     from deeplearning4j_tpu.optimize.profiler import _device_ops, trace
     with tempfile.TemporaryDirectory() as logdir:
@@ -154,16 +218,31 @@ def device_ms(fn, operands):
             for _ in range(REPS):
                 out = fn(*operands)
             jax.block_until_ready(out)
-        ms = [t for name, t in _device_ops(logdir)
-              if "sparse_attention" in name]
-    return statistics.median(ms) if ms else None
+        ops = _device_ops(logdir)
+    ms = [t for name, t in ops if "sparse_attention" in name]
+    if whole_call:
+        return {"device_ms": sum(t for _, t in ops) / REPS,
+                "kernels_device_ms": sum(ms) / REPS}
+    return {"device_ms": statistics.median(ms) if ms else None}
+
+
+def attention_operands(mod, a):
+    q, k, v, mask, do, q2, k2 = arrays(a)
+    o, lse = jax.jit(lambda q, k, v, mask, q2, k2: (
+        mod.masked_attention(q, k, v, mask, scale_of(a))
+        if a.schedule == "mask" else
+        mod.shared_key_attention(q, k, v, q2, k2, scale_of(a))
+        if a.schedule == "latent" else
+        mod.masked_attention(q, k, v, None, scale_of(a), window=a.window)))(
+            q, k, v, mask, q2, k2)
+    return q, k, v, mask, o, lse, do, q2, k2
 
 
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--module", default=None)
-    p.add_argument("--blocks", default="512x512,1024x512,512x1024,1024x1024")
-    p.add_argument("--kernels", default="fwd,dq,dkv")
+    p.add_argument("--blocks", default=None)
+    p.add_argument("--kernels", default=None)
     p.add_argument("--schedule", choices=sorted(SHAPES), default="mask")
     p.add_argument("--T", type=int, default=None)
     p.add_argument("--heads", type=int, default=None)
@@ -175,7 +254,13 @@ def main():
                              SHAPES[a.schedule]):
         if getattr(a, name) is None:
             setattr(a, name, default)
+    indexer = a.schedule == "indexer"
+    a.blocks = a.blocks or ("x512,x1024" if indexer else
+                            "512x512,1024x512,512x1024,1024x1024")
+    a.kernels = a.kernels or ("index_fwd,index_vjp_xla,index_bwd" if indexer
+                              else "fwd,dq,dkv")
     mod = load(a.module)
+    every = indexer_kernels if indexer else kernels
     say = lambda **kw: print(json.dumps(
         {"module": a.module or "this tree", "schedule": a.schedule,
          "T": a.T, "heads": a.heads, "window": a.window, **kw}), flush=True)
@@ -185,8 +270,9 @@ def main():
         from jax.sharding import SingleDeviceSharding
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
-        sh = shapes(a, SingleDeviceSharding(topo.devices[0]))
-        for name, bq, bk, fn in kernels(mod, a):
+        sh = (indexer_shapes if indexer else shapes)(
+            a, SingleDeviceSharding(topo.devices[0]))
+        for name, bq, bk, fn in every(mod, a):
             try:
                 fn.lower(*sh).compile()
                 say(kernel=name, bq=bq, bk=bk, compiled=True)
@@ -199,16 +285,8 @@ def main():
         print(f"found platform {jax.default_backend()!r}, not a TPU; "
               "refusing to measure", file=sys.stderr)
         return 4
-    q, k, v, mask, do, q2, k2 = arrays(a)
-    o, lse = jax.jit(lambda q, k, v, mask, q2, k2: (
-        mod.masked_attention(q, k, v, mask, scale_of(a))
-        if a.schedule == "mask" else
-        mod.shared_key_attention(q, k, v, q2, k2, scale_of(a))
-        if a.schedule == "latent" else
-        mod.masked_attention(q, k, v, None, scale_of(a), window=a.window)))(
-            q, k, v, mask, q2, k2)
-    operands = (q, k, v, mask, o, lse, do, q2, k2)
-    for name, bq, bk, fn in kernels(mod, a):
+    operands = indexer_arrays(a) if indexer else attention_operands(mod, a)
+    for name, bq, bk, fn in every(mod, a):
         try:
             jax.block_until_ready(fn(*operands))
         except Exception as e:
@@ -223,7 +301,7 @@ def main():
             ms.append((time.perf_counter() - t0) / REPS * 1e3)
         say(kernel=name, bq=bq, bk=bk, ms=statistics.median(ms),
             ms_min=min(ms), ms_max=max(ms),
-            device_ms=device_ms(fn, operands),
+            **device_ms(fn, operands, whole_call=indexer),
             device=jax.devices()[0].device_kind)
     return 0
 
