@@ -15,6 +15,7 @@ reference's parameter-averaging threads / Spark / Aeron parameter server.
 
 __version__ = "0.1.0"
 
+import jax.monitoring
 import jax.profiler
 
 from . import obs
@@ -31,6 +32,14 @@ from .nn.multilayer import MultiLayerNetwork
 # spans land on the device trace's clock. Installed here, the one module
 # they all import, because obs/ itself imports no jax.
 obs.TRACER.annotate_with(jax.profiler.TraceAnnotation)
+
+# Every trace, lowering and backend compile of the process, and every hit
+# or miss of the persistent cache, into obs/compiles.py's counters and
+# spans: jax publishes them, obs/ counts them (always on, no switch).
+jax.monitoring.register_scalar_listener(obs.compiles.on_scalar)
+jax.monitoring.register_event_time_span_listener(obs.compiles.on_time_span)
+jax.monitoring.register_event_duration_secs_listener(obs.compiles.on_duration)
+jax.monitoring.register_event_listener(obs.compiles.on_event)
 
 __all__ = [
     "ComputationGraph",

@@ -147,17 +147,15 @@ def fused_program(net, key, builder):
     """Per-net cache of compiled fused programs, invalidated when the
     health watchdog or activation-stats mode toggles (the same
     generation counters ParallelWrapper watches)."""
-    from .. import obs
     gen = (net._health_gen, net._act_stats_gen)
     cache = net._fused_cache
     if cache is None or cache.get("gen") != gen:
         cache = {"gen": gen}
         net._fused_cache = cache
     if key not in cache:
-        # a fused-program (re)build is the expensive, rare event a trace
-        # must show: an unexpected span here mid-run means something is
-        # thrashing the program cache (health/act-stats toggles)
-        with obs.TRACER.span("train.compile", cat="train",
-                             key=repr(key)):
-            cache[key] = builder()
+        # the build is a `jax.jit` wrapper and compiles at its first
+        # dispatch like every other program: `Trainer._dispatch` is where
+        # a trace shows it (`train.compile`), and a toggle that thrashes
+        # this cache shows there as a dispatch that compiled again
+        cache[key] = builder()
     return cache[key]

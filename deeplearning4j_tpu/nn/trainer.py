@@ -34,6 +34,7 @@ optimize/solvers/StochasticGradientDescent.java:51-72.
 from __future__ import annotations
 
 import copy
+import time
 
 import jax
 import jax.numpy as jnp
@@ -90,14 +91,18 @@ class Trainer:
     # ------------------------------------------------------------------
     def init(self, parameters=None, clone_parameters=False):
         if self._params is None:
-            layers = [layer for _, layer in self._layer_items()]
-            keys = jax.random.split(self._rng, len(layers) + 1)
-            self._rng = keys[0]
-            self._params = self._per_layer(
-                layer.init_params(keys[i + 1], self.param_dtype)
-                for i, layer in enumerate(layers))
-            self._model_state = self._initial_state()
-            self._init_updater_state()
+            t0 = time.monotonic()
+            with obs.TRACER.span("train.init", cat="train"):
+                layers = [layer for _, layer in self._layer_items()]
+                keys = jax.random.split(self._rng, len(layers) + 1)
+                self._rng = keys[0]
+                self._params = self._per_layer(
+                    layer.init_params(keys[i + 1], self.param_dtype)
+                    for i, layer in enumerate(layers))
+                self._model_state = self._initial_state()
+                self._init_updater_state()
+            obs.default_registry().counter("train.init_s").inc(
+                time.monotonic() - t0)
         if parameters is not None:
             self.set_params(parameters)
         return self
@@ -410,13 +415,16 @@ class Trainer:
     def _dispatch(self, step, *batch, **span_args):
         """The ONE call of a compiled training program: the single step and
         the fused ones alike take and return the donated training state and
-        the loop state around their batch. Returns (score(s), carries,
-        extras)."""
+        the loop state around their batch. A dispatch that traced or
+        compiled is a `train.compile` span too (obs/compiles.py). Returns
+        (score(s), carries, extras)."""
         with obs.TRACER.span("train.dispatch", cat="train", **span_args):
+            mark = obs.compiles.mark()
             (self._params, self._updater_state, self._model_state, score,
              carries, self._loop, *extras) = step(
                  self._params, self._updater_state, self._model_state,
                  self._loop_state(), *batch)
+            obs.compiles.dispatched(mark, step, **span_args)
         return score, carries, extras
 
     def finish_step(self, score, extras, emits_health, classify=None,

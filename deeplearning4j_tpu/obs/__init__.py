@@ -21,6 +21,13 @@ package is that substrate:
     serving SLO metrics (TTFT, inter-token) scrape as.
   * `trace.FlightRecorder` — arm the tracer when rolling p99 crosses a
     threshold, so SLO violations self-document.
+  * `compiles` — what jax traces, lowers and compiles, from the
+    listeners the package root registers with `jax.monitoring`: the
+    process-wide `compile.*` counters, a `compile.backend` span for
+    every backend compile by the function's name, and the
+    `mark()` / `dispatched()` bracket by which a dispatch site of a
+    training program turns a dispatch that compiled into one
+    `train.compile` span and the `train.compile*` counters.
   * `decompose.decompose` — post-hoc span-derived latency
     decomposition: each served request's total attributed to
     queue-wait / prefill / decode / scheduling-gap phases (the
@@ -41,7 +48,7 @@ process-wide default tracer (disabled until `enable_tracing()`);
 """
 from __future__ import annotations
 
-from . import registry
+from . import compiles, registry
 from .decompose import decompose, decompose_requests
 from .fleet import (AutoscaleSignal, FleetView, merge_traces,
                     parse_prometheus_text)
@@ -73,7 +80,7 @@ def disable_tracing():
 __all__ = [
     "Tracer", "Span", "TraceContext", "FlightRecorder",
     "MetricsRegistry", "Histogram",
-    "default_registry", "fmt", "registry",
+    "default_registry", "fmt", "registry", "compiles",
     "decompose", "decompose_requests",
     "FleetView", "AutoscaleSignal", "merge_traces",
     "parse_prometheus_text",

@@ -400,9 +400,11 @@ class ParallelWrapper:
             net._last_batch_size = int(
                 jax.tree.leaves(feats)[0].shape[0])
             with obs.TRACER.span("parallel.dispatch", cat="train"):
+                mark = obs.compiles.mark()
                 (net._params, net._updater_state, net._model_state, score,
                  _, *extras) = step(net._params, net._updater_state,
                                     net._model_state, batch)
+                obs.compiles.dispatched(mark, step)
             # the trainer's epilogue over the wrapper's seam: a rolled-back
             # round rewound counters/rng, and the next batch retrains
             net.finish_step(score, extras, self._emits_health,
@@ -582,13 +584,15 @@ class ParallelWrapper:
         if self._kstep_health_gen != net._health_gen:
             self._jit_kstep = None         # watchdog toggled mid-life
             self._kstep_health_gen = net._health_gen
-        if self._jit_kstep is None:
+        mark = obs.compiles.mark()     # ahead of the build: what it compiles
+        if self._jit_kstep is None:    # ahead of time is this dispatch's
             self._jit_kstep = self._build_kstep()(batches_tree)
         with obs.TRACER.span("parallel.dispatch", cat="train", k=k):
             (net._params, net._updater_state, net._model_state,
              score, *extra) = self._jit_kstep(
                  net._params, net._updater_state, net._model_state,
                  batches_tree)
+            obs.compiles.dispatched(mark, self._jit_kstep, k=k)
         action = "ok"
         if self._kstep_emits_health:
             with obs.TRACER.span("parallel.health", cat="train", k=k):
