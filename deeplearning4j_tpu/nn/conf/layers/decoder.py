@@ -211,13 +211,15 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
     def init_state(self):
         return {"layer_loss": jnp.zeros((), jnp.float32),
                 "selected_keys": jnp.zeros((), jnp.float32),
-                "attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
+                "attend_grid_steps_per_tile": jnp.zeros((), jnp.float32),
+                "attend_backward_passes": jnp.zeros((), jnp.float32)}
 
     def gauges(self, state):
         return {"selected_keys_per_query": state["selected_keys"],
                 "indexer_loss": state["layer_loss"],
                 "attend_grid_steps_per_tile":
-                    state["attend_grid_steps_per_tile"]}
+                    state["attend_grid_steps_per_tile"],
+                "attend_backward_passes": state["attend_backward_passes"]}
 
     def init_params(self, key, dtype=jnp.float32):
         D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
@@ -304,7 +306,8 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
         parts = self.project(params, x, extras[0])
         # a row keeps for its backward its inputs, the selection and the
         # kernel's output; its scores and the target are computed again
-        from ....ops.sparse_attention import KEEP, grid_steps_per_tile
+        from ....ops.sparse_attention import (KEEP, backward_passes,
+                                              grid_steps_per_tile)
         row = jax.checkpoint(
             self._row,
             policy=jax.checkpoint_policies.save_only_these_names(KEEP))
@@ -314,7 +317,9 @@ class SparseAttentionLayer(_StatefulSequenceLayer):
         return out, {"layer_loss": jnp.sum(kl) / (B * T),
                      "selected_keys": jnp.sum(n) / (B * T),
                      "attend_grid_steps_per_tile": jnp.float32(
-                         grid_steps_per_tile(T))}
+                         grid_steps_per_tile(T)),
+                     "attend_backward_passes": jnp.float32(backward_passes(
+                         T, self.head_dim, self.head_dim))}
 
 
 # ---------------------------------------------------------------------------
@@ -366,7 +371,8 @@ class AttentionLayer(_StatefulSequenceLayer):
     init_std: float = 0.02
 
     def init_state(self):
-        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
+        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32),
+                "attend_backward_passes": jnp.zeros((), jnp.float32)}
 
     def gauges(self, state):
         return dict(state)
@@ -401,7 +407,8 @@ class AttentionLayer(_StatefulSequenceLayer):
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
-        from ....ops.sparse_attention import (grid_steps_per_tile,
+        from ....ops.sparse_attention import (backward_passes,
+                                              grid_steps_per_tile,
                                               masked_attention)
         B, T, _ = x.shape
         H, KV, Dh = self.n_heads, self.n_kv_heads, self.head_dim
@@ -424,7 +431,8 @@ class AttentionLayer(_StatefulSequenceLayer):
         # the kernels' schedule is a function of T: a constant of the trace
         return o.reshape(B, T, H * Dh) @ params["Wo"], {
             "attend_grid_steps_per_tile": jnp.float32(grid_steps_per_tile(
-                T, window=self.window))}
+                T, window=self.window)),
+            "attend_backward_passes": jnp.float32(backward_passes(T, Dh, Dh))}
 
 
 @register_layer("latentattention")
@@ -447,7 +455,8 @@ class LatentAttentionLayer(_StatefulSequenceLayer):
     init_std: float = 0.02
 
     def init_state(self):
-        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32)}
+        return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32),
+                "attend_backward_passes": jnp.zeros((), jnp.float32)}
 
     def gauges(self, state):
         return dict(state)
@@ -488,7 +497,8 @@ class LatentAttentionLayer(_StatefulSequenceLayer):
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
-        from ....ops.sparse_attention import (grid_steps_per_tile,
+        from ....ops.sparse_attention import (backward_passes,
+                                              grid_steps_per_tile,
                                               shared_key_attention)
         B, T, _ = x.shape
         H, dn, dr, dv = (self.n_heads, self.qk_nope_head_dim,
@@ -513,7 +523,9 @@ class LatentAttentionLayer(_StatefulSequenceLayer):
             out = jnp.moveaxis(o, 1, 2).reshape(B, T, H * dv) @ params["Wo"]
         # the kernels' schedule is a function of T: a constant of the trace
         return out, {"attend_grid_steps_per_tile": jnp.float32(
-            grid_steps_per_tile(T))}
+            grid_steps_per_tile(T)),
+                     "attend_backward_passes": jnp.float32(
+                         backward_passes(T, dn, dv, dr))}
 
 
 @register_layer("projection")

@@ -12,16 +12,25 @@ time. Grouped queries: query head a reads key/value head a // (H / KV),
 chosen by the block index map, nothing is repeated in HBM.
 
 - `masked_attention(q, k, v, mask)` -> (o, lse): forward and, through its
-  custom VJP, the FlashAttention-2 backward in two grid passes (dQ with the
-  key blocks innermost; dK/dV with the group's query heads and query blocks
-  innermost, so the sum over a group's heads happens in VMEM).
+  custom VJP, the FlashAttention-2 backward in ONE grid pass (PR 37): a
+  step makes its tile's scores, p, dp and ds once and from them the tile's
+  part of dQ (summed over a query block's key blocks, innermost), of dK and
+  of dV, which are summed in float32 slabs of all T keys that stay in VMEM
+  while the group's query heads pass. Where the slabs, T x (d + dv + d2) x
+  4 bytes, are over `SLAB_BUDGET` (16 MB: past T = 16,384 at 128-wide
+  heads) the backward is the two passes it was before: dQ with the key
+  blocks innermost; dK/dV with the group's query heads and query blocks
+  innermost, so the sum over a group's heads happens in VMEM, and scores
+  and dp made in both (7 products a tile for 5). `backward_passes` says
+  which, from shapes alone.
 - `shared_key_attention(q, k, v, q_shared, k_shared)` -> (o, lse): the same
-  three kernels for a latent attention, whose heads score over slots of
+  kernels for a latent attention, whose heads score over slots of
   their own AND over slots of ONE key that all of them share: two products
   a tile; the key's width need not be the value's (neither v nor o is
   padded to it); the shared key is read by index map, never repeated, and
-  its gradient, a sum over every head that reads it, is summed in VMEM
-  inside one run of the dK/dV kernel.
+  its gradient, a sum over every head that reads it, is summed in VMEM: in
+  a third slab that stays for all of the backward's run, or inside one run
+  of the two-pass dK/dV kernel.
 - `head_summed_probs(q, k, lse, mask)` -> [B, T, T]: the probabilities of
   all heads added up, pair by pair: the target of the indexer's loss.
 - `at_least_kth(scores, k)` -> int8 [R, S]: which entries of each row are at
@@ -36,13 +45,13 @@ chosen by the block index map, nothing is repeated in HBM.
   summed there. XLA's transposed dots wrote f32[S, C, heads] and a `pred`
   of that shape out for every chunk (PR 35).
 
-The same three kernels serve an `attention` layer's plain causal and
+The same kernels serve an `attention` layer's plain causal and
 windowed attention (`mask=None`): the tile's mask is then made inside the
 kernel from the block indices and an iota (key j visible to query i iff
 j <= i and, under a `window`, i - j < window), and only on the tiles that
 the diagonal or the band's far edge crosses; no [T, T] array exists.
 
-The mask is causal, so `masked_attention`'s three kernels walk only the
+The mask is causal, so `masked_attention`'s kernels walk only the
 (query block, key block) tiles with a visible pair (on or under the
 diagonal and, under a window, inside the band): their grid is (batch x head,
 step), and `tile_schedule` lists each step's tile in int32 tables that
@@ -75,15 +84,26 @@ NEG = -1e30      # a masked score; finite, so a row that has met no key yet
 FLOOR = -1e20    # where the forward's running maximum starts: under every
 #                  real score and so far above NEG that exp(NEG - m) is 0 by
 #                  itself; a row that has met no key keeps l = 0
-BLOCK = 1024     # query and key block of masked_attention's three kernels,
+BLOCK = 1024     # query and key block of masked_attention's kernels,
 #                  under a mask operand and plain causal alike: the fastest
 #                  of 256 .. 2048 a side on the chip at T = 8192, d = 128
-#                  (PERF.md section 6, PR 29; the scoped VMEM default holds
-#                  its 4 MB float32 tiles) and of those measured at
-#                  T = 16384 with the mask made from positions (PR 32)
+#                  (PERF.md section 6, PR 29) and of those measured at
+#                  T = 16384 with the mask made from positions (PR 32). The
+#                  scoped VMEM default (16 MB) holds the forward's and the
+#                  two-pass backward's 4 MB float32 tiles; the one-pass
+#                  backward's four tiles beside its slabs ask for BWD_VMEM
 WINDOW_BLOCK = (512, 512)     # (query, key) block under a window (PERF.md
 #                  section 6, PR 32: measured alone on the chip at
 #                  T = 16384, d = 128, window 512)
+SLAB_BUDGET = 16 << 20      # the one-pass backward's float32 slabs of dK, dV
+#                  (and a shared key's dK2), T x (d + dv + d2) x 4 bytes: up
+#                  to here one kernel, past it the two (`backward_passes`);
+#                  T = 16384 at d = dv = 128 is the budget exactly
+BWD_VMEM = 64 << 20         # the one-pass backward's VMEM: at the budget the
+#                  slabs' 16 MB, their bf16 output blocks' two buffers 16
+#                  and the 1024 x 1024 tiles need 48 MB (compiled for a
+#                  described v5e: 40 is refused; at T = 8192 32 passes),
+#                  over the scoped default of 16, under the chip's 128
 
 
 def _params(interpret, semantics, **more):
@@ -133,9 +153,11 @@ def _each_tile(mask_ref, e, tile, step):
 FIRST, LAST = 1, 2      # bits of a step's `edge`: its run's first, its last
 CROSSED = 4             # the diagonal or the band's far edge crosses the
 #                         tile: some of its pairs are masked by position
-OWN_FIRST, OWN_LAST = 8, 16     # `by_key` under a shared key: the first and
-#                         last step of ONE key/value head's run inside the
-#                         run of the shared key's block
+OWN_FIRST, OWN_LAST = 8, 16     # the first and last step of ONE key/value
+#                         head's run: in `by_key` under a shared key, inside
+#                         the run of the shared key's block; in `one_pass`,
+#                         inside the run of all heads that read a shared key
+#                         (the whole of it where there is none)
 
 
 def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
@@ -151,8 +173,13 @@ def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
       first(i) .. last(i) in order (forward, dQ: one run a query block);
     - `by_key` (j, r, i, edge): key block by key block, inside it head r of
       `heads` (a key/value head's group of query heads), inside that the
-      query blocks first(j) .. last(j) (dK/dV: one run a key block, so the
-      sum over the group's heads stays in VMEM).
+      query blocks first(j) .. last(j) (the two-pass dK/dV: one run a key
+      block, so the sum over the group's heads stays in VMEM);
+    - `one_pass` (r, i, j, edge): `by_query` once for each head r of `heads`
+      (the one-pass backward: dQ's run is a query block's, as in
+      `by_query`; dK and dV are summed in slabs of all T keys that stay in
+      VMEM for the `own` heads, all `heads` without it, that read one
+      key/value head: OWN_FIRST .. OWN_LAST).
 
     `edge` marks a run's first step (FIRST: clear the accumulators), its
     last (LAST: write the result) and a tile with a masked pair (CROSSED:
@@ -160,7 +187,9 @@ def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
     (`shared_key_attention`: `heads` query heads read one shared key, each
     `own` of them one key/value head of their own) `by_key` also marks
     where a key/value head's own run inside the shared block's begins and
-    ends (OWN_FIRST, OWN_LAST)."""
+    ends (OWN_FIRST, OWN_LAST). For a fixed key block `one_pass` and
+    `by_key` visit the tiles in the same order: head r, then query block
+    i."""
     nq, nk = T // bq, T // bk
     far = T if window is None else window     # i - j < far is always asked
     # the key blocks query block i sees, the query blocks that see j
@@ -182,24 +211,35 @@ def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
                | LAST * (r == heads - 1 and i == queries(j)[-1])
                | crossed(i, j) | owns(r, i, queries(j)))
               for j in range(nk) for r in range(heads) for i in queries(j)]
+    group = own or heads
+    one_pass = [(r, i, j, e
+                 | OWN_FIRST * (r % group == 0 and t == 0)
+                 | OWN_LAST * (r % group == group - 1
+                               and t == len(by_query) - 1))
+                for r in range(heads) for t, (i, j, e) in enumerate(by_query)]
     cols = lambda rows: tuple(np.asarray(c, np.int32) for c in zip(*rows))
     return {"grid_steps": len(by_query),
             "computing_steps": sum(
                 j * bk <= i * bq + bq - 1 and i * bq - (j * bk + bk - 1) < far
                 for i, j, _ in by_query),
-            "by_query": cols(by_query), "by_key": cols(by_key)}
+            "by_query": cols(by_query), "by_key": cols(by_key),
+            "one_pass": cols(one_pass)}
 
 
 def _call(kernel, tables, grid, in_specs, out_specs, out_shape, scratch,
-          name, interpret, operands):
+          name, interpret, operands, aliases=None, **more):
     """One kernel over (batch x head, the steps of `tables`): the tables
-    are prefetched scalars, read by the index maps and by the kernel."""
+    are prefetched scalars, read by the index maps and by the kernel;
+    `aliases` {operand: result} are written in place; `more` goes to the
+    compiler."""
     return pl.pallas_call(
         kernel, out_shape=out_shape, interpret=interpret, name=name,
+        input_output_aliases={len(tables) + a: b
+                              for a, b in (aliases or {}).items()},
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
-        **_params(interpret, ("parallel", "arbitrary")))(
+        **_params(interpret, ("parallel", "arbitrary"), **more))(
             *(jnp.asarray(t) for t in tables), *operands)
 
 
@@ -401,14 +441,167 @@ def _dkv_kernel(j_tab, r_tab, i_tab, edge, q_ref, k_ref, v_ref, *rest,
             dk2_ref[0] = dk2_acc[:].astype(dk2_ref.dtype)
 
 
+def _bwd_kernel(r_tab, i_tab, j_tab, edge, q_ref, k_ref, v_ref, *rest, scale,
+                bk, band=None, shared=False):
+    """The whole backward of a tile in one step: its scores, p, dp and ds
+    are made once, `ds k` joins the query block's dQ (one run a query
+    block, as `_dq_kernel`'s), `p^T do` and `ds^T q` join rows j * bk .. of
+    dV's and dK's float32 slabs of all T keys, which stay in VMEM while the
+    heads that read the key/value head pass (OWN_FIRST .. OWN_LAST) and
+    are written out once. Under a shared key all its query heads pass in
+    one run of the grid's second axis, and dK2's slab stays for them all."""
+    if shared:
+        (*rest, q2_ref, k2_ref, dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
+         dq_acc, dk_acc, dv_acc, dq2_acc, dk2_acc) = rest
+    else:
+        *rest, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    more = (q2_ref, k2_ref) if shared else ()
+    *mask_ref, do_ref, lse_ref, delta_ref = rest
+    mask_ref = mask_ref[0] if mask_ref else None
+    t = pl.program_id(1)
+    e = edge[t]
+
+    @pl.when(e & FIRST != 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+        if shared:
+            dq2_acc[:] = jnp.zeros_like(dq2_acc)
+
+    @pl.when(e & OWN_FIRST != 0)
+    def _init_own():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if shared:
+        @pl.when(t == 0)
+        def _init_shared():
+            dk2_acc[:] = jnp.zeros_like(dk2_acc)
+
+    def step(tile):
+        keys = pl.ds(pl.multiple_of(j_tab[t] * bk, bk), bk)
+        p = jnp.exp(_scores(q_ref, k_ref, mask_ref, scale, band, tile, more)
+                    - lse_ref[0])
+        dv_acc[keys] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        dq_acc[:] += jax.lax.dot_general(
+            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        dk_acc[keys] += jax.lax.dot_general(
+            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if shared:
+            dq2_acc[:] += jax.lax.dot_general(
+                ds.astype(k2_ref.dtype), k2_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            dk2_acc[keys] += jax.lax.dot_general(
+                ds.astype(q2_ref.dtype), q2_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+    _each_tile(mask_ref, e, (i_tab[t], j_tab[t]), step)
+
+    @pl.when(e & LAST != 0)
+    def _emit():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        if shared:
+            dq2_ref[0] = dq2_acc[:].astype(dq2_ref.dtype)
+
+    @pl.when(e & OWN_LAST != 0)
+    def _emit_own():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if shared:
+        @pl.when(t == pl.num_programs(1) - 1)
+        def _emit_shared():
+            dk2_ref[0] = dk2_acc[:].astype(dk2_ref.dtype)
+
+
+def backward_passes(T, d, dv, d2=0):
+    """How many times the backward walks its tiles at T keys of scored
+    width `d` (+ `d2` against a shared key) and summed width `dv`: 1 where
+    the one-pass kernel's float32 slabs of dK, dV (and dK2) fit SLAB_BUDGET,
+    2 where `_bwd` keeps the dQ and the dK/dV kernel. Shapes alone decide."""
+    return 1 if T * (d + dv + d2) * 4 <= SLAB_BUDGET else 2
+
+
 def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret, window=None,
          shared=()):
+    """(dq, dk, dv) or, with `shared`, (dq, dk, dv, dq2, dk2): by one kernel
+    where `backward_passes` says 1, else by two."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
+                    keepdims=True)
+    d2 = shared[1].shape[-1] if shared else 0
+    walk = (_one_pass if backward_passes(q.shape[1], q.shape[2], v.shape[-1],
+                                         d2) == 1 else _two_pass)
+    return walk(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
+                window, shared)
+
+
+def _one_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
+              window, shared):
+    BH, T, d = q.shape
+    BKV, dv = k.shape[0], v.shape[-1]
+    B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
+    R, KV = BH // BKV, BKV // B
+    BKS, d2 = shared[1].shape[::2] if shared else (BKV, None)
+    R2 = BH // BKS              # query heads a run of the second axis passes
+    vm = {"memory_space": pltpu.VMEM}
+    # step t of key head g (the shared key's where there is one, else the
+    # key/value head): head r[t] of the R2 query heads that read g, query
+    # block i[t], key block j[t] (`tile_schedule`'s `one_pass`); the
+    # key/value head is query head g * R2 + r[t]'s
+    sched = tile_schedule(T, bq, bk, heads=R2, window=window,
+                          own=R if shared else None)
+    head = lambda g, r, t: g * R2 + r[t]
+    of_query = lambda w: pl.BlockSpec(
+        (1, bq, w), lambda g, t, r, i, j, e: (head(g, r, t), i[t], 0), **vm)
+    of_key = lambda w: pl.BlockSpec(
+        (1, bk, w), lambda g, t, r, i, j, e: (head(g, r, t) // R, j[t], 0),
+        **vm)
+    slab = lambda w: pl.BlockSpec(
+        (1, T, w), lambda g, t, r, i, j, e: (head(g, r, t) // R, 0, 0), **vm)
+    shared_block = pl.BlockSpec(
+        (1, bk, d2), lambda g, t, r, i, j, e: (g, j[t], 0), **vm)
+    shared_slab = pl.BlockSpec(
+        (1, T, d2), lambda g, t, r, i, j, e: (g, 0, 0), **vm)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    operands = _given(mask, (q, k, v), mask, (do, lse, delta, *shared))
+    return tuple(_call(
+        functools.partial(_bwd_kernel, scale=scale, bk=bk,
+                          band=None if mask is not None else (bq, bk, window),
+                          shared=bool(shared)),
+        sched["one_pass"], (BKS, R2 * sched["grid_steps"]),
+        _given(mask, (of_query(d), of_key(d), of_key(dv)),
+               pl.BlockSpec((1, bq, bk),
+                            lambda g, t, r, i, j, e: (g // KV, i[t], j[t]),
+                            **vm),
+               (of_query(dv), of_query(1), of_query(1),
+                *((of_query(d2), shared_block) if shared else ()))),
+        [of_query(d), slab(d), slab(dv),
+         *((of_query(d2), shared_slab) if shared else ())],
+        [shape(q), shape(k), shape(v), *(shape(a) for a in shared)],
+        [pltpu.VMEM(dims, jnp.float32) for dims in (
+            (bq, d), (T, d), (T, dv),
+            *(((bq, d2), (T, d2)) if shared else ()))],
+        "sparse_attention_bwd", interpret, operands,
+        # q, k, v and the shared pair, the last two operands, die with
+        # this call: their gradients are written in their places
+        aliases=dict(zip((0, 1, 2, len(operands) - 2, len(operands) - 1),
+                         range(3 + len(shared)))),
+        vmem_limit_bytes=BWD_VMEM))
+
+
+def _two_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
+              window, shared):
     BH, T, d = q.shape
     BKV, dv = k.shape[0], v.shape[-1]
     B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
     H, R, KV = BH // B, BH // BKV, BKV // B
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
-                    keepdims=True)
     vm = {"memory_space": pltpu.VMEM}
     band = None if mask is not None else (bq, bk, window)
     operands = _given(mask, (q, k, v), mask, (do, lse, delta, *shared))

@@ -182,6 +182,8 @@ def test_fit_agrees_with_the_reference(followed, what):
         for at in ("l0", "l1", "mtp"):
             assert got["gauges"][
                 f"latentattention.{at}_attn.attend_grid_steps_per_tile"] == 1
+            assert got["gauges"][
+                f"latentattention.{at}_attn.attend_backward_passes"] == 1
     else:
         # after three steps every entry is within 3 gamma of zero and is
         # the reference's; it moved, and not all one way
@@ -514,8 +516,11 @@ def test_the_forward_kernel_runs_once_a_layer(lowered):
         fwd = {p for p in mine
                if p.endswith("sparse_attention_fwd/pallas_call")}
         assert len(fwd) == 1 and not fwd & again, fwd
-        for kernel in ("sparse_attention_dq", "sparse_attention_dkv"):
-            assert any(p.endswith(f"{kernel}/pallas_call") for p in mine)
+        # the backward is one kernel a layer (PR 37), not dQ's and dK/dV's
+        bwd = {p for p in mine if re.search(
+            r"sparse_attention_(bwd|dq|dkv)/pallas_call$", p)}
+        assert len(bwd) == 1 and next(iter(bwd)).endswith(
+            "sparse_attention_bwd/pallas_call"), bwd
         assert any("/latent/" in p for p in mine & again), at
     from deeplearning4j_tpu import obs
     net = ComputationGraph(conf_of()).init()

@@ -503,6 +503,22 @@ def test_the_experts_of_the_lowered_step_are_loops_under_both_names():
     assert all("moe.l" in p for p in experts)
 
 
+def test_the_backward_of_attend_in_the_lowered_step_is_one_kernel():
+    """What `train_sparse_attn_device_ms` reads is the scope `attend`: the
+    row's backward holds under it ONE kernel that makes dQ, dK and dV
+    (PR 37: `sparse_attention_bwd`; the row's path carries no vertex, so
+    both layers' read the same) where it held dQ's and dK/dV's, beside the
+    forward kernel of the step and of the row's rematerialisation."""
+    text = ComputationGraph(conf_of()).init().lower_step(
+        mds_of(batch_of(0))).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*/[^"]*)"', text))
+    mine = {p for p in paths if re.search(
+        r"sparse_attention_(fwd|bwd|dq|dkv)/pallas_call$", p)}
+    assert mine == {"attend/sparse_attention_fwd/pallas_call",
+                    "checkpoint/attend/sparse_attention_fwd/pallas_call",
+                    "checkpoint/attend/sparse_attention_bwd/pallas_call"}
+
+
 SKEWED = dict(MODEL, num_hidden_layers=1,
               deployment={"router_width": 16, "first_held": 0})
 

@@ -173,6 +173,9 @@ def test_fit_agrees_with_the_reference(followed, what):
         for j in range(5):
             assert got["gauges"][
                 f"attention.l{j}_attn.attend_grid_steps_per_tile"] == 1.0
+            # and the backward walks them once
+            assert got["gauges"][
+                f"attention.l{j}_attn.attend_backward_passes"] == 1.0
 
 
 def attention_layer(window, heads=8, **over):
@@ -447,8 +450,11 @@ def test_a_segment_keeps_its_attention_kernels_output_and_recomputes_the_rest(
         fwd = {p for p in mine if p.endswith("sparse_attention_fwd/pallas_call")}
         assert len(fwd) == 1 and not fwd & again, fwd
         assert "transpose(" not in next(iter(fwd))
-        for kernel in ("sparse_attention_dq", "sparse_attention_dkv"):
-            assert any(p.endswith(f"{kernel}/pallas_call") for p in mine)
+        # the backward is one kernel a layer (PR 37), not dQ's and dK/dV's
+        bwd = {p for p in mine if re.search(
+            r"sparse_attention_(bwd|dq|dkv)/pallas_call$", p)}
+        assert len(bwd) == 1 and next(iter(bwd)).endswith(
+            "sparse_attention_bwd/pallas_call"), bwd
         for what in ("/rotary/", "/gate/", "/dot_general"):
             assert any(what in p for p in mine & again), (i, what)
     # the parent's text, where the layer names nothing: the kernel twice

@@ -149,10 +149,12 @@ def _small_resnet50():
 # holds the step without rematerialisation. A PR that changes one of these
 # steps on purpose computes its hash anew (the body of the test below):
 # PR 35 did for "tiny-keye", whose index scores got a backward of their own
-# (`decoder.index_scores`); the two CNNs' are the parent's still.
+# (`decoder.index_scores`), and PR 37, whose attention backward became one
+# kernel (`sparse_attention_bwd`) and whose layers' state gained
+# `attend_backward_passes`; the two CNNs' are the parent's still.
 REMAT_STEP_SHA256 = {
     "tiny-keye":
-        "6bcac9d3e10fbec834aadf123452dc7bbf477d1f33573ed555a052ea9bdbf0ad",
+        "a3a2588f051145ec25f9e3e2bf55d85cd9a91aa6aadebdfe674179e8a668f2eb",
     "residual-cnn":
         "9a7f306c2963ca5d54481136528d1580c1fdf06d524de743784f6a466d7a2cb1",
     "resnet50":

@@ -4,7 +4,8 @@ against a dense float32 reference under a random causal selection, tiled
 against one tile, and the tile schedule the kernels build their grid from;
 the index scores' hand-written backward (`index_scores_bwd`, PR 35) against
 `jax.vjp` of the expression it replaced, alone and through the layer.
-Interpreted on the CPU."""
+The one-pass backward (PR 37) against the two kernels it took the place of,
+bit for bit, and against dense attention. Interpreted on the CPU."""
 import os
 import re
 import sys
@@ -18,9 +19,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from deeplearning4j_tpu.nn.conf.layers import decoder             # noqa: E402
+from deeplearning4j_tpu.ops import sparse_attention as sa         # noqa: E402
 from deeplearning4j_tpu.ops.sparse_attention import (             # noqa: E402
-    FIRST, LAST, grid_steps_per_tile, index_scores_bwd, masked_attention,
-    tile_schedule)
+    FIRST, LAST, OWN_FIRST, OWN_LAST, backward_passes, grid_steps_per_tile,
+    index_scores_bwd, masked_attention, shared_key_attention, tile_schedule)
 
 D = 16
 
@@ -147,6 +149,173 @@ def runs(owner, edge):
         prev = o
 
 
+# ------------------------------------------------- the one-pass backward
+# (how the tile's mask comes, T, bq, bk, H, KV, window, shared keys): CASES
+# under a mask operand; by position, plain causal and under a window that
+# is, and is not, a multiple of the blocks; a shared key read by every head
+# with `own` = H / KV = 1, 2 and 4 query heads a key/value head, and two
+# shared keys (the grid's first axis is then the shared key's)
+ONE_PASS = [("mask", *c, None, 0) for c in CASES] + [
+    ("causal", 64, 16, 32, 4, 2, None, 0),
+    ("causal", 96, 32, 16, 6, 1, None, 0),
+    ("window", 96, 16, 32, 4, 2, 32, 0),
+    ("window", 64, 16, 16, 4, 1, 24, 0),
+    ("window", 128, 32, 32, 2, 2, 40, 0),
+    ("shared", 64, 16, 32, 4, 4, None, 1),
+    ("shared", 64, 32, 16, 8, 4, None, 1),
+    ("shared", 96, 32, 32, 8, 2, None, 1),
+    ("shared", 64, 16, 16, 8, 4, None, 2)]
+D2 = 8      # slots of a shared key
+
+
+def one_pass_case(how, T, H, KV, window, KS, gap, seed):
+    """(fn of (q, k, v[, q2, k2]) -> (o, lse), its operands, do, the dense
+    reference of the same)."""
+    q, k, v, mask, do = inputs(T, H, KV, gap, seed)
+    scale = (D + (D2 if KS else 0)) ** -0.5
+    t, s = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    if how != "mask":
+        mask = ((s <= t) & ((t - s < window) if window else True)).astype(
+            jnp.int8)[None]
+    if not KS:
+        given = mask if how == "mask" else None
+        return (lambda bq, bk: lambda q, k, v: masked_attention(
+            q, k, v, given, scale, bq, bk, window=window), (q, k, v), do,
+            lambda q, k, v: dense(q, k, v, mask, scale))
+    k2, kq = jax.random.split(jax.random.PRNGKey(seed + 1))
+    q2 = jax.random.normal(kq, (1, H, T, D2), jnp.float32)
+    k2 = jax.random.normal(k2, (1, KS, T, D2), jnp.float32)
+    rep = lambda a: jnp.repeat(a, H // a.shape[1], 1)
+    return (lambda bq, bk: lambda *a: shared_key_attention(*a, scale, bq, bk),
+            (q, k, v, q2, k2), do,
+            lambda q, k, v, q2, k2: dense(
+                jnp.concatenate([q, q2], -1),
+                jnp.concatenate([rep(k), rep(k2)], -1), rep(v), mask, scale))
+
+
+def gradients(fn, operands, do):
+    (o, lse), pull = jax.vjp(fn, *operands)
+    return pull((do, jnp.zeros_like(lse)))
+
+
+@pytest.mark.parametrize("how,T,bq,bk,H,KV,window,KS", ONE_PASS)
+def test_one_pass_backward_is_the_two_kernels_and_dense_attention(
+        how, T, bq, bk, H, KV, window, KS, monkeypatch):
+    """dQ, dK, dV (and a shared key's dQ2, dK2) of the one kernel are the
+    two kernels' bit for bit: for a fixed key block `one_pass` adds into
+    dK[j], dV[j], dK2[j] in `by_key`'s order (head r, then query block i),
+    and into dQ in `by_query`'s (j); every product has the operands and
+    the shape it had. Dense attention differs by float32 sums' order."""
+    fn, operands, do, plain = one_pass_case(
+        how, T, H, KV, window, KS, gap=max(bq, bk), seed=T + bq)
+    assert backward_passes(T, D, D, D2 if KS else 0) == 1
+    one = gradients(fn(bq, bk), operands, do)
+    monkeypatch.setattr(sa, "SLAB_BUDGET", 0)       # nothing fits: two passes
+    two = gradients(fn(bq, bk), operands, do)
+    want = gradients(plain, operands, do)
+    assert len(one) == len(operands)
+    for name, a, b, w in zip(("dq", "dk", "dv", "dq2", "dk2"), one, two,
+                             want):
+        assert a.shape == w.shape and a.dtype == w.dtype, name
+        assert np.abs(np.asarray(w)).max() > 0.1, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+        np.testing.assert_allclose(a, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def kernels_of(fn, *operands):
+    """The names of the Pallas kernels in `fn`'s jaxpr."""
+    return sorted(set(re.findall(r"sparse_attention_\w+",
+                                 str(jax.make_jaxpr(fn)(*operands)))))
+
+
+WALKS = {1: ["sparse_attention_bwd", "sparse_attention_fwd"],
+         2: ["sparse_attention_dkv", "sparse_attention_dq",
+             "sparse_attention_fwd"]}
+
+
+# one head of 4,096 slots at 576 keys: slabs of 576 x 8,192 x 4 bytes = 18.9
+# MB, over SLAB_BUDGET as it stands (16.8 MB); at 384 keys 12.6 MB, under
+@pytest.mark.parametrize("T, passes", [(384, 1), (576, 2)])
+def test_slabs_over_the_budget_take_the_two_kernels(T, passes, monkeypatch):
+    d = 4096
+    assert (T * 2 * d * 4 > sa.SLAB_BUDGET) == (passes == 2)
+    assert backward_passes(T, d, d) == passes
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 1, T, d), jnp.float32)
+                   for kk in ks)
+    fn = lambda q, k, v: masked_attention(q, k, v, None, d ** -0.5, 192, 192)
+    # a new function a call: `make_jaxpr` remembers the one it has traced
+    pull = lambda: lambda q, k, v: gradients(fn, (q, k, v), do)
+    assert kernels_of(pull(), q, k, v) == WALKS[passes]
+    got = pull()(q, k, v)
+    # the other walk, by a budget that is not the module's
+    monkeypatch.setattr(sa, "SLAB_BUDGET",
+                        0 if passes == 1 else T * 2 * d * 4)
+    assert kernels_of(pull(), q, k, v) == WALKS[3 - passes]
+    for name, a, b in zip(("dq", "dk", "dv"), got, pull()(q, k, v)):
+        assert np.abs(np.asarray(a)).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_the_choice_of_passes_reads_shapes_alone():
+    """The benchmark's three geometries take one pass; at 128-wide heads the
+    two kernels come back past T = 16,384."""
+    assert backward_passes(8192, 128, 128) == 1             # train-vl8k
+    assert backward_passes(16384, 128, 128) == 1            # train-lc16k
+    assert backward_passes(8192, 128, 128, 64) == 1         # train-mtp8k
+    assert backward_passes(16384 + 1024, 128, 128) == 2
+    assert backward_passes(16384, 128, 128, 64) == 2
+
+
+@pytest.mark.parametrize("head_dim, passes", [(16, 1.0), (4096, 2.0)])
+def test_gauge_reads_the_backward_passes(head_dim, passes):
+    """`attend_backward_passes` in the layer's state, beside
+    `attend_grid_steps_per_tile`: 1.0 where the layer's call takes the one
+    kernel, 2.0 where its slabs are over the budget."""
+    layer = decoder.AttentionLayer(n_in=32, n_out=32, n_heads=1,
+                                   n_kv_heads=1, head_dim=head_dim)
+    params = layer.init_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 576, 32), jnp.float32)
+    _, state = layer.forward_with_state(params, x, layer.init_state())
+    assert sorted(state) == sorted(layer.init_state())
+    assert layer.gauges(state)["attend_backward_passes"] == passes
+    assert layer.gauges(state)["attend_grid_steps_per_tile"] == 1.0
+
+
+@pytest.mark.parametrize("T,bq,bk,window", [(256, 32, 32, None),
+                                            (256, 64, 32, None),
+                                            (256, 32, 64, None),
+                                            (256, 32, 32, 48),
+                                            (64, 64, 64, None)])
+@pytest.mark.parametrize("heads,own", [(1, None), (3, None), (4, 2), (4, 1)])
+def test_one_pass_schedule(T, bq, bk, window, heads, own):
+    """`one_pass` is `by_query` once a head; a key/value head's slabs are
+    cleared at its first head's first step and written at its last head's
+    last; and for a fixed key block the tiles come in `by_key`'s order."""
+    sched = tile_schedule(T, bq, bk, heads=heads, window=window, own=own)
+    i0, j0, e0 = sched["by_query"]
+    r, i, j, e = sched["one_pass"]
+    n = sched["grid_steps"]
+    assert all(a.dtype == np.int32 and len(a) == heads * n
+               for a in (r, i, j, e))
+    group = own or heads
+    for h in range(heads):
+        mine = slice(h * n, h * n + n)
+        assert (r[mine] == h).all()
+        np.testing.assert_array_equal(i[mine], i0)
+        np.testing.assert_array_equal(j[mine], j0)
+        np.testing.assert_array_equal(e[mine] & ~(OWN_FIRST | OWN_LAST), e0)
+        firsts = np.flatnonzero(e[mine] & OWN_FIRST)
+        lasts = np.flatnonzero(e[mine] & OWN_LAST)
+        assert firsts.tolist() == ([0] if h % group == 0 else [])
+        assert lasts.tolist() == ([n - 1] if h % group == group - 1 else [])
+    runs(list(zip(r.tolist(), i.tolist())), e)      # dQ's: a (head, block)
+    kj, kr, ki, _ = sched["by_key"]
+    for block in range(T // bk):
+        assert list(zip(r[j == block], i[j == block])) == list(
+            zip(kr[kj == block], ki[kj == block]))
+
+
 def test_gauge_reads_one_grid_step_a_tile():
     """Through `publish_layer_gauges()` on the tiny configuration, after a
     step of `fit`: the constant travels through the layer's state."""
@@ -160,6 +329,9 @@ def test_gauge_reads_one_grid_step_a_tile():
         "sparseattention.l0_attn.attend_grid_steps_per_tile",
         "sparseattention.l1_attn.attend_grid_steps_per_tile"]
     assert set(steps.values()) == {1.0}
+    # and their backward walks them once (PR 37)
+    assert [said[f"sparseattention.l{i}_attn.attend_backward_passes"]
+            for i in (0, 1)] == [1.0, 1.0]
 
 
 # ------------------------------------------- the index scores' backward

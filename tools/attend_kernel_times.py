@@ -1,7 +1,14 @@
-"""One call of each masked-attention kernel (ops/sparse_attention.py: forward,
-dQ, dK/dV) timed alone on the chip at a cell's shape, block shape by block
-shape: the numbers PERF.md gives for "a call", and what the module's `BLOCK`
-and `WINDOW_BLOCK` were chosen from. One JSON line a (kernel, block shape).
+"""One call of each masked-attention kernel (ops/sparse_attention.py: forward
+and backward) timed alone on the chip at a cell's shape, block shape by
+block shape: the numbers PERF.md gives for "a call", and what the module's
+`BLOCK` and `WINDOW_BLOCK` were chosen from. One JSON line a (kernel, block
+shape). `bwd` (PR 37) is the whole of `_bwd`, whatever kernels the module
+makes it of (one since PR 37, dQ's and dK/dV's before it and past
+`SLAB_BUDGET`): `device_ms` there is ALL the device's operations of a call,
+`kernels_device_ms` the kernels' own, so that parent and change read on one
+scale. `dq` and `dkv` call `_bwd` too and keep a part of its results: of a
+two-pass module that leaves one kernel in the program, of a one-pass module
+its one kernel, whole, either way.
 `--schedule mask` (the default) is `train-vl8k`'s: a mask operand at
 T = 8192, 32 / 4 heads; `causal` and `window` are `train-lc16k`'s
 full and window layers: the mask made from positions at T = 16384, 48 or
@@ -25,6 +32,8 @@ is the chunk); `device_ms` there is ALL the device's operations of a call.
         --blocks 256x256,256x512,512x512
     chiprun -- python tools/attend_kernel_times.py \\
         --module .chip_tree/parent/deeplearning4j_tpu/ops/sparse_attention.py
+    chiprun -- python tools/attend_kernel_times.py --schedule latent \\
+        --blocks 1024x1024 --kernels fwd,bwd
 
 `--module` times another checkout's kernels in the same process (a parent
 unpacked under .chip_tree/). `--compile-only` compiles every shape for a
@@ -115,7 +124,8 @@ def one_each(mod, scale, bq, bk, a):
             flat(q), flat(k), flat(v), given(mask), scale, bq, bk, False,
             **by, **shared(q2, k2)),
         "dq": lambda *a: dq_of(bwd(*a)),
-        "dkv": lambda *a: dkv_of(bwd(*a))}
+        "dkv": lambda *a: dkv_of(bwd(*a)),
+        "bwd": bwd}
 
 
 def plain_index_scores(qi, ki, w):
@@ -258,7 +268,7 @@ def main():
     a.blocks = a.blocks or ("x512,x1024" if indexer else
                             "512x512,1024x512,512x1024,1024x1024")
     a.kernels = a.kernels or ("index_fwd,index_vjp_xla,index_bwd" if indexer
-                              else "fwd,dq,dkv")
+                              else "fwd,bwd")
     mod = load(a.module)
     every = indexer_kernels if indexer else kernels
     say = lambda **kw: print(json.dumps(
@@ -301,7 +311,7 @@ def main():
             ms.append((time.perf_counter() - t0) / REPS * 1e3)
         say(kernel=name, bq=bq, bk=bk, ms=statistics.median(ms),
             ms_min=min(ms), ms_max=max(ms),
-            **device_ms(fn, operands, whole_call=indexer),
+            **device_ms(fn, operands, whole_call=indexer or name == "bwd"),
             device=jax.devices()[0].device_kind)
     return 0
 
