@@ -8,6 +8,7 @@ from .convolution import (ConvolutionLayer, GlobalPoolingLayer,
 from .feedforward import (ActivationLayer, AutoEncoder, DenseLayer,
                           DropoutLayer, EmbeddingLayer, LossLayer, OutputLayer,
                           RnnOutputLayer)
+from .mamba2 import Mamba2Layer
 from .normalization import BatchNormalization, LocalResponseNormalization
 from .rbm import RBM
 from .recurrent import (BaseRecurrentLayer, GravesBidirectionalLSTM,
@@ -25,7 +26,7 @@ __all__ = [
     "BaseRecurrentLayer", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
     "SelfAttentionLayer", "TokenEmbeddingLayer", "RMSNormLayer",
     "SparseAttentionLayer", "AttentionLayer", "LatentAttentionLayer",
-    "ProjectionLayer", "GatedMLPLayer", "MoELayer",
+    "ProjectionLayer", "GatedMLPLayer", "MoELayer", "Mamba2Layer",
     "LMHeadLayer", "RBM", "VariationalAutoencoder",
     "BernoulliReconstructionDistribution",
     "GaussianReconstructionDistribution",

@@ -1,7 +1,7 @@
 """Layers of a mixture-of-experts decoder, for the containers.
 
 Beyond-reference capability (the reference predates all of it). Nine layer
-kinds, each traced under its own `<kind>.<vertex>` scope by the container:
+kinds here (a tenth, the `mamba2` state-space mixer, in `mamba2.py`), each traced under its own `<kind>.<vertex>` scope by the container:
 
 - `tokenembedding`: ids [B, T] -> rows of a table; a second input
   (`extras[0]`, [B, P, D]: an image's embeddings) replaces the rows at the
@@ -35,7 +35,8 @@ kinds, each traced under its own `<kind>.<vertex>` scope by the container:
   of the experts (`parallel/moe.py` `held_experts_ffn`: nothing dropped);
   with `shared_width`, a shared expert that every token passes, added
   unscaled (inner scope `shared`); `routed_scale` multiplies the routed
-  weights. `scoring` "sigmoid" scores each expert alone, and with
+  weights. With `activation` "relu2" an expert is relu(x Wu)^2 Wd, two
+  matrices and no gate. `scoring` "sigmoid" scores each expert alone, and with
   `bias_update_rate` the top k are chosen by score PLUS a bias that is the
   layer's state, not a parameter: no gradient reaches it, each training
   forward moves it towards an even load.
@@ -358,17 +359,26 @@ def rotary_inv_freq(rotary_dim, theta, yarn=None):
 class AttentionLayer(_StatefulSequenceLayer):
     """Grouped-query attention over positions 0 .. T-1: causal, inside
     `window` where it is set. `n_heads` may differ from layer to layer of a
-    model over the same `n_kv_heads`."""
+    model over the same `n_kv_heads`. With `rope_theta` None nothing is
+    turned (a model whose other layers carry the order: no `rotary` scope);
+    with `gate` false the output passes no gate (no `Wgate` leaf, no `gate`
+    scope)."""
     n_in: int = None
     n_out: int = None
     n_heads: int = 48
     n_kv_heads: int = 8
     head_dim: int = 128
     window: int = None          # None: every key up to the query
-    rope_theta: float = 1e4
+    rope_theta: float = 1e4     # None: no positional encoding
     rotary_dim: int = None      # the slots turned; None: all of a head's
     yarn: tuple = None          # see `rotary_inv_freq`
+    gate: bool = True           # the per-head sigmoid gate on the output
     init_std: float = 0.02
+
+    def to_dict(self):
+        d = super().to_dict()
+        d.setdefault("rope_theta", None)    # None is a value here, not "unset"
+        return d
 
     def init_state(self):
         return {"attend_grid_steps_per_tile": jnp.zeros((), jnp.float32),
@@ -389,9 +399,11 @@ class AttentionLayer(_StatefulSequenceLayer):
         D, H, KV, Dh = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
         k = jax.random.split(key, 5)
         mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
-        return {"Wq": mk(k[0], (D, H * Dh)), "Wk": mk(k[1], (D, KV * Dh)),
-                "Wv": mk(k[2], (D, KV * Dh)), "Wo": mk(k[3], (H * Dh, D)),
-                "Wgate": mk(k[4], (D, H))}
+        p = {"Wq": mk(k[0], (D, H * Dh)), "Wk": mk(k[1], (D, KV * Dh)),
+             "Wv": mk(k[2], (D, KV * Dh)), "Wo": mk(k[3], (H * Dh, D))}
+        if self.gate:
+            p["Wgate"] = mk(k[4], (D, H))
+        return p
 
     def turn(self, x, positions):
         """x [B, T, heads, Dh]: the first `rotary_dim` slots turned by
@@ -415,19 +427,21 @@ class AttentionLayer(_StatefulSequenceLayer):
         q = (x @ params["Wq"]).reshape(B, T, H, Dh)
         k = (x @ params["Wk"]).reshape(B, T, KV, Dh)
         v = (x @ params["Wv"]).reshape(B, T, KV, Dh)
-        with jax.named_scope("rotary"):
-            pos = jnp.broadcast_to(jnp.arange(T), (B, T))
-            q, k = self.turn(q, pos), self.turn(k, pos)
+        if self.rope_theta is not None:
+            with jax.named_scope("rotary"):
+                pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+                q, k = self.turn(q, pos), self.turn(k, pos)
         heads = lambda a: jnp.moveaxis(a, 1, 2)         # [B, heads, T, Dh]
         with jax.named_scope(
                 "attend_full" if self.window is None else "attend_window"):
             o, _ = masked_attention(heads(q), heads(k), heads(v), None,
                                     1.0 / math.sqrt(Dh), window=self.window)
         o = jnp.moveaxis(o, 1, 2)                       # [B, T, H, Dh]
-        with jax.named_scope("gate"):
-            g = jax.nn.sigmoid(jnp.dot(
-                x, params["Wgate"], preferred_element_type=jnp.float32))
-            o = (o * g[..., None]).astype(x.dtype)
+        if self.gate:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(jnp.dot(
+                    x, params["Wgate"], preferred_element_type=jnp.float32))
+                o = (o * g[..., None]).astype(x.dtype)
         # the kernels' schedule is a function of T: a constant of the trace
         return o.reshape(B, T, H * Dh) @ params["Wo"], {
             "attend_grid_steps_per_tile": jnp.float32(grid_steps_per_tile(
@@ -569,6 +583,10 @@ def gated_mlp(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def relu2_mlp(x, w_up, w_down):
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
 # ---------------------------------------------------------------------------
 # experts
 # ---------------------------------------------------------------------------
@@ -586,7 +604,10 @@ class MoELayer(_StatefulSequenceLayer):
     the top k are those of score + b while the weights stay the scores'; b
     [n_experts] is state, zero at first, and every training forward leaves
     b + gamma sign(mean(c) - c), c its own tokens' pairs by expert (a
-    deployment adds the other chips' counts before the sign)."""
+    deployment adds the other chips' counts before the sign). An expert,
+    and the shared one, is the gated (SiLU(x Wg) * (x Wu)) Wd; with
+    `activation` "relu2" it is relu(x Wu)^2 Wd, two matrices and no gate:
+    no `Wg`, `Sg` leaves at all."""
     n_in: int = None
     n_out: int = None
     n_experts: int = 128
@@ -626,15 +647,21 @@ class MoELayer(_StatefulSequenceLayer):
         D, F, G = self.n_in, self.expert_width, self._held()
         k = jax.random.split(key, 4)
         mk = lambda kk, shape: _normal(kk, shape, self.init_std, dtype)
+        gated = not self._two_matrix()
         p = {"Wr": mk(k[0], (D, self.n_experts)),
-             "Wg": mk(k[1], (G, D, F)), "Wu": mk(k[2], (G, D, F)),
-             "Wd": mk(k[3], (G, F, D))}
+             "Wu": mk(k[2], (G, D, F)), "Wd": mk(k[3], (G, F, D))}
+        if gated:
+            p["Wg"] = mk(k[1], (G, D, F))
         if self.shared_width:
             S = self.shared_width
             k = jax.random.split(jax.random.fold_in(key, 1), 3)
-            p.update(Sg=mk(k[0], (D, S)), Su=mk(k[1], (D, S)),
-                     Sd=mk(k[2], (S, D)))
+            p.update(Su=mk(k[1], (D, S)), Sd=mk(k[2], (S, D)))
+            if gated:
+                p["Sg"] = mk(k[0], (D, S))
         return p
+
+    def _two_matrix(self):
+        return self.activation == "relu2"
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
@@ -657,12 +684,14 @@ class MoELayer(_StatefulSequenceLayer):
                         - jnp.bincount(experts.reshape(-1),
                                        length=self.n_experts)))
         y, counts, n_run = held_experts_ffn(
-            tokens, experts, gates, params["Wg"], params["Wu"], params["Wd"],
-            self.first_held, self.n_experts)
+            tokens, experts, gates, params.get("Wg"), params["Wu"],
+            params["Wd"], self.first_held, self.n_experts)
         if self.shared_width:
             with jax.named_scope("shared"):
-                y = y + gated_mlp(tokens, params["Sg"], params["Su"],
-                                  params["Sd"])
+                y = y + (relu2_mlp(tokens, params["Su"], params["Sd"])
+                         if self._two_matrix() else
+                         gated_mlp(tokens, params["Sg"], params["Su"],
+                                   params["Sd"]))
         counts = counts.astype(jnp.float32)
         return y.astype(x.dtype).reshape(B, T, D), {
             "held_pairs": counts,

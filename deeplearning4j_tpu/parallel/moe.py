@@ -14,11 +14,11 @@ path is tested against, at every k. Used by `models/zoo/transformer.py`
 `make_moe_block_fn` (the pipeline/EP trainer of `examples/three_axis_mesh.py`
 and `__graft_entry__.dryrun_multichip`), never by the containers.
 
-**`held_experts_ffn` (gated SiLU experts without biases, top-k of all E,
-NO CAPACITY, NOTHING DROPPED)**: one chip's share of an expert-parallel
+**`held_experts_ffn` (gated SiLU or two-matrix relu^2 experts without
+biases, top-k of all E, NO CAPACITY, NOTHING DROPPED)**: one chip's share of an expert-parallel
 layer. It routes over all E experts, is told which contiguous range it
 holds, and computes every routed (token, choice) pair of a held expert:
-pairs sorted by expert, the three products as `lax.ragged_dot` over the
+pairs sorted by expert, the three (or two) products as `lax.ragged_dot` over the
 ragged groups, in row blocks of static size, as many of them as hold a pair:
 the loop's trip count is read from the routing on the device, forward and
 in its hand-written backward. It has no exchange and nothing that stands in
@@ -269,10 +269,12 @@ def route_all(router_w, x, k, norm_topk=True, scoring="softmax", bias=None):
     return experts, top_p
 
 
-def _block_out(lo, rows, k, order, starts, ends, x, gate_flat, w_gate, w_up,
-               w_down):
+def _block_out(lo, rows, k, order, starts, ends, x, gate_flat, weights):
     """The sorted pairs lo .. lo + rows - 1: (their gated results
-    [rows, D] float32, zero past the last held pair; each row's token)."""
+    [rows, D] float32, zero past the last held pair; each row's token).
+    `weights` (w_gate, w_up, w_down) is the gated SiLU expert, (w_up,
+    w_down) the two-matrix relu(x W_up)^2 W_down."""
+    *w_in, w_down = weights
     pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
     valid = (lo + jnp.arange(rows) < ends[-1])[:, None]
     tok = pair // k
@@ -281,22 +283,22 @@ def _block_out(lo, rows, k, order, starts, ends, x, gate_flat, w_gate, w_up,
     rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
         a, w, sizes, preferred_element_type=jnp.float32), 0.0)
     xs = jnp.where(valid, x[tok], 0)
-    h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(x.dtype)
+    h = (jax.nn.silu(rd(xs, w_in[0])) * rd(xs, w_in[1]) if len(w_in) == 2
+         else jnp.square(jax.nn.relu(rd(xs, w_in[0])))).astype(x.dtype)
     out = rd(h, w_down) * jnp.where(valid[:, 0], gate_flat[pair],
                                     0.0)[:, None]
     return out, tok
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _walk_blocks(rows, k, x, gate_flat, w_gate, w_up, w_down, order, starts,
-                 ends, n_run):
+def _walk_blocks(rows, k, x, gate_flat, weights, order, starts, ends, n_run):
     """The first `n_run` blocks of `rows` sorted pairs, summed by token. A
     loop whose trip count is read from the routing has no reverse-mode rule,
     hence the hand-written backward: it keeps the inputs, nothing a block,
     and walks the same blocks last to first, each computed again."""
     def body(i, y):
         out, tok = _block_out(i * rows, rows, k, order, starts, ends, x,
-                              gate_flat, w_gate, w_up, w_down)
+                              gate_flat, weights)
         return y.at[tok].add(out)
 
     with jax.named_scope("experts"):
@@ -322,7 +324,7 @@ def _walk_blocks_bwd(rows, k, args, y_bar):
     # the metrics that read it would gain what fell out of it
     with jax.named_scope("experts"):
         grads = jax.lax.fori_loop(0, n_run, body,
-                                  tuple(jnp.zeros_like(a) for a in diff))
+                                  jax.tree.map(jnp.zeros_like, tuple(diff)))
     return (*grads, None, None, None, None)
 
 
@@ -332,7 +334,9 @@ _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
                      n_experts=None, block_rows=None):
     """y[t] = sum over the routed pairs (t, e) with e held here of
-    gates[t, e] * W_down,e(SiLU(W_gate,e x[t]) * W_up,e x[t]).
+    gates[t, e] * W_down,e(SiLU(W_gate,e x[t]) * W_up,e x[t]) or, with
+    `w_gate` None (a two-matrix expert), of gates[t, e] * W_down,e
+    relu(W_up,e x[t])^2.
 
     x [N, D]; experts/gates [N, k] from `route_all`; w_gate/w_up [G, D, F],
     w_down [G, F, D] are experts first_held .. first_held + G - 1. EVERY
@@ -350,7 +354,7 @@ def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
     """
     N, D = x.shape
     k = experts.shape[1]
-    G = w_gate.shape[0]
+    G = w_up.shape[0]
     local = experts.reshape(-1) - first_held
     key = jnp.where((local >= 0) & (local < G), local, G)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
@@ -362,6 +366,7 @@ def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
     rows = min(int(block_rows), N * k)
     order = jnp.pad(order, (0, -(N * k) % rows))
     n_run = (ends[-1] + rows - 1) // rows
-    y = _walk_blocks(rows, k, x, gates.reshape(-1), w_gate, w_up, w_down,
-                     order, ends - counts, ends, n_run)
+    weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    y = _walk_blocks(rows, k, x, gates.reshape(-1), weights, order,
+                     ends - counts, ends, n_run)
     return y, counts, n_run
