@@ -83,15 +83,17 @@ class Mamba2Layer(_StatefulSequenceLayer):
 
     def init_state(self):
         return {k: jnp.zeros((), jnp.float32)
-                for k in ("dt_mean", "decay_min", "state_rms", "chunks")}
+                for k in ("dt_mean", "decay_min", "state_rms", "chunks",
+                          "ssd_kernel")}
 
     def gauges(self, state):
         return dict(state)
 
     def remat_keeps(self):
-        # the states entering each chunk, which `ssd_scan` names: with them
-        # kept a rematerialised segment does not run the recurrence across
-        # chunks a second time
+        # the states entering each chunk, which `ssd_scan`'s XLA form names:
+        # with them kept its backward does not run the recurrence across
+        # chunks a second time (the kernels name none: their rematerialised
+        # `ssd_fwd` makes the states beside y either way)
         from ....ops.ssd import KEEP
         return (KEEP,)
 
@@ -118,7 +120,7 @@ class Mamba2Layer(_StatefulSequenceLayer):
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
-        from ....ops.ssd import ssd_scan
+        from ....ops.ssd import inside_kernel, ssd_scan
         B, T, _ = x.shape
         H, P, G, N = (self.mamba_num_heads, self.mamba_head_dim,
                       self.n_groups, self.ssm_state_size)
@@ -140,14 +142,17 @@ class Mamba2Layer(_StatefulSequenceLayer):
                                  + params["dt_bias"].astype(f32))
             A = -jnp.exp(params["A_log"].astype(f32))
             y, last = ssd_scan(xs, dt, A, Bm.reshape(B, T, G, N),
-                               Cm.reshape(B, T, G, N), self.chunk_size)
-            y = (y.astype(f32) + params["D"].astype(f32)[:, None]
-                 * xs.astype(f32)).astype(x.dtype)
+                               Cm.reshape(B, T, G, N),
+                               params["D"].astype(f32), self.chunk_size)
             said = {"dt_mean": jnp.mean(dt),
                     "decay_min": jnp.exp(jnp.min(dt * A)),
                     "state_rms": jnp.sqrt(jnp.mean(last * last)),
                     # a function of T: a constant of the trace
-                    "chunks": jnp.float32(T // self.chunk_size)}
+                    "chunks": jnp.float32(T // self.chunk_size),
+                    # whether the scan ran as the Pallas kernels: shapes
+                    # decide, a constant of the trace
+                    "ssd_kernel": jnp.float32(inside_kernel(
+                        self.chunk_size, P, N, H // G))}
         with jax.named_scope("ssm_norm"):
             y = jax.checkpoint(
                 lambda y, z, w: gated_group_norm(y, z, w, G, self.eps))(

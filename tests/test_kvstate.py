@@ -307,7 +307,8 @@ class TestPreemption:
             b1 = srv.submit([1, 2, 3, 4], 28, klass="batch")  # 8 blocks
             _wait_tokens(srv, 2)
             b2 = srv.submit([4, 3, 2, 1], 28, klass="batch")  # blocked
-            time.sleep(0.02)
+            # straight behind it: b1 has 26 tokens left, which a fast host
+            # decodes within a pause, and then nothing needs preempting
             i1 = srv.submit([5, 6, 7], 5, klass="interactive")
             r1 = i1.result(60)      # would hang without the gate scan
             snap = srv.metrics.snapshot()

@@ -156,23 +156,61 @@ def recurrence(x, dt, A, Bm, Cm):
     return jnp.stack(ys), jnp.stack(lasts)
 
 
+def scan(x, dt, A, Bm, Cm, chunk, **kw):
+    """`ssd.ssd_scan` with no skip (D = 0)."""
+    return ssd.ssd_scan(x, dt, A, Bm, Cm, jnp.zeros(A.shape, jnp.float32),
+                        chunk, **kw)
+
+
+def scan_by(monkeypatch, inside):
+    """The scan's rule set to run `inside` ("kernel": the Pallas kernels,
+    interpreted here; "xla": the XLA products), whatever the shapes say."""
+    monkeypatch.setattr(ssd, "inside_kernel",
+                        lambda *shape: float(inside == "kernel"))
+
+
+def pallas_calls(fn, *args):
+    """{kernel name: its pallas_calls} anywhere in the jaxpr of fn(*args)."""
+    out = {}
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                name = e.params["name"]
+                out[name] = out.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("inside", ["kernel", "xla"])
 @pytest.mark.parametrize("chunk", [4, 8, 16])
-def test_the_chunked_scan_is_the_recurrence_at_every_chunk_size(chunk):
+def test_the_chunked_scan_is_the_recurrence_at_every_chunk_size(
+        chunk, inside, monkeypatch):
+    scan_by(monkeypatch, inside)
     args = scan_inputs()
     decay = np.exp(np.asarray(args[1]) * np.asarray(args[2]))
     assert (decay < 1e-3).any() and (decay > 0.999).any()
     with jax.default_matmul_precision("highest"):
         want_y, want_last = recurrence(*args)
-        y, last = ssd.ssd_scan(*args, chunk)
+        y, last = scan(*args, chunk)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(last, want_last, rtol=2e-4, atol=2e-5)
     assert last.dtype == jnp.float32 and y.dtype == args[0].dtype
 
 
-@pytest.mark.parametrize("block", [None, 2])
-def test_the_chunked_scans_gradients_are_the_recurrences(block):
-    """All chunks' insides at once, and two chunks a rematerialised step."""
-    args = scan_inputs(seed=1)
+@pytest.mark.parametrize("inside, block", [("xla", None), ("xla", 2),
+                                           ("kernel", None)])
+def test_the_chunked_scans_gradients_are_the_recurrences(inside, block,
+                                                         monkeypatch):
+    """The kernels; the XLA form with all chunks' insides at once, and two
+    chunks a rematerialised step; with the skip D x."""
+    scan_by(monkeypatch, inside)
+    args = scan_inputs(seed=1) + (
+        jax.random.uniform(jax.random.PRNGKey(7), (4,), jnp.float32, 0.5,
+                           1.5),)
     w = jax.random.normal(jax.random.PRNGKey(5), args[0].shape, jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(6), (2, 4, 8, 16), jnp.float32)
 
@@ -180,46 +218,110 @@ def test_the_chunked_scans_gradients_are_the_recurrences(block):
         def f(*a):
             y, last = fn(*a)
             return jnp.sum(w * y) + jnp.sum(v * last)
-        return jax.grad(f, range(5))
+        return jax.grad(f, range(6))
+
+    def skipped(x, dt, A, Bm, Cm, D):
+        y, last = recurrence(x, dt, A, Bm, Cm)
+        return y + D[:, None] * x, last
 
     with jax.default_matmul_precision("highest"):
-        want = scored(recurrence)(*args)
+        want = scored(skipped)(*args)
         got = scored(lambda *a: ssd.ssd_scan(*a, 8, block=block))(*args)
-    for name, a, b in zip(("x", "dt", "A", "B", "C"), got, want):
+    for name, a, b in zip(("x", "dt", "A", "B", "C", "D"), got, want):
         np.testing.assert_allclose(
             a, b, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(b))),
             err_msg=name)
         assert float(jnp.max(jnp.abs(b))) > 0, name
 
 
-def test_the_recurrence_across_chunks_keeps_only_its_named_states():
-    """Under a checkpoint that saves `ssd.KEEP` alone the backward has the
-    entering states and does not run the forward recurrence again: one
-    forward loop over chunks and one backward in the whole program."""
+@pytest.mark.parametrize("inside", ["kernel", "xla"])
+def test_the_recurrence_across_chunks_keeps_only_its_named_states(
+        inside, monkeypatch):
+    """Under a checkpoint that saves `ssd.KEEP` alone, scored by y**2, whose
+    backward needs y again: the XLA form keeps the entering states and
+    remakes y from them without running the forward recurrence again (one
+    forward loop over chunks and one backward in the whole program). The
+    kernels name nothing to keep: y comes again only from `ssd_fwd`, which
+    makes the states beside it, so the backward walks forward once more
+    (`ssd_fwd` twice, `ssd_bwd` once), under a score linear in y too."""
+    scan_by(monkeypatch, inside)
     args = scan_inputs()
-    f = jax.checkpoint(
-        lambda *a: jnp.sum(ssd.ssd_scan(*a, 8)[0] ** 2),
-        policy=jax.checkpoint_policies.save_only_these_names(ssd.KEEP))
-    text = jax.jit(jax.grad(f, range(5))).lower(*args).as_text()
-    assert len(re.findall(r"stablehlo\.while", text)) == 2
-    g = jax.grad(f, range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(ssd.ssd_scan(*a, 8)[0] ** 2),
-                    range(5))(*args)
-    for a, b in zip(g, want):
+    keep = jax.checkpoint_policies.save_only_these_names(ssd.KEEP)
+    score = lambda *a: jnp.sum(scan(*a, 8)[0] ** 2)
+    grad = jax.grad(jax.checkpoint(score, policy=keep), range(5))
+    if inside == "xla":
+        text = jax.jit(grad).lower(*args).as_text()
+        assert len(re.findall(r"stablehlo\.while", text)) == 2
+    else:
+        w = jax.random.normal(jax.random.PRNGKey(5), args[0].shape,
+                              jnp.float32)
+        linear = lambda *a: jnp.sum(w * scan(*a, 8)[0])
+        for fn in (score, linear):
+            assert pallas_calls(jax.grad(jax.checkpoint(fn, policy=keep),
+                                         range(5)), *args) == {
+                "ssd_fwd": 2, "ssd_bwd": 1}
+    want = jax.grad(score, range(5))(*args)
+    for a, b in zip(grad(*args), want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("inside", ["kernel", "xla"])
+def test_decays_under_1e_20_beside_decays_near_1(inside, monkeypatch):
+    """A near -16 with time steps from 1e-4 to 4 in every head: a position's
+    decay from under 1e-20 (exp(-64)) to over 0.998, so l, summed along a
+    chunk, reaches hundreds while two neighbours' difference is a
+    thousandth. l, the decays and the states in float32 keep the value
+    and every gradient at the recurrence's (a bfloat16 l, or a product of
+    the time steps rounded to bfloat16, would not)."""
+    scan_by(monkeypatch, inside)
+    x, dt, A, Bm, Cm = scan_inputs(seed=2)
+    dt = jnp.exp(jax.random.uniform(jax.random.PRNGKey(9), dt.shape,
+                                    jnp.float32, float(np.log(1e-4)),
+                                    float(np.log(4.0))))
+    A = -16.0 + jnp.arange(A.shape[0], dtype=jnp.float32) * 0.1
+    decay = np.exp(np.asarray(dt) * np.asarray(A))
+    assert (decay < 1e-20).any() and (decay > 0.998).any()
+    w = jax.random.normal(jax.random.PRNGKey(5), x.shape, jnp.float32)
+    score = lambda fn: lambda *a: jnp.sum(w * fn(*a)[0]) + jnp.sum(fn(*a)[1])
+    with jax.default_matmul_precision("highest"):
+        (want_y, want_last), want = (recurrence(x, dt, A, Bm, Cm),
+                                     jax.grad(score(recurrence), range(5))(
+                                         x, dt, A, Bm, Cm))
+        mine = lambda *a: scan(*a, 8)
+        (y, last), got = (mine(x, dt, A, Bm, Cm),
+                          jax.grad(score(mine), range(5))(x, dt, A, Bm, Cm))
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(last, want_last, rtol=2e-4, atol=2e-5)
+    for name, a, b in zip(("x", "dt", "A", "B", "C"), got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(b))),
+            err_msg=name)
+
+
+def test_the_rule_runs_the_kernels_at_the_cells_chunks():
+    """Shapes alone decide: the cell's chunks of 128 over heads of 64, 8 a
+    group, and a state of 128 run as the kernels (and so does a group a
+    head); chunks or states that do not tile the chip's lanes, heads that
+    do not tile its packed sublanes, or a grid step past the VMEM limit
+    run as XLA products."""
+    for shape in ((128, 64, 128, 8), (256, 128, 128, 4), (128, 64, 128, 1)):
+        assert ssd.inside_kernel(*shape) == 1.0, shape
+    for shape in ((8, 8, 16, 2), (64, 64, 128, 8), (128, 64, 64, 8),
+                  (128, 40, 128, 8), (128, 64, 128, 64)):
+        assert ssd.inside_kernel(*shape) == 0.0, shape
 
 
 @pytest.mark.parametrize("t, chunk", [(20, 8), (33, 16), (7, 8)])
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused(t, chunk):
     args = scan_inputs(t=t)
     with pytest.raises(ValueError, match=rf"T = {t}.*L = {chunk}"):
-        ssd.ssd_scan(*args, chunk)
+        scan(*args, chunk)
 
 
 def test_group_sizes_are_checked():
     x, dt, A, Bm, Cm = scan_inputs(heads=4, groups=2)
     with pytest.raises(ValueError, match="whole groups"):
-        ssd.ssd_scan(x[:, :, :3], dt[:, :, :3], A[:3], Bm, Cm, 8)
+        scan(x[:, :, :3], dt[:, :, :3], A[:3], Bm, Cm, 8)
 
 
 # ---------------------------------------------------------------- the layer
@@ -597,6 +699,7 @@ def test_fit_agrees_with_the_reference(followed, what):
         for name, said in zip(MAMBA, want["aux1"]["ssm"]):
             g = lambda leaf: got["gauges"][f"mamba2.{name}.{leaf}"]
             assert g("chunks") == T // 8
+            assert g("ssd_kernel") == 0.0       # chunks of 8: XLA's products
             for leaf in ("dt_mean", "decay_min", "state_rms"):
                 assert g(leaf) == pytest.approx(float(said[leaf]), rel=1e-4)
             assert 0 < g("decay_min") < 1 and g("state_rms") > 0
@@ -669,6 +772,52 @@ def test_the_scopes_are_on_the_lowered_step_forward_and_backward(lowered):
     assert not any("/rotary/" in p or "/gate/" in p for p in paths)
     kernels = {p for p in paths if p.endswith("/pallas_call")}
     assert kernels and all("/attend_full/" in p for p in kernels)
+
+
+@pytest.fixture(scope="module")
+def lowered_kernels():
+    """The same step with the scan's rule saying 1, as the cell's shapes
+    make it say: its kernels are interpreted here."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ssd, "inside_kernel", lambda *shape: 1.0)
+        net = ComputationGraph(conf_of()).init()
+        return net.lower_step(mds_of(batch_of(0))).as_text(debug_info=True)
+
+
+def test_the_kernels_carry_the_ssd_scope_forward_and_backward(
+        lowered_kernels):
+    """`train_ssd_device_ms` sums what runs under `ssd`: each layer's
+    `ssd_fwd` is there in the step's forward and again in its
+    rematerialised segment, its `ssd_bwd` in the transpose, and every
+    kernel of the step is the scan's or the attention's."""
+    kernels = {p for p in paths_of(lowered_kernels)
+               if p.endswith("/pallas_call")}
+    for v in MAMBA:
+        mine = {p for p in kernels if f"mamba2.{v}" in p}
+        fwd = {p for p in mine if p.endswith("/ssd/ssd_fwd/pallas_call")}
+        bwd = {p for p in mine if p.endswith("/ssd/ssd_bwd/pallas_call")}
+        assert len(fwd) == 2 and len(bwd) == 1 and fwd | bwd == mine, v
+        assert all("transpose(" in p for p in bwd), v
+        assert sum("/rematted_computation/" in p for p in fwd) == 1, v
+    assert all(re.search(r"/(ssd|attend_full)/", p) for p in kernels)
+
+
+def test_the_ssd_kernel_gauge_carries_the_rule(monkeypatch):
+    """The layer says whether its scan ran as the kernels: the rule's
+    answer for its own chunk, head, state and heads a group (chunks of 8,
+    heads of 8, a state of 16, 4 heads a group: 0.0, the XLA products; 1.0
+    where the rule says 1; the cell's geometry gives 1.0, as
+    `test_the_rule_runs_the_kernels_at_the_cells_chunks` shows)."""
+    layer = mamba_layer()
+    p = weights()["l0_mixer"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 64), jnp.float32)
+    said = lambda: float(layer.forward_with_state(
+        p, x, layer.init_state())[1]["ssd_kernel"])
+    assert said() == 0.0
+    asked = []
+    monkeypatch.setattr(ssd, "inside_kernel",
+                        lambda *shape: asked.append(shape) or 1.0)
+    assert said() == 1.0 and set(asked) == {(8, 8, 16, 4)}
 
 
 def test_nothing_of_size_t_by_t_is_made_outside_the_attention(monkeypatch):

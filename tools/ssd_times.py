@@ -8,19 +8,21 @@ JSON line a variant. The geometry is `nemotron-3-nano-30b-a3b.train-ssm16k`'s:
 bfloat16 operands.
 
     chiprun -- python tools/ssd_times.py
-    chiprun -- python tools/ssd_times.py --block 0,16
+    chiprun -- python tools/ssd_times.py --inside xla --block 0,16
 
-A variant is (block, keep): `block` chunks a `lax.map` step of the inside of
-the chunks (0: all at once); `keep` what a rematerialising caller saves for the
-backward: "states" (the states entering each chunk, `ssd.KEEP`: the
-backward runs no forward recurrence), "nothing" (everything again) or
-"all" (no rematerialisation: jax keeps every intermediate, the pairs'
-decays among them). `--compile-only` compiles every variant for a described
-v5e with no chip attached, prints its temporaries and times nothing. A time
-comes from a TPU or not at all.
+A variant is (inside, block, keep): `inside` which form runs, "kernel" (the
+Pallas kernels `ssd_fwd` and `ssd_bwd`, PR 39) or "xla" (the XLA products),
+whatever `ssd.inside_kernel` would say at these shapes; `block`, for "xla"
+only, chunks a `lax.map` step of the inside of the chunks (0: all at once);
+`keep` what a rematerialising caller saves for the backward: "states" (the
+states entering each chunk, `ssd.KEEP`: the backward runs no forward
+recurrence), "nothing" (everything again) or "all" (no rematerialisation:
+jax keeps every intermediate, the pairs' decays among them).
+`--compile-only` compiles every variant for a described v5e with no chip
+attached, prints its temporaries and times nothing. A time comes from a TPU
+or not at all.
 """
 import argparse
-import itertools
 import json
 import os
 import statistics
@@ -40,11 +42,17 @@ ROWS, T, H, P, G, N, L = 1, 16_384, 64, 64, 8, 128, 128
 REPS, SETS = 5, 5       # calls a timing, timings a median
 
 
-def scan_of(block, keep):
-    """(forward, value-and-gradients) of the scan under what a
-    rematerialising layer would keep."""
+def scan_of(inside, block, keep):
+    """(forward, value-and-gradients) of the scan in the form `inside` under
+    what a rematerialising layer would keep."""
     def run(x, dt, A, B, C):
-        return ssd.ssd_scan(x, dt, A, B, C, L, block=block or None)
+        rule, ssd.inside_kernel = ssd.inside_kernel, lambda *shape: float(
+            inside == "kernel")
+        try:
+            return ssd.ssd_scan(x, dt, A, B, C, jnp.zeros(H, jnp.float32),
+                                L, block=block or None)
+        finally:
+            ssd.inside_kernel = rule
 
     if keep != "all":
         policy = (jax.checkpoint_policies.save_only_these_names(ssd.KEEP)
@@ -94,12 +102,16 @@ def timed(fn, operands):
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--block", default="0,8,32")
+    p.add_argument("--inside", default="kernel,xla")
+    p.add_argument("--block", default="32")
     p.add_argument("--keep", default="states,nothing")
     p.add_argument("--compile-only", action="store_true")
     a = p.parse_args()
-    variants = list(itertools.product(
-        [int(b) for b in a.block.split(",")], a.keep.split(",")))
+    variants = [(inside, block, keep)
+                for inside in a.inside.split(",")
+                for block in ([int(b) for b in a.block.split(",")]
+                              if inside == "xla" else [0])
+                for keep in a.keep.split(",")]
     say = lambda **kw: print(json.dumps(kw), flush=True)
 
     if a.compile_only:
@@ -108,9 +120,10 @@ def main():
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         sh = shapes(SingleDeviceSharding(topo.devices[0]))
-        for block, keep in variants:
-            fwd, both = scan_of(block, keep)
-            say(block=block, keep=keep, compiled=True,
+        ssd._resolve_interpret = lambda interpret: False    # Mosaic, not CPU
+        for inside, block, keep in variants:
+            fwd, both = scan_of(inside, block, keep)
+            say(inside=inside, block=block, keep=keep, compiled=True,
                 fwd_temp_bytes=fwd.lower(*sh[:5]).compile()
                 .memory_analysis().temp_size_in_bytes,
                 temp_bytes=both.lower(*sh).compile()
@@ -122,9 +135,9 @@ def main():
               "refusing to measure", file=sys.stderr)
         return 4
     operands = arrays()
-    for block, keep in variants:
-        fwd, both = scan_of(block, keep)
-        say(block=block, keep=keep,
+    for inside, block, keep in variants:
+        fwd, both = scan_of(inside, block, keep)
+        say(inside=inside, block=block, keep=keep,
             forward=timed(fwd, operands[:5]), both=timed(both, operands),
             device=jax.devices()[0].device_kind)
     return 0
