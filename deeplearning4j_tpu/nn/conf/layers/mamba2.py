@@ -20,7 +20,10 @@ and N = `ssm_state_size`, x [B, T, n_in] the layer's (already normed) input:
 Inner scopes, which the benchmark's readers sum by: `ssm_proj` (W_in,
 W_out), `ssm_conv` (the convolution and its SiLU), `ssd` (time steps,
 decays, the scan, the D skip), `ssm_norm` (gate and grouped norm).
-Everything the layer traces is inside one of the four.
+Everything the layer traces is inside one of the four. The scan, the
+convolution and the gated norm run as Pallas kernels where their shapes
+tile the chip (`ops/ssd.py`, `ops/ssm_pointwise.py`); the convolution and
+the norm read their channels of W_in's product where they lie.
 
 Initialisers are Mamba-2's, not normal draws: `A_log` the log of a uniform
 draw in [1, 16], `dt_bias` the inverse softplus of a log-uniform draw in
@@ -38,25 +41,6 @@ import jax.numpy as jnp
 
 from .base import register_layer
 from .decoder import _StatefulSequenceLayer, _normal
-
-
-def causal_depthwise_conv(x, w, b):
-    """x [B, T, C]; w [C, K]; b [C]: y_t = b + sum_k w[:, k] x_{t-K+1+k},
-    zeros before position 0, summed in float32."""
-    K, T = w.shape[1], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    return b.astype(jnp.float32) + sum(
-        padded[:, k:k + T] * w[:, k] for k in range(K))
-
-
-def gated_group_norm(y, z, w, groups, eps):
-    """y * SiLU(z), then RMSNorm over each of `groups` equal runs of the
-    last axis, times w; float32 inside, y's dtype out."""
-    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    by = g.reshape(g.shape[:-1] + (groups, -1))
-    by = by * jax.lax.rsqrt(jnp.mean(by * by, -1, keepdims=True) + eps)
-    return (by.reshape(g.shape) * w.astype(jnp.float32)).astype(y.dtype)
 
 
 @register_layer("mamba2")
@@ -84,7 +68,7 @@ class Mamba2Layer(_StatefulSequenceLayer):
     def init_state(self):
         return {k: jnp.zeros((), jnp.float32)
                 for k in ("dt_mean", "decay_min", "state_rms", "chunks",
-                          "ssd_kernel")}
+                          "ssd_kernel", "pointwise_kernel")}
 
     def gauges(self, state):
         return dict(state)
@@ -120,6 +104,7 @@ class Mamba2Layer(_StatefulSequenceLayer):
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
+        from ....ops import ssm_pointwise as pw
         from ....ops.ssd import inside_kernel, ssd_scan
         B, T, _ = x.shape
         H, P, G, N = (self.mamba_num_heads, self.mamba_head_dim,
@@ -127,14 +112,14 @@ class Mamba2Layer(_StatefulSequenceLayer):
         inner, conv = self._widths()
         f32 = jnp.float32
         with jax.named_scope("ssm_proj"):
-            z, xbc, dt = jnp.split(x @ params["W_in"], [inner, inner + conv],
-                                   -1)
+            # [z ; xBC ; dt]: the convolution and the gated norm read their
+            # channels of it in place
+            zxd = x @ params["W_in"]
+            dt = zxd[..., inner + conv:]
         # the convolution and the gated norm keep their (bfloat16) inputs
         # for the backward and nothing of their float32 insides
         with jax.named_scope("ssm_conv"):
-            xbc = jax.checkpoint(lambda a, w, b: jax.nn.silu(
-                causal_depthwise_conv(a, w, b)).astype(a.dtype))(
-                    xbc, params["w_c"], params["b_c"])
+            xbc = pw.conv_silu(zxd, params["w_c"], params["b_c"], lo=inner)
         with jax.named_scope("ssd"):
             xs, Bm, Cm = jnp.split(xbc, [inner, inner + G * N], -1)
             xs = xs.reshape(B, T, H, P)
@@ -152,11 +137,17 @@ class Mamba2Layer(_StatefulSequenceLayer):
                     # whether the scan ran as the Pallas kernels: shapes
                     # decide, a constant of the trace
                     "ssd_kernel": jnp.float32(inside_kernel(
-                        self.chunk_size, P, N, H // G))}
+                        self.chunk_size, P, N, H // G)),
+                    # whether the convolution and the gated norm ran as
+                    # their Pallas kernels (the mean of the two rules: 0.5
+                    # where one did), a constant of the trace
+                    "pointwise_kernel": jnp.float32(
+                        (pw.conv_inside_kernel(conv, T, self.conv_kernel,
+                                               inner)
+                         + pw.norm_inside_kernel(inner, G, T)) / 2)}
         with jax.named_scope("ssm_norm"):
-            y = jax.checkpoint(
-                lambda y, z, w: gated_group_norm(y, z, w, G, self.eps))(
-                    y.reshape(B, T, inner), z, params["w_n"])
+            y = pw.gated_norm(y.reshape(B, T, inner), zxd, params["w_n"], G,
+                              self.eps)
         with jax.named_scope("ssm_proj"):
             out = y @ params["W_out"]
         return out, jax.lax.stop_gradient(said)

@@ -15,6 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from deeplearning4j_tpu.ops import sparse_attention as sa         # noqa: E402
+from deeplearning4j_tpu.ops import ssm_pointwise as sp            # noqa: E402
 from deeplearning4j_tpu.ops.sparse_attention import (             # noqa: E402
     index_scores_bwd)
 
@@ -105,3 +106,43 @@ def test_the_one_pass_backward_compiles_at_the_cells_shapes(
     assert "tpu_custom_call" in text and "sparse_attention_bwd" in text
     assert "sparse_attention_dq" not in text
     assert "sparse_attention_dkv" not in text
+
+
+# `train-ssm16k`'s convolution (4 taps over 6,144 channels read 4,096 into
+# the in-projection's 10,304) and gated norm (8 groups of 512 over 4,096
+# channels, the gate at 0 of the same projection), T = 16,384; the operands
+# made a position down a column, as the layer's arrays lie on the chip
+POINTWISE = {"conv": ("ssm_conv", lambda zxd, w, b: sp.conv_silu(
+                 zxd, w, b, lo=4096), ((6144, 4), (6144,)), 6144),
+             "norm": ("ssm_norm", lambda y, zxd, w: sp.gated_norm(
+                 y, zxd, w, 8, 1e-5), ((4096,),), 4096)}
+
+
+@pytest.mark.parametrize("op", sorted(POINTWISE))
+def test_the_pointwise_kernels_compile_at_the_cells_widths(
+        one_chip, as_on_the_chip, monkeypatch, op):
+    """Forward and backward fit `KERNEL_VMEM` at the module's own blocks,
+    each is one kernel, and no `[16384, C]` array is copied or transposed
+    around them."""
+    monkeypatch.setattr(sp, "_resolve_interpret", lambda interpret: False)
+    name, fn, small, C = POINTWISE[op]
+    T = 16384
+    shape = lambda dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                              sharding=one_chip)
+    across = lambda a: jnp.swapaxes(a, 1, 2)
+    big = [shape((1, 10304, T))] if op == "conv" else \
+        [shape((1, C, T)), shape((1, 10304, T))]
+
+    def both(*args):
+        *operands, ct = args
+        operands = [across(a) if a.ndim == 3 else a for a in operands]
+        out, vjp = jax.vjp(fn, *operands)
+        return across(out), [across(g) if g.ndim == 3 else g
+                             for g in vjp(across(ct))]
+
+    text = jax.jit(both).lower(*big, *(shape(d) for d in small),
+                               shape((1, C, T))).compile().as_text()
+    assert f"{name}_fwd" in text and f"{name}_bwd" in text
+    for line in text.splitlines():
+        if " copy(" in line or " transpose(" in line:
+            assert f"{T},{C}]" not in line and f"{C},{T}]" not in line, line

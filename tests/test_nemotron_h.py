@@ -31,7 +31,7 @@ from deeplearning4j_tpu.models.zoo import nemotron_h_conf         # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers import decoder, mamba2     # noqa: E402
 from deeplearning4j_tpu.nn.graph import ComputationGraph          # noqa: E402
 from deeplearning4j_tpu.ops import sparse_attention as sa         # noqa: E402
-from deeplearning4j_tpu.ops import ssd                            # noqa: E402
+from deeplearning4j_tpu.ops import ssd, ssm_pointwise             # noqa: E402
 from deeplearning4j_tpu.parallel.moe import held_experts_ffn, route_all  # noqa: E402
 
 # the published layers 0-8 (4 state-space, 4 expert, 1 attention) at hidden
@@ -416,7 +416,7 @@ def test_the_convolution_is_depthwise_causal_and_four_taps_wide():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 10, 3), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(2), (3, 4), jnp.float32)
     b = jnp.asarray([0.5, -1.0, 2.0])
-    got = np.asarray(mamba2.causal_depthwise_conv(x, w, b))[0]
+    got = np.asarray(ssm_pointwise.causal_depthwise_conv(x, w, b))[0]
     xn, wn = np.asarray(x)[0], np.asarray(w)
     for t in range(10):
         want = np.asarray(b).copy()
@@ -432,7 +432,7 @@ def test_the_gate_comes_before_the_norm_and_the_norm_is_by_group():
     y = jax.random.normal(jax.random.PRNGKey(1), (1, 5, 64), jnp.float32)
     z = jax.random.normal(jax.random.PRNGKey(2), (1, 5, 64), jnp.float32)
     w = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(3), (64,))
-    got = np.asarray(mamba2.gated_group_norm(y, z, w, 2, 1e-5))
+    got = np.asarray(ssm_pointwise.gated_group_norm(y, z, w, 2, 1e-5))
     g = np.asarray(y * jax.nn.silu(z))
     for lo in (0, 32):
         run = g[..., lo:lo + 32]
@@ -700,6 +700,7 @@ def test_fit_agrees_with_the_reference(followed, what):
             g = lambda leaf: got["gauges"][f"mamba2.{name}.{leaf}"]
             assert g("chunks") == T // 8
             assert g("ssd_kernel") == 0.0       # chunks of 8: XLA's products
+            assert g("pointwise_kernel") == 0.0     # T = 64: the XLA forms
             for leaf in ("dt_mean", "decay_min", "state_rms"):
                 assert g(leaf) == pytest.approx(float(said[leaf]), rel=1e-4)
             assert 0 < g("decay_min") < 1 and g("state_rms") > 0
@@ -800,6 +801,30 @@ def test_the_kernels_carry_the_ssd_scope_forward_and_backward(
         assert all("transpose(" in p for p in bwd), v
         assert sum("/rematted_computation/" in p for p in fwd) == 1, v
     assert all(re.search(r"/(ssd|attend_full)/", p) for p in kernels)
+
+
+@pytest.mark.parametrize("scope", ["ssm_conv", "ssm_norm"])
+def test_the_pointwise_kernels_carry_their_scopes_forward_and_backward(
+        scope):
+    """At widths that tile (heads of 16 and a state of 32: a convolution of
+    256 channels read 128 into the in-projection, groups of 64; T = 128)
+    `train_ssm_pointwise_device_ms` sums what runs under `ssm_conv` and
+    `ssm_norm`: each layer's forward kernel is there in the step's forward
+    and again in its rematerialised segment, its backward kernel in the
+    transpose, and nothing else of either scope is a kernel."""
+    net = ComputationGraph(conf_of(mamba_head_dim=16,
+                                   ssm_state_size=32)).init()
+    text = net.lower_step(mds_of(batch_of(0, t=128))).as_text(
+        debug_info=True)
+    kernels = {p for p in paths_of(text) if p.endswith("/pallas_call")}
+    for v in MAMBA:
+        mine = {p for p in kernels
+                if f"mamba2.{v}" in p and f"/{scope}/" in p}
+        fwd = {p for p in mine if p.endswith(f"/{scope}_fwd/pallas_call")}
+        bwd = {p for p in mine if p.endswith(f"/{scope}_bwd/pallas_call")}
+        assert len(fwd) == 2 and len(bwd) == 1 and fwd | bwd == mine, v
+        assert all("transpose(" in p for p in bwd), v
+        assert sum("/rematted_computation/" in p for p in fwd) == 1, v
 
 
 def test_the_ssd_kernel_gauge_carries_the_rule(monkeypatch):
