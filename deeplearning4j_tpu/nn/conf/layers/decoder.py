@@ -628,7 +628,8 @@ class MoELayer(_StatefulSequenceLayer):
     def init_state(self):
         state = {"held_pairs": jnp.zeros((self._held(),), jnp.float32),
                  "absent_pairs": jnp.zeros((), jnp.float32),
-                 "blocks_run": jnp.zeros((), jnp.float32)}
+                 "blocks_run": jnp.zeros((), jnp.float32),
+                 "experts_kernel": jnp.zeros((), jnp.float32)}
         if self.bias_update_rate is not None:
             state["bias"] = jnp.zeros((self.n_experts,), jnp.float32)
         return state
@@ -638,7 +639,8 @@ class MoELayer(_StatefulSequenceLayer):
         out = {"held_pairs_max": jnp.max(held),
                "held_pairs_mean": jnp.mean(held),
                "absent_pairs": state["absent_pairs"],
-               "blocks_run": state["blocks_run"]}
+               "blocks_run": state["blocks_run"],
+               "experts_kernel": state["experts_kernel"]}
         if "bias" in state:
             out["bias_abs_max"] = jnp.max(jnp.abs(state["bias"]))
         return out
@@ -665,7 +667,8 @@ class MoELayer(_StatefulSequenceLayer):
 
     def forward_with_state(self, params, x, state, *, train=False, rng=None,
                            mask=None):
-        from ....parallel.moe import held_experts_ffn, route_all
+        from ....parallel.moe import (experts_kernel, held_experts_ffn,
+                                      route_all)
         B, T, D = x.shape
         tokens = x.reshape(B * T, D)
         biased = self.bias_update_rate is not None
@@ -696,7 +699,12 @@ class MoELayer(_StatefulSequenceLayer):
         return y.astype(x.dtype).reshape(B, T, D), {
             "held_pairs": counts,
             "absent_pairs": B * T * self.experts_per_token - jnp.sum(counts),
-            "blocks_run": n_run.astype(jnp.float32), **more}
+            "blocks_run": n_run.astype(jnp.float32),
+            # whether the walk's products ran as the grouped kernels: shapes
+            # decide, a constant of the trace
+            "experts_kernel": jnp.float32(experts_kernel(
+                B * T, self.experts_per_token, D, self.expert_width,
+                self._held(), self.n_experts)), **more}
 
 
 # ---------------------------------------------------------------------------

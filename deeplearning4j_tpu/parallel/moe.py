@@ -18,8 +18,10 @@ and `__graft_entry__.dryrun_multichip`), never by the containers.
 biases, top-k of all E, NO CAPACITY, NOTHING DROPPED)**: one chip's share of an expert-parallel
 layer. It routes over all E experts, is told which contiguous range it
 holds, and computes every routed (token, choice) pair of a held expert:
-pairs sorted by expert, the three (or two) products as `lax.ragged_dot` over the
-ragged groups, in row blocks of static size, as many of them as hold a pair:
+pairs sorted by expert, the three (or two) products over the ragged groups
+as the grouped kernels of `ops/grouped_matmul.py` where the shapes tile the
+chip, else as `lax.ragged_dot`, in row blocks of static size, as many of
+them as hold a pair:
 the loop's trip count is read from the routing on the device, forward and
 in its hand-written backward. It has no exchange and nothing that stands in
 for the absent chips: what their experts would add is left out. Used by the
@@ -49,6 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..ops import grouped_matmul as gm
 
 
 def make_expert_mesh(n_expert, devices=None):
@@ -279,9 +283,14 @@ def _block_out(lo, rows, k, order, starts, ends, x, gate_flat, weights):
     valid = (lo + jnp.arange(rows) < ends[-1])[:, None]
     tok = pair // k
     sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-    # rows past the last group are not the kernel's to define
-    rd = lambda a, w: jnp.where(valid, jax.lax.ragged_dot(
-        a, w, sizes, preferred_element_type=jnp.float32), 0.0)
+    G, F, D = w_down.shape
+    if gm.inside_kernel(rows, D, F, G):
+        product = lambda a, w: gm.grouped_matmul(a, w, sizes)
+    else:
+        product = lambda a, w: jax.lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32)
+    # rows past the last group are not `ragged_dot`'s to define
+    rd = lambda a, w: jnp.where(valid, product(a, w), 0.0)
     xs = jnp.where(valid, x[tok], 0)
     h = (jax.nn.silu(rd(xs, w_in[0])) * rd(xs, w_in[1]) if len(w_in) == 2
          else jnp.square(jax.nn.relu(rd(xs, w_in[0])))).astype(x.dtype)
@@ -331,6 +340,25 @@ def _walk_blocks_bwd(rows, k, args, y_bar):
 _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 
 
+def walk_rows(N, k, G, n_experts=None, block_rows=None):
+    """Rows a block of `held_experts_ffn`'s walk over the N k pairs of G
+    held experts: `block_rows`, by default 1.25 times the held experts'
+    expected share of the pairs rounded up to 512, at most N k."""
+    if block_rows is None:
+        share = N * k * G / (n_experts or G)
+        block_rows = -(-int(1.25 * share) // 512) * 512
+    return min(int(block_rows), N * k)
+
+
+def experts_kernel(N, k, D, F, G, n_experts=None, block_rows=None):
+    """1.0 where `held_experts_ffn` over N tokens of width D, k experts a
+    token, G held experts of width F runs its products as the grouped
+    kernels (`ops/grouped_matmul.py inside_kernel` at its block's rows),
+    0.0 where as `lax.ragged_dot`. Shapes alone decide."""
+    return gm.inside_kernel(walk_rows(N, k, G, n_experts, block_rows), D, F,
+                            G)
+
+
 def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
                      n_experts=None, block_rows=None):
     """y[t] = sum over the routed pairs (t, e) with e held here of
@@ -360,10 +388,7 @@ def held_experts_ffn(x, experts, gates, w_gate, w_up, w_down, first_held,
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     counts = jnp.bincount(key, length=G + 1)[:G].astype(jnp.int32)
     ends = jnp.cumsum(counts)
-    if block_rows is None:
-        share = N * k * G / (n_experts or G)
-        block_rows = -(-int(1.25 * share) // 512) * 512
-    rows = min(int(block_rows), N * k)
+    rows = walk_rows(N, k, G, n_experts, block_rows)
     order = jnp.pad(order, (0, -(N * k) % rows))
     n_run = (ends[-1] + rows - 1) // rows
     weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)

@@ -14,6 +14,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from deeplearning4j_tpu.nn.conf.layers import decoder            # noqa: E402
+from deeplearning4j_tpu.ops import grouped_matmul as gm          # noqa: E402
 from deeplearning4j_tpu.ops import sparse_attention as sa         # noqa: E402
 from deeplearning4j_tpu.ops import ssm_pointwise as sp            # noqa: E402
 from deeplearning4j_tpu.ops.sparse_attention import (             # noqa: E402
@@ -146,3 +148,73 @@ def test_the_pointwise_kernels_compile_at_the_cells_widths(
     for line in text.splitlines():
         if " copy(" in line or " transpose(" in line:
             assert f"{T},{C}]" not in line and f"{C},{T}]" not in line, line
+
+
+# the held experts' walk of each decoder cell: (block rows, width, expert
+# width, held experts); a block's products x [rows, D] through [G, D, F]
+# and back through [G, F, D]
+EXPERTS = {"train-ssm16k": (7680, 2688, 1856, 8),
+           "train-vl8k": (20480, 2048, 768, 16),
+           "train-lc16k": (20480, 2048, 512, 32),
+           "train-mtp8k": (10240, 2048, 768, 16)}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERTS))
+def test_the_grouped_kernels_compile_at_the_cells_widths(
+        one_chip, as_on_the_chip, monkeypatch, cell):
+    """Both products of a block, forward and backward, at the module's own
+    blocks: every step fits `KERNEL_VMEM`, and no `ragged_dot` is left."""
+    monkeypatch.setattr(gm, "_resolve_interpret", lambda interpret: False)
+    rows, D, F, G = EXPERTS[cell]
+    assert gm.inside_kernel(rows, D, F, G) == 1.0
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+
+    def both(x, w_up, w_down, sizes, ct):
+        def block(x, w_up, w_down):
+            h = jnp.square(gm.grouped_matmul(x, w_up, sizes))
+            return gm.grouped_matmul(h.astype(x.dtype), w_down, sizes)
+        out, vjp = jax.vjp(block, x, w_up, w_down)
+        return out, vjp(ct)
+
+    text = jax.jit(both).lower(
+        shape((rows, D)), shape((G, D, F)), shape((G, F, D)),
+        shape((G,), jnp.int32), shape((rows, D), jnp.float32)
+    ).compile().as_text()
+    assert "grouped_matmul" in text and "grouped_matmul_dw" in text
+    assert "ragged" not in text
+
+
+def test_a_step_builds_each_grouped_kernel_once(one_chip, as_on_the_chip,
+                                                monkeypatch):
+    """Two `moe` layers of the same widths, each rematerialised, value and
+    gradients lowered for the chip: the text holds the six distinct
+    kernels of one layer (two products forward, their dx and dW), not one
+    a call site, however many layers call them."""
+    monkeypatch.setattr(gm, "_resolve_interpret", lambda interpret: False)
+    layer = decoder.MoELayer(n_in=128, n_out=128, n_experts=8,
+                             experts_per_token=2, expert_width=256,
+                             experts_held=4)
+    shape = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), tree)
+
+    def kernels(n):
+        def loss(ps, x):
+            for p in ps:
+                def one(p, x):
+                    return x + layer.forward_with_state(
+                        p, x, layer.init_state(), train=True)[0]
+                x = jax.checkpoint(one)(p, x)
+            return jnp.sum(x.astype(jnp.float32) ** 2)
+        ps = [jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                           layer.init_params(jax.random.PRNGKey(i)))
+              for i in range(n)]
+        text = jax.jit(jax.value_and_grad(loss)).lower(
+            shape(ps), shape(jnp.zeros((1, 512, 128), jnp.bfloat16))
+        ).as_text()
+        return text.count("tpu_custom_call")
+
+    # 512 tokens, 2 experts each, 4 of 8 held: blocks of 1,024 pairs
+    assert gm.inside_kernel(1024, 128, 256, 4) == 1.0
+    assert kernels(1) == 6
+    assert kernels(2) == kernels(1)

@@ -151,10 +151,12 @@ def _small_resnet50():
 # PR 35 did for "tiny-keye", whose index scores got a backward of their own
 # (`decoder.index_scores`), and PR 37, whose attention backward became one
 # kernel (`sparse_attention_bwd`) and whose layers' state gained
-# `attend_backward_passes`; the two CNNs' are the parent's still.
+# `attend_backward_passes`, and again when the `moe` layers' state gained
+# `experts_kernel` (a constant: the presets' widths keep `ragged_dot`); the
+# two CNNs' are the parent's still.
 REMAT_STEP_SHA256 = {
     "tiny-keye":
-        "a3a2588f051145ec25f9e3e2bf55d85cd9a91aa6aadebdfe674179e8a668f2eb",
+        "b06327ab93d676d4d418376b909809e2da2f43e2e05471b445c4931f0b15d2f4",
     "residual-cnn":
         "9a7f306c2963ca5d54481136528d1580c1fdf06d524de743784f6a466d7a2cb1",
     "resnet50":
