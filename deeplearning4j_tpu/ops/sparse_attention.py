@@ -32,7 +32,12 @@ chosen by the block index map, nothing is repeated in HBM.
   a third slab that stays for all of the backward's run, or inside one run
   of the two-pass dK/dV kernel.
 - `head_summed_probs(q, k, lse, mask)` -> [B, T, T]: the probabilities of
-  all heads added up, pair by pair: the target of the indexer's loss.
+  all heads added up, pair by pair: the target of the indexer's loss. A
+  512 x 512 tile is walked in parts of 128 queries x 256 keys whose sum stays
+  in registers while the heads pass, and the mask is applied once, to the
+  sum; each head's lse is spread across the lanes once a query block. Its
+  pallas_call sits under a module-level `jax.jit`, so a step traces and
+  lowers it once, not once a layer and again in each rematerialisation.
 - `at_least_kth(scores, k)` -> int8 [R, S]: which entries of each row are at
   least the row's k-th largest (the top-k selection with ties kept), by a
   binary search over the floats' bits that reads the row once from HBM;
@@ -99,6 +104,18 @@ SLAB_BUDGET = 16 << 20      # the one-pass backward's float32 slabs of dK, dV
 #                  (and a shared key's dK2), T x (d + dv + d2) x 4 bytes: up
 #                  to here one kernel, past it the two (`backward_passes`);
 #                  T = 16384 at d = dv = 128 is the budget exactly
+HEAD_SUM_BLOCK = (512, 512)  # (query, key) block of `head_summed_probs`: the
+#                  fastest of 128 x 512 .. 512 x 1,024 on the chip at
+#                  T = 8192, 32 / 4 heads of 128 (PERF.md section 6)
+HEAD_SUM_SUB = (128, 256)    # (rows, keys) of the part of its tile whose sum
+#                  over the heads stays in registers: a head's scores and
+#                  the sum, 32 vregs each; the fastest of 32 x 512 ..
+#                  128 x 256 in 256 x 512 blocks on the chip
+HEAD_SUM_VMEM = 48 << 20     # its VMEM: q's block and lse's (a column padded
+#                  to the 128 lanes) two buffers each, lse spread across the
+#                  lanes, the output's, k's and the mask's blocks: 35.5 MB
+#                  at 512 x 512, 39 at 512 x 1,024, over the scoped default
+#                  of 16, under the chip's 128
 BWD_VMEM = 64 << 20         # the one-pass backward's VMEM: at the budget the
 #                  slabs' 16 MB, their bf16 output blocks' two buffers 16
 #                  and the 1024 x 1024 tiles need 48 MB (compiled for a
@@ -765,9 +782,19 @@ def _shared_bwd(scale, block_q, block_k, interpret, res, g):
 shared_key_attention.defvjp(_shared_fwd, _shared_bwd)
 
 
-def _probs_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, *, scale, bq, bk,
-                  heads, rep):
+def _probs_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, lse_lanes, *,
+                  scale, bq, bk, heads, rep, rows, keys):
+    """A (query block, key block) tile in parts of `rows` x `keys` whose sum
+    stays in registers while the heads pass, in order, so the float32 sum
+    rounds as a walk head by head does. The mask is applied once, to the
+    sum: an unkept pair adds its exp unmasked (a score that overflows adds
+    inf, never NaN) and is written 0, as the walk that masked each head's
+    score with NEG wrote exp(NEG - lse) = 0 for it."""
     i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)            # a query block's first step: every head's
+    def _spread():              # lse across the lanes, once
+        lse_lanes[...] = jnp.broadcast_to(lse_ref[0], lse_lanes.shape)
 
     @pl.when(j * bk > i * bq + bq - 1)
     def _skip():
@@ -775,32 +802,43 @@ def _probs_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, *, scale, bq, bk,
 
     @pl.when(j * bk <= i * bq + bq - 1)
     def _step():
-        keep = mask_ref[0].astype(jnp.int32) != 0
+        lanes = min(keys, 128)
+        for r in range(0, bq, rows):
+            for c in range(0, bk, keys):
+                acc = jnp.zeros((rows, keys), jnp.float32)
+                for h in range(heads):
+                    s = jax.lax.dot_general(
+                        q_ref[0, h, r:r + rows],
+                        k_ref[0, h // rep, c:c + keys],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    lse = lse_lanes[h, r:r + rows, :lanes]
+                    acc = acc + jnp.exp(
+                        s * scale - jnp.tile(lse, (1, keys // lanes)))
+                keep = mask_ref[0, r:r + rows, c:c + keys].astype(
+                    jnp.int32) != 0
+                out_ref[0, r:r + rows, c:c + keys] = jnp.where(keep, acc, 0.0)
 
-        def head(h, acc):
-            s = jax.lax.dot_general(
-                q_ref[0, h], k_ref[0, h // rep], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            return acc + jnp.exp(jnp.where(keep, s, NEG) - lse_ref[0, h])
 
-        out_ref[0] = jax.lax.fori_loop(
-            0, heads, head, jnp.zeros((bq, bk), jnp.float32))
+def _sub_tile(bq, bk):
+    """(rows, keys) of `_probs_kernel`'s part of a tile: `HEAD_SUM_SUB`
+    where the block takes it, else no more than a block, and keys no more
+    than the 128 lanes."""
+    rows, keys = HEAD_SUM_SUB
+    return (_divisor_block(bq, rows),
+            keys if bk % keys == 0 else _divisor_block(bk, 128))
 
 
-def head_summed_probs(q, k, lse, mask, scale, block_q=256, block_k=512,
-                      interpret=None):
-    """sum over heads a of exp(q_a k_{a // rep}^T * scale - lse_a) on the
-    pairs `mask` keeps, 0 elsewhere: [B, T, T] float32. Shapes as
-    `masked_attention`; not differentiated (the indexer's target)."""
+@functools.partial(jax.jit, static_argnames=("scale", "bq", "bk", "rows",
+                                             "keys", "interpret"))
+def _head_sum(q, k, lse, mask, *, scale, bq, bk, rows, keys, interpret):
     B, H, T, d = q.shape
     KV = k.shape[1]
-    bq, bk = _blocks(T, block_q, block_k)
-    interpret = _resolve_interpret(interpret)
     vm = {"memory_space": pltpu.VMEM}
     seen = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
     return pl.pallas_call(
         functools.partial(_probs_kernel, scale=scale, bq=bq, bk=bk, heads=H,
-                          rep=H // KV),
+                          rep=H // KV, rows=rows, keys=keys),
         grid=(B, T // bq, T // bk),
         in_specs=[
             pl.BlockSpec((1, H, bq, d), lambda b, i, j: (b, 0, i, 0), **vm),
@@ -810,10 +848,25 @@ def head_summed_probs(q, k, lse, mask, scale, block_q=256, block_k=512,
                          **vm),
             pl.BlockSpec((1, H, bq, 1), lambda b, i, j: (b, 0, i, 0), **vm)],
         out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j), **vm),
+        scratch_shapes=[pltpu.VMEM((H, bq, 128), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, T, T), jnp.float32),
         interpret=interpret, name="sparse_attention_head_sum",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
-            q, k, mask, lse[..., None])
+        **_params(interpret, ("parallel", "parallel", "arbitrary"),
+                  vmem_limit_bytes=HEAD_SUM_VMEM))(q, k, mask, lse[..., None])
+
+
+def head_summed_probs(q, k, lse, mask, scale, block_q=None, block_k=None,
+                      interpret=None):
+    """sum over heads a of exp(q_a k_{a // rep}^T * scale - lse_a) on the
+    pairs `mask` keeps, 0 elsewhere: [B, T, T] float32. Shapes as
+    `masked_attention`; not differentiated (the indexer's target). Blocks
+    `HEAD_SUM_BLOCK` unless asked, each side cut to a divisor of T."""
+    T = q.shape[2]
+    bq = _divisor_block(T, block_q or HEAD_SUM_BLOCK[0])
+    bk = _divisor_block(T, block_k or HEAD_SUM_BLOCK[1])
+    rows, keys = _sub_tile(bq, bk)
+    return _head_sum(q, k, lse, mask, scale=scale, bq=bq, bk=bk, rows=rows,
+                     keys=keys, interpret=_resolve_interpret(interpret))
 
 
 def _kth_kernel(x_ref, out_ref, *, k):

@@ -110,6 +110,57 @@ def test_the_one_pass_backward_compiles_at_the_cells_shapes(
     assert "sparse_attention_dkv" not in text
 
 
+# `train-vl8k`'s indexer target: one row, 32 query heads over 4 key/value
+# heads of 128, T = 8,192, at the module's blocks and at the largest that
+# `tools/attend_kernel_times.py` is asked to time
+@pytest.mark.parametrize("block", [(None, None), (512, 1024)])
+def test_the_head_sum_kernel_compiles_at_the_cells_shapes(
+        one_chip, as_on_the_chip, block):
+    """Its blocks, lse spread across the lanes and the unrolled parts of a
+    tile fit `HEAD_SUM_VMEM`, and the call is one kernel."""
+    T, H, KV, d = 8192, 32, 4, 128
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    text = jax.jit(lambda q, k, lse, mask: sa.head_summed_probs(
+        q, k, lse, mask, d ** -0.5, *block, interpret=False)).lower(
+            shape((1, H, T, d)), shape((1, KV, T, d)),
+            shape((1, H, T), jnp.float32), shape((1, T, T), jnp.int8)
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "sparse_attention_head_sum" in text
+
+
+def test_a_step_lowers_the_head_sum_kernel_once(one_chip, as_on_the_chip,
+                                                monkeypatch):
+    """Calls of the same shapes, each in a segment of its own that the
+    backward rematerialises, as `_row` calls it a layer: the lowered text
+    holds the kernel once, not once a call, however many calls there are."""
+    monkeypatch.setattr(sa, "_resolve_interpret", lambda interpret: False)
+    T, H, KV, d = 1024, 32, 4, 128
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+
+    def kernels(n):
+        def loss(q, k, lse, mask):
+            out = jnp.zeros((), jnp.float32)
+            for i in range(n):
+                def one(q):
+                    sg = jax.lax.stop_gradient
+                    target = sa.head_summed_probs(sg(q), k, lse, mask,
+                                                  d ** -0.5)
+                    return jnp.sum(target * q[0, 0, :, :1].astype(
+                        jnp.float32))
+                out = out + jax.checkpoint(one)(q * (i + 1))
+            return out
+        return jax.jit(jax.grad(loss)).lower(
+            shape((1, H, T, d)), shape((1, KV, T, d)),
+            shape((1, H, T), jnp.float32), shape((1, T, T), jnp.int8)
+        ).as_text().count("tpu_custom_call")
+
+    assert kernels(1) == 1
+    assert kernels(3) == 1
+
+
 # `train-ssm16k`'s convolution (4 taps over 6,144 channels read 4,096 into
 # the in-projection's 10,304) and gated norm (8 groups of 512 over 4,096
 # channels, the gate at 0 of the same projection), T = 16,384; the operands

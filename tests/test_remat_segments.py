@@ -152,11 +152,13 @@ def _small_resnet50():
 # (`decoder.index_scores`), and PR 37, whose attention backward became one
 # kernel (`sparse_attention_bwd`) and whose layers' state gained
 # `attend_backward_passes`, and again when the `moe` layers' state gained
-# `experts_kernel` (a constant: the presets' widths keep `ragged_dot`); the
+# `experts_kernel` (a constant: the presets' widths keep `ragged_dot`), and
+# again when the indexer's target kernel (`head_summed_probs`) got a body
+# that masks the sum over the heads once and a `jax.jit` of its own; the
 # two CNNs' are the parent's still.
 REMAT_STEP_SHA256 = {
     "tiny-keye":
-        "b06327ab93d676d4d418376b909809e2da2f43e2e05471b445c4931f0b15d2f4",
+        "236333a5ff8c95d8c58e25a990bed223ed89f0dd9db87e81e7b1586789f35833",
     "residual-cnn":
         "9a7f306c2963ca5d54481136528d1580c1fdf06d524de743784f6a466d7a2cb1",
     "resnet50":

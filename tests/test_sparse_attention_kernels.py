@@ -5,7 +5,11 @@ against one tile, and the tile schedule the kernels build their grid from;
 the index scores' hand-written backward (`index_scores_bwd`, PR 35) against
 `jax.vjp` of the expression it replaced, alone and through the layer.
 The one-pass backward (PR 37) against the two kernels it took the place of,
-bit for bit, and against dense attention. Interpreted on the CPU."""
+bit for bit, and against dense attention. The indexer's target
+(`head_summed_probs`) against dense softmaxes added over the heads, against
+the per-head walk it replaced, bit for bit at equal blocks, and off the
+selection. Interpreted on the CPU."""
+import functools
 import os
 import re
 import sys
@@ -14,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -334,6 +339,156 @@ def test_gauge_reads_one_grid_step_a_tile():
             for i in (0, 1)] == [1.0, 1.0]
 
 
+# ------------------------------------------------ the indexer's target
+def _per_head_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, *, scale, bq,
+                     bk, heads, rep):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j * bk > i * bq + bq - 1)
+    def _skip():
+        out_ref[0] = jnp.zeros_like(out_ref[0])
+
+    @pl.when(j * bk <= i * bq + bq - 1)
+    def _step():
+        keep = mask_ref[0].astype(jnp.int32) != 0
+
+        def head(h, acc):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h // rep], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            return acc + jnp.exp(jnp.where(keep, s, sa.NEG) - lse_ref[0, h])
+
+        out_ref[0] = jax.lax.fori_loop(
+            0, heads, head, jnp.zeros((bq, bk), jnp.float32))
+
+
+def per_head_probs(q, k, lse, mask, scale, bq, bk):
+    """The reference form of `head_summed_probs`' tile body: a walk over
+    the heads, each head's whole [bq, bk] tile of scores masked with NEG
+    and added to a sum kept in VMEM. Interpreted."""
+    B, H, T, d = q.shape
+    KV = k.shape[1]
+    seen = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+    return pl.pallas_call(
+        functools.partial(_per_head_kernel, scale=scale, bq=bq, bk=bk,
+                          heads=H, rep=H // KV),
+        grid=(B, T // bq, T // bk),
+        in_specs=[
+            pl.BlockSpec((1, H, bq, d), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, KV, bk, d),
+                         lambda b, i, j: (b, 0, seen(i, j), 0)),
+            pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, seen(i, j))),
+            pl.BlockSpec((1, H, bq, 1), lambda b, i, j: (b, 0, i, 0))],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, T, T), jnp.float32),
+        interpret=True)(q, k, mask, lse[..., None])
+
+
+def dense_head_sum(q, k, mask, scale):
+    """Each head's softmax over the keys `mask` keeps, added over the heads
+    in float32; 0 off the selection."""
+    R = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhtd,bhsd->bhts", q, jnp.repeat(k, R, 1),
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    p = jax.nn.softmax(jnp.where(mask[:, None] != 0, s, -jnp.inf), -1)
+    return jnp.sum(jnp.where(mask[:, None] != 0, p, 0.0), 1)
+
+
+def target_inputs(T, H, KV, gap, seed, quarters=False):
+    """`inputs`' q, k and selection (its rows past `gap` keep no key in
+    their first tiles, so whole tiles of the mask are empty), row T / 2
+    keeping key 0 alone, and lse as the forward kernel gives it. With
+    `quarters`, q and k rounded to multiples of 1/4, so that every score is
+    exact in float32 whatever order a product adds its terms in."""
+    q, k, v, mask, _ = inputs(T, H, KV, gap, seed)
+    if quarters:
+        q, k = jnp.round(q * 4) / 4, jnp.round(k * 4) / 4
+    t = T // 2
+    mask = mask.at[0, t].set((jnp.arange(T) == 0).astype(jnp.int8))
+    _, lse = dense(q, k, v, mask, D ** -0.5)
+    return q, k, lse, mask
+
+
+# (T, block_q, block_k, H, KV, the sub-tile asked for): a tile one part or
+# several, blocks no divisor of T (the largest divisor under them is taken)
+HEAD_SUM_CASES = [(64, 16, 32, 4, 2, None), (128, 32, 64, 8, 2, None),
+                  (96, 32, 16, 4, 4, None), (40, 16, 32, 2, 2, None),
+                  (512, 256, 512, 2, 1, None), (128, 64, 64, 4, 2, (8, 16)),
+                  (96, 48, 32, 4, 1, (16, 32))]
+
+
+@pytest.mark.parametrize("T,bq,bk,H,KV,sub", HEAD_SUM_CASES)
+def test_head_summed_probs_against_dense(T, bq, bk, H, KV, sub,
+                                         monkeypatch):
+    if sub:
+        monkeypatch.setattr(sa, "HEAD_SUM_SUB", sub)
+    q, k, lse, mask = target_inputs(T, H, KV, max(bq, bk), seed=T + bq)
+    got = sa.head_summed_probs(q, k, lse, mask, D ** -0.5, bq, bk,
+                               interpret=True)
+    np.testing.assert_allclose(got, dense_head_sum(q, k, mask, D ** -0.5),
+                               rtol=2e-5, atol=2e-6)
+    # one selected key: every head's probability there is 1
+    np.testing.assert_allclose(got[0, T // 2, 0], H, rtol=1e-6)
+    assert not np.any(np.asarray(got[0, T // 2, 1:]))
+
+
+@pytest.mark.parametrize("T,bq,bk,H,KV,sub", HEAD_SUM_CASES)
+def test_head_summed_probs_is_the_per_head_body_bit_for_bit(
+        T, bq, bk, H, KV, sub, monkeypatch):
+    """The heads are added in the same order and the mask changes nothing
+    a kept pair adds, so at equal blocks the sum is the same float. Two
+    things of this CPU backend are kept out by the inputs: it adds a small
+    product's terms in another order than a large one's (the scores are
+    made exact: q and k in quarters), and it contracts a product and a
+    difference with nothing between them into one fused multiply-add,
+    which the per-head body's select prevents (the scale, 16 ** -0.5 =
+    1 / 4, keeps each scaled score exact, so both round alike). The chip
+    does neither; `tools/attend_kernel_times.py` compares the two there at
+    the cell's shapes and scale."""
+    if sub:
+        monkeypatch.setattr(sa, "HEAD_SUM_SUB", sub)
+    assert D ** -0.5 == 0.25
+    q, k, lse, mask = target_inputs(T, H, KV, max(bq, bk), seed=T + bk,
+                                    quarters=True)
+    got = sa.head_summed_probs(q, k, lse, mask, 0.25, bq, bk, interpret=True)
+    want = per_head_probs(q, k, lse, mask, 0.25, *sa._blocks(T, bq, bk))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_head_summed_probs_writes_zero_off_the_selection_without_nan():
+    """An unselected pair whose score overflows exp in every head adds inf
+    to the sum, which the mask turns to 0: no NaN, no inf, and 0 on every
+    pair the selection leaves out, the upper triangle among them."""
+    T, H, KV = 64, 4, 2
+    q, k, lse, mask = target_inputs(T, H, KV, 32, seed=5)
+    t, s = 40, 3            # a query, every head the same, and a key it
+    u = q[0, 0, t]          # does not select, made to score 100 |u|^2 / 4
+    q = q.at[:, :, t].set(u)
+    k = k.at[:, :, s].set(100 * u)
+    mask = mask.at[0, t, s].set(0)
+    _, lse = dense(q, k, k, mask, D ** -0.5)
+    assert float(jnp.min(100 * (u @ u) * D ** -0.5 - lse[0, :, t])) > 100
+    got = sa.head_summed_probs(q, k, lse, mask, D ** -0.5, 16, 32,
+                               interpret=True)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert not np.any(np.asarray(got)[np.asarray(mask) == 0])
+    # rows that select key 3 score it near 400, where a float32 rounds at
+    # 3e-5: so does the difference inside exp
+    np.testing.assert_allclose(got, dense_head_sum(q, k, mask, D ** -0.5),
+                               rtol=1e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("bq,bk,sub", [(256, 512, (128, 256)),
+                                       (128, 128, (128, 128)),
+                                       (256, 384, (128, 128)),
+                                       (16, 32, (16, 32)), (40, 20, (40, 20))])
+def test_the_head_sum_sub_tile_reads_the_blocks_alone(bq, bk, sub):
+    """128 x 256 where the blocks take it; no more than a block; keys 256
+    or no more than the 128 lanes, so one head's lse column spread across
+    the lanes serves a part of any width."""
+    assert sa._sub_tile(bq, bk) == sub
+
+
 # ------------------------------------------- the index scores' backward
 def plain_index_scores(qi, ki, w):
     """`decoder.index_scores` as the parent of PR 35 had it, whose VJP XLA
@@ -485,3 +640,18 @@ def test_the_backward_kernel_of_the_lowered_step_is_under_indexer():
     assert mine and all("indexer/sparse_attention_index_bwd" in p
                         for p in mine)
     assert not [p for p in mine if "indexer/indexer" in p]
+
+
+def test_the_target_kernel_of_the_compiled_step_is_under_indexer():
+    """`train_indexer_device_ms` reads the head-summed target kernel by the
+    `indexer` scope `_row` opens around its call, in the compiled step's
+    metadata: the kernel's own `jax.jit` is lowered once, and XLA puts each
+    call site's names in front of what it inlines there."""
+    from test_keye_vl import batch_of, conf_of, mds_of
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.optimize.profiler import op_scopes
+    text = ComputationGraph(conf_of()).init().lower_step(
+        mds_of(batch_of(0))).compile().as_text()
+    mine = {p for p in op_scopes(text).values()
+            if "sparse_attention_head_sum" in p}
+    assert mine and all("indexer/jit(_head_sum)/" in p for p in mine)

@@ -26,6 +26,11 @@ as XLA makes it under `jax.checkpoint`, which is what the step ran before
 PR 35 (`index_vjp_xla`), and `index_scores_bwd`, the kernel that took its
 place, at `--blocks` key blocks (`index_bwd`; "x512,x1024", the query block
 is the chunk); `device_ms` there is ALL the device's operations of a call.
+`--kernels head_sum,head_sum_per_head` (`mask` schedule) times the indexer's
+target, `head_summed_probs`, and beside it the per-head body it replaced
+(`per_head_probs`: each head's [block_q, block_k] scores masked and added
+to a sum in VMEM), each at every `--blocks` entry; the `head_sum` line
+gives the largest difference from the per-head body at its blocks.
 
     chiprun -- python tools/attend_kernel_times.py                  # this tree
     chiprun -- python tools/attend_kernel_times.py --schedule window \\
@@ -41,6 +46,7 @@ described v5e with no chip attached and times nothing (what Mosaic refuses,
 it refuses here). A time comes from a TPU or not at all.
 """
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -54,6 +60,8 @@ sys.path.insert(0, ROOT)
 
 import jax                                                        # noqa: E402
 import jax.numpy as jnp                                           # noqa: E402
+from jax.experimental import pallas as pl                         # noqa: E402
+from jax.experimental.pallas import tpu as pltpu                  # noqa: E402
 
 # one sequence of a cell's attention, as a layer's row calls it: (T, query
 # heads, key/value heads, window) by schedule
@@ -100,6 +108,66 @@ def kernels(mod, a):
                 yield name, bq, bk, jax.jit(fn)
 
 
+def _per_head_kernel(q_ref, k_ref, mask_ref, lse_ref, out_ref, *, scale, bq,
+                     bk, heads, rep):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j * bk > i * bq + bq - 1)
+    def _skip():
+        out_ref[0] = jnp.zeros_like(out_ref[0])
+
+    @pl.when(j * bk <= i * bq + bq - 1)
+    def _step():
+        keep = mask_ref[0].astype(jnp.int32) != 0
+
+        def head(h, acc):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h // rep], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            return acc + jnp.exp(jnp.where(keep, s, -1e30) - lse_ref[0, h])
+
+        out_ref[0] = jax.lax.fori_loop(
+            0, heads, head, jnp.zeros((bq, bk), jnp.float32))
+
+
+def per_head_probs(q, k, lse, mask, scale, bq, bk):
+    """`head_summed_probs` as a loop over the heads, each head's whole
+    [bq, bk] tile of scores masked (-1e30 where the pair is not kept) and
+    added to a sum that lives in VMEM: the body the kernel's is compared
+    with, bit for bit at equal blocks."""
+    B, H, T, d = q.shape
+    KV = k.shape[1]
+    vm = {"memory_space": pltpu.VMEM}
+    seen = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+    return pl.pallas_call(
+        functools.partial(_per_head_kernel, scale=scale, bq=bq, bk=bk,
+                          heads=H, rep=H // KV),
+        grid=(B, T // bq, T // bk),
+        in_specs=[
+            pl.BlockSpec((1, H, bq, d), lambda b, i, j: (b, 0, i, 0), **vm),
+            pl.BlockSpec((1, KV, bk, d),
+                         lambda b, i, j: (b, 0, seen(i, j), 0), **vm),
+            pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, seen(i, j)),
+                         **vm),
+            pl.BlockSpec((1, H, bq, 1), lambda b, i, j: (b, 0, i, 0), **vm)],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j), **vm),
+        out_shape=jax.ShapeDtypeStruct((B, T, T), jnp.float32),
+        name="sparse_attention_head_sum_per_head",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=48 << 20))(q, k, mask, lse[..., None])
+
+
+def differences(out, ref):
+    """The largest |out - ref|, and relative to |ref| where ref is not 0,
+    and whether every element is the same float."""
+    gap = jnp.abs(out - ref)
+    return {"max_abs_diff": float(jnp.max(gap)),
+            "max_rel_diff": float(jnp.max(
+                gap / jnp.where(ref != 0, jnp.abs(ref), jnp.inf))),
+            "bit_equal": bool(jnp.all(out == ref))}
+
+
 def one_each(mod, scale, bq, bk, a):
     flat = mod._flat
     # a mask operand, or (by position) none and the window
@@ -125,7 +193,11 @@ def one_each(mod, scale, bq, bk, a):
             **by, **shared(q2, k2)),
         "dq": lambda *a: dq_of(bwd(*a)),
         "dkv": lambda *a: dkv_of(bwd(*a)),
-        "bwd": bwd}
+        "bwd": bwd,
+        "head_sum": lambda q, k, v, mask, o, lse, *_: mod.head_summed_probs(
+            q, k, lse, mask, scale, bq, bk, interpret=False),
+        "head_sum_per_head": lambda q, k, v, mask, o, lse, *_:
+            per_head_probs(q, k, lse, mask, scale, bq, bk)}
 
 
 def plain_index_scores(qi, ki, w):
@@ -309,10 +381,13 @@ def main():
                 out = fn(*operands)
             jax.block_until_ready(out)
             ms.append((time.perf_counter() - t0) / REPS * 1e3)
+        gap = differences(out, jax.jit(one_each(
+            mod, scale_of(a), bq, bk, a)["head_sum_per_head"])(*operands)
+                          ) if name == "head_sum" else {}
         say(kernel=name, bq=bq, bk=bk, ms=statistics.median(ms),
             ms_min=min(ms), ms_max=max(ms),
             **device_ms(fn, operands, whole_call=indexer or name == "bwd"),
-            device=jax.devices()[0].device_kind)
+            **gap, device=jax.devices()[0].device_kind)
     return 0
 
 
