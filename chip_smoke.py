@@ -24,9 +24,11 @@ published / seeded), and checks what comes out by the repo's own means:
   lm_flash_trainer   TransformerLM(attention="flash").fit_batch at B=8
                      T=1024: the forward and both backward Pallas kernels
                      through the normal trainer.
-  flash_kernels      every kernel in ops/flash_attention.py compiled by
-                     Mosaic (interpret=False) at D=64 and D=128 against the
-                     float32 blockwise reference.
+  flash_kernels      ops.flash_attention and the ring's hop entries
+                     (ops/sparse_attention.py: the causal diagonal and the
+                     whole rectangle), forward and backward, compiled by
+                     Mosaic (interpret=False) at D=64 and D=128 against
+                     float32 dense attention at two heads.
   multichip_wrapper  (>= 4 devices) ParallelWrapper over four chips on
   multichip_modes    ResNet-50, and __graft_entry__.run_multichip_modes on
                      the real devices.
@@ -115,7 +117,7 @@ REHEARSAL = dict(
 # would land near or above 1; so would wrong weights or a wrong mask.
 REL_L2_BF16_RESNET = 0.2      # measured 0.113 (v5e), 0.084 (XLA:CPU)
 REL_L2_BF16_LM = 0.03         # measured 0.0076-0.0095 (v5e, full width)
-# Flash kernels against the float32 blockwise oracle, inputs bf16: the
+# Attention kernels against the float32 dense oracle, inputs bf16: the
 # output is rounded to bf16 once (2^-9 relative) and the probability panel is
 # rounded to bf16 before the P.V / P^T.dO matmuls. Every entry must land
 # within 3% of the largest reference entry — the bound
@@ -485,16 +487,17 @@ def phase_lm_flash_trainer(cfg):
 
 
 # ---------------------------------------------------------------------------
-# phase 5: every flash kernel, compiled, against the float32 oracle
+# phase 5: the attention entries off the cells' path, compiled, against the
+# float32 oracle
 # ---------------------------------------------------------------------------
 def _flash_kernel_errors(shape, interpret):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from deeplearning4j_tpu.ops.flash_attention import (
-        _blockwise_attention_ckpt, flash_attention,
-        flash_attention_bwd_partial, flash_attention_partial)
+    from deeplearning4j_tpu.ops import flash_attention
+    from deeplearning4j_tpu.ops.sparse_attention import ring_hop, ring_hop_bwd
+    from deeplearning4j_tpu.parallel.ring_attention import blockwise_attention
 
     B, T, H, D = shape["B"], shape["T"], shape["H"], shape["D"]
     scale = 1.0 / D ** 0.5
@@ -504,48 +507,48 @@ def _flash_kernel_errors(shape, interpret):
     q, k, v, g = mk(), mk(), mk(), mk()       # g: the output cotangent
     f32 = lambda a: a.astype(jnp.float32)
 
-    # float32 oracle: blockwise (never holds [T, T]), "highest" matmuls
-    def oracle(q, k, v):
-        return _blockwise_attention_ckpt(q, k, v, True, scale)
-
+    # float32 oracle: dense attention with "highest" matmuls, of one head
+    # at each end of the batch x head range (one head's [T, T] scores are
+    # 256 MB at T = 8192); the kernels' o, dq, dk, dv are read at those heads
+    heads = ((0, 0), (B - 1, H - 1))
+    at = lambda a, b, h: a[b:b + 1, :, h:h + 1]
+    oracle = {}
     with jax.default_matmul_precision("highest"):
-        o_ref, vjp = jax.vjp(jax.jit(oracle), f32(q), f32(k), f32(v))
-        dq_ref, dk_ref, dv_ref = vjp(f32(g))
+        for causal in (True, False):
+            att = jax.jit(lambda q, k, v, causal=causal: blockwise_attention(
+                q, k, v, causal=causal, scale=scale))
+            for b, h in heads:
+                o, vjp = jax.vjp(att, *(f32(at(a, b, h)) for a in (q, k, v)))
+                oracle[causal, b, h] = (o, *vjp(f32(at(g, b, h))))
 
-    # forward-only kernel (`_kernel`), then forward+lse and the dQ and dK/dV
-    # kernels through jax.vjp of the public op — default blocks: 1024^2
-    # forward, 512^2 backward
+    def errs_of(name, got, causal):
+        """`got` (o, dq, dk, dv) [B, T, H, D] against the oracle's."""
+        for i, part in enumerate(("o", "dq", "dk", "dv")[:len(got)]):
+            errs[f"{name}_{part}"] = max(
+                max_err_over_max_ref(at(got[i], b, h), oracle[causal, b, h][i])
+                for b, h in heads)
+
+    # the public op, forward alone and through jax.vjp: the causal forward
+    # and the one-pass backward at the module's blocks
     flash = lambda q, k, v: flash_attention(q, k, v, True, None, 1024, 1024,
                                             interpret)
-    errs = {"fwd": max_err_over_max_ref(jax.jit(flash)(q, k, v), o_ref)}
+    errs = {}
+    errs_of("fwd", [jax.jit(flash)(q, k, v)], True)
     o, vjp = jax.vjp(jax.jit(flash), q, k, v)
-    dq, dk, dv = vjp(g)
-    errs["fwd_lse"] = max_err_over_max_ref(o, o_ref)
-    errs["dq"] = max_err_over_max_ref(dq, dq_ref)
-    errs["dk"] = max_err_over_max_ref(dk, dk_ref)
-    errs["dv"] = max_err_over_max_ref(dv, dv_ref)
+    errs_of("flash", (o, *vjp(g)), True)
 
-    # the ring-attention hop kernels on one whole-sequence chunk. Equal
-    # non-zero offsets mask exactly like zero ones, and prove the SMEM
-    # scalars are read.
+    # the ring's hop entries on one whole-sequence chunk: the diagonal (the
+    # causal schedule) and the rectangle, each (o, lse) and its backward at
+    # that o and lse, all float32
     flat = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     unflat = lambda a: a.reshape(B, H, T, D).transpose(0, 2, 1, 3)
-    off = jnp.asarray(T, jnp.int32)
-    acc, m, l = jax.jit(lambda q, k, v: flash_attention_partial(
-        q, k, v, off, off, causal=True, interpret=interpret))(
-        flat(q), flat(k), flat(v))
-    o_part = acc / l[..., None]
-    errs["partial"] = max_err_over_max_ref(unflat(o_part), o_ref)
-    lse = (m + jnp.log(l))[..., None]
-    delta = jnp.sum(f32(flat(g)) * o_part, -1, keepdims=True)
-    dqp, dkp, dvp = jax.jit(
-        lambda q, k, v, delta, do, lse: flash_attention_bwd_partial(
-            q, k, v, delta, do, lse, off, off, causal=True,
-            interpret=interpret))(flat(q), flat(k), flat(v), delta, flat(g),
-                                  lse)
-    errs["bwd_partial_dq"] = max_err_over_max_ref(unflat(dqp), dq_ref)
-    errs["bwd_partial_dk"] = max_err_over_max_ref(unflat(dkp), dk_ref)
-    errs["bwd_partial_dv"] = max_err_over_max_ref(unflat(dvp), dv_ref)
+    for name, diagonal in (("hop_diagonal", True), ("hop_rectangle", False)):
+        o, lse = jax.jit(lambda q, k, v: ring_hop(
+            q, k, v, scale, diagonal, interpret))(flat(q), flat(k), flat(v))
+        grads = jax.jit(lambda q, k, v, o, lse, do: ring_hop_bwd(
+            q, k, v, o, lse, do, scale, diagonal, interpret))(
+                flat(q), flat(k), flat(v), o, lse, flat(g))
+        errs_of(name, [unflat(a) for a in (o, *grads)], diagonal)
     return errs
 
 
@@ -560,10 +563,11 @@ def phase_flash_kernels(cfg):
             check(np.isfinite(e) and e <= FLASH_MAX_ERR_OVER_MAX_REF,
                   f"{name} {k}: max err / max ref {e:.4f} > "
                   f"{FLASH_MAX_ERR_OVER_MAX_REF}")
-    return {"checked": "forward, forward+lse, dQ, dK/dV, partial and "
-                       "backward-partial kernels vs the float32 blockwise "
-                       "oracle" + ("" if cfg["interpret"]
-                                   else ", Mosaic-compiled (interpret=False)"),
+    return {"checked": "ops.flash_attention forward and backward, the "
+                       "ring's diagonal and rectangle hops and their "
+                       "backward vs float32 dense attention at two heads"
+                       + ("" if cfg["interpret"]
+                          else ", Mosaic-compiled (interpret=False)"),
             "max_err_over_max_ref": out,
             "bound": FLASH_MAX_ERR_OVER_MAX_REF}
 

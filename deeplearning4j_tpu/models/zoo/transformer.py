@@ -84,8 +84,9 @@ def causal_attention(x, wqkv, wo, n_heads, return_kv=False):
 
 
 def flash_causal_attention(x, wqkv, wo, n_heads):
-    """causal_attention via the Pallas flash kernel (`ops/flash_attention`):
-    never materializes the [T, T] scores — the long-context fast path."""
+    """causal_attention via the Pallas kernels of `ops/sparse_attention.py`
+    (`ops.flash_attention`): never materializes the [T, T] scores — the
+    long-context fast path."""
     from ...ops import flash_attention
     B, T, D = x.shape
     H = n_heads

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _resolve_interpret
+from .backend import compiler_params
+from .backend import resolve_interpret as _resolve_interpret
 
 LANES = 128
 ROW_TILE = 256          # rows a tile (a tile two groups share is computed
@@ -228,11 +229,6 @@ def _check(*extent_and_block):
         raise ValueError(f"blocks {pairs} do not tile their extents")
 
 
-def _params(interpret, semantics):
-    return {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=KERNEL_VMEM)}
-
-
 def _gmm_call(lhs, rhs, sizes, tm, tn, transpose_rhs, out_dtype, interpret):
     rows, K = lhs.shape
     G = rhs.shape[0]
@@ -264,7 +260,8 @@ def _gmm_call(lhs, rhs, sizes, tm, tn, transpose_rhs, out_dtype, interpret):
                             + rhs.size * rhs.dtype.itemsize
                             + rows * N * jnp.dtype(out_dtype).itemsize)),
         interpret=interpret, name="grouped_matmul",
-        **_params(interpret, ("parallel", "arbitrary")))(
+        **compiler_params(interpret, ("parallel", "arbitrary"),
+                          KERNEL_VMEM))(
             *sched[:4], lhs, rhs)
 
 
@@ -292,7 +289,8 @@ def _dw_call(lhs, dy, sizes, G, tm, tk, tn, out_dtype, interpret):
                             + dy.size * dy.dtype.itemsize * (K // tk)
                             + G * K * N * jnp.dtype(out_dtype).itemsize)),
         interpret=interpret, name="grouped_matmul_dw",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
+        **compiler_params(interpret, ("parallel", "parallel", "arbitrary"),
+                          KERNEL_VMEM))(
             *sched[:4], lhs, dy)
 
 

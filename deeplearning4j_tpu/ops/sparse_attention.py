@@ -6,9 +6,8 @@ The main attention of a `sparseattention` layer
 indexer selected; the selection is one set for all heads, a bool [T, T] a
 sequence (causality included). XLA's lowering would write every head's
 [T, T] scores to HBM several times over; these kernels stream K/V blocks
-through VMEM under the online softmax, as `ops/flash_attention.py` does for
-plain causal attention, and read the mask a (block_q, block_k) tile at a
-time. Grouped queries: query head a reads key/value head a // (H / KV),
+through VMEM under the online softmax and read the mask a (block_q,
+block_k) tile at a time. Grouped queries: query head a reads key/value head a // (H / KV),
 chosen by the block index map, nothing is repeated in HBM.
 
 - `masked_attention(q, k, v, mask)` -> (o, lse): forward and, through its
@@ -55,6 +54,10 @@ windowed attention (`mask=None`): the tile's mask is then made inside the
 kernel from the block indices and an iota (key j visible to query i iff
 j <= i and, under a `window`, i - j < window), and only on the tiles that
 the diagonal or the band's far edge crosses; no [T, T] array exists.
+`flash_attention` ([B, T, H, d], `TransformerLM(attention="flash")`) and
+`ring_hop` / `ring_hop_bwd` (each hop of `parallel/ring_attention.py`'s
+kernel path) are the same kernels again; under `causal=False` the schedule
+is the whole rectangle, no tile is CROSSED and no mask is made.
 
 The mask is causal, so `masked_attention`'s kernels walk only the
 (query block, key block) tiles with a visible pair (on or under the
@@ -73,6 +76,7 @@ masked-out work, and the diagonal tiles' upper halves, as waste.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +85,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _divisor_block, _resolve_interpret
+from .backend import compiler_params, divisor_block
+from .backend import resolve_interpret as _resolve_interpret
 
 KEEP = "sparse_attention"    # checkpoint name of what a backward needs kept
 NEG = -1e30      # a masked score; finite, so a row that has met no key yet
@@ -121,11 +126,6 @@ BWD_VMEM = 64 << 20         # the one-pass backward's VMEM: at the budget the
 #                  and the 1024 x 1024 tiles need 48 MB (compiled for a
 #                  described v5e: 40 is refused; at T = 8192 32 passes),
 #                  over the scoped default of 16, under the chip's 128
-
-
-def _params(interpret, semantics, **more):
-    return {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics, **more)}
 
 
 def _scores(q_ref, k_ref, mask_ref, scale, band=None, tile=None, shared=()):
@@ -177,10 +177,11 @@ OWN_FIRST, OWN_LAST = 8, 16     # the first and last step of ONE key/value
 #                         (the whole of it where there is none)
 
 
-def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
+def tile_schedule(T, bq, bk, heads=1, window=None, own=None, causal=True):
     """The tiles that `masked_attention`'s kernels visit at query blocks of
     `bq` and key blocks of `bk`: those with a visible pair (key j <= query
-    i and, under a `window`, i - j < window), each once, and no other.
+    i and, under a `window`, i - j < window), each once, and no other; with
+    `causal` False every tile of the rectangle, none of them CROSSED.
     `grid_steps` is the length of the kernels' second grid axis a head (the
     first walks batch x head), and `computing_steps` how many of those steps
     hold such a pair: all of them. The tables are int32 numpy arrays, one
@@ -218,6 +219,11 @@ def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
     # it lies in 0 .. far - 1
     crossed = lambda i, j: CROSSED * (
         i * bq - (j * bk + bk - 1) < 0 or i * bq + bq - 1 - j * bk >= far)
+    seen = lambda i, j: (j * bk <= i * bq + bq - 1
+                         and i * bq - (j * bk + bk - 1) < far)
+    if not causal:
+        keys, queries = lambda i: range(nk), lambda j: range(nq)
+        crossed, seen = (lambda i, j: 0), (lambda i, j: True)
     ends = lambda x, run: FIRST * (x == run[0]) | LAST * (x == run[-1])
     by_query = [(i, j, ends(j, keys(i)) | crossed(i, j))
                 for i in range(nq) for j in keys(i)]
@@ -236,19 +242,16 @@ def tile_schedule(T, bq, bk, heads=1, window=None, own=None):
                 for r in range(heads) for t, (i, j, e) in enumerate(by_query)]
     cols = lambda rows: tuple(np.asarray(c, np.int32) for c in zip(*rows))
     return {"grid_steps": len(by_query),
-            "computing_steps": sum(
-                j * bk <= i * bq + bq - 1 and i * bq - (j * bk + bk - 1) < far
-                for i, j, _ in by_query),
+            "computing_steps": sum(seen(i, j) for i, j, _ in by_query),
             "by_query": cols(by_query), "by_key": cols(by_key),
             "one_pass": cols(one_pass)}
 
 
 def _call(kernel, tables, grid, in_specs, out_specs, out_shape, scratch,
-          name, interpret, operands, aliases=None, **more):
+          name, interpret, operands, aliases=None, vmem_limit_bytes=None):
     """One kernel over (batch x head, the steps of `tables`): the tables
     are prefetched scalars, read by the index maps and by the kernel;
-    `aliases` {operand: result} are written in place; `more` goes to the
-    compiler."""
+    `aliases` {operand: result} are written in place."""
     return pl.pallas_call(
         kernel, out_shape=out_shape, interpret=interpret, name=name,
         input_output_aliases={len(tables) + a: b
@@ -256,7 +259,8 @@ def _call(kernel, tables, grid, in_specs, out_specs, out_shape, scratch,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
-        **_params(interpret, ("parallel", "arbitrary"), **more))(
+        **compiler_params(interpret, ("parallel", "arbitrary"),
+                          vmem_limit_bytes))(
             *(jnp.asarray(t) for t in tables), *operands)
 
 
@@ -326,11 +330,13 @@ def _specs(bq, bk, d, R, H, dv, R2=None, d2=None):
             *((rows(d2), keys(d2, R2)) if d2 else ()))
 
 
-def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None, shared=()):
+def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None, shared=(),
+         causal=True, dtype=None):
     """q [B*H, T, d], k [B*KV, T, d], v [B*KV, T, dv], mask int8 [B, T, T]
-    or None (by position: causal, inside `window`); `shared` (q2 [B*H, T,
-    d2], k2 [B*KS, T, d2]): the slots scored against a key that several
-    key/value heads share."""
+    or None (by position: causal, inside `window`, or with `causal` False
+    every pair); `shared` (q2 [B*H, T, d2], k2 [B*KS, T, d2]): the slots
+    scored against a key that several key/value heads share. o is in
+    `dtype`, q's where None."""
     BH, T, d = q.shape
     dv = v.shape[-1]
     q_spec, k_spec, mask_spec, row_spec, v_spec, o_spec, *shared_specs = \
@@ -338,7 +344,7 @@ def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None, shared=()):
                1 if mask is None else BH // mask.shape[0], dv,
                *((BH // shared[1].shape[0], shared[1].shape[-1])
                  if shared else ()))
-    sched = tile_schedule(T, bq, bk, window=window)
+    sched = tile_schedule(T, bq, bk, window=window, causal=causal)
     band = None if mask is not None else (bq, bk, window)
     return _call(
         functools.partial(_fwd_kernel, scale=scale, band=band,
@@ -346,7 +352,7 @@ def _fwd(q, k, v, mask, scale, bq, bk, interpret, window=None, shared=()):
         sched["by_query"], (BH, sched["grid_steps"]),
         _given(mask, (q_spec, k_spec, v_spec), mask_spec, shared_specs),
         [o_spec, row_spec],
-        [jax.ShapeDtypeStruct((BH, T, dv), q.dtype),
+        [jax.ShapeDtypeStruct((BH, T, dv), dtype or q.dtype),
          jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         [pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32),
@@ -547,20 +553,21 @@ def backward_passes(T, d, dv, d2=0):
 
 
 def _bwd(q, k, v, mask, o, lse, do, scale, bq, bk, interpret, window=None,
-         shared=()):
+         shared=(), causal=True, dtype=None):
     """(dq, dk, dv) or, with `shared`, (dq, dk, dv, dq2, dk2): by one kernel
-    where `backward_passes` says 1, else by two."""
+    where `backward_passes` says 1, else by two. Each gradient is in its
+    operand's dtype, or in `dtype` where one is given."""
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
                     keepdims=True)
     d2 = shared[1].shape[-1] if shared else 0
     walk = (_one_pass if backward_passes(q.shape[1], q.shape[2], v.shape[-1],
                                          d2) == 1 else _two_pass)
     return walk(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
-                window, shared)
+                window, shared, causal, dtype)
 
 
 def _one_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
-              window, shared):
+              window, shared, causal, dtype):
     BH, T, d = q.shape
     BKV, dv = k.shape[0], v.shape[-1]
     B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
@@ -573,7 +580,7 @@ def _one_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
     # block i[t], key block j[t] (`tile_schedule`'s `one_pass`); the
     # key/value head is query head g * R2 + r[t]'s
     sched = tile_schedule(T, bq, bk, heads=R2, window=window,
-                          own=R if shared else None)
+                          own=R if shared else None, causal=causal)
     head = lambda g, r, t: g * R2 + r[t]
     of_query = lambda w: pl.BlockSpec(
         (1, bq, w), lambda g, t, r, i, j, e: (head(g, r, t), i[t], 0), **vm)
@@ -586,7 +593,7 @@ def _one_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
         (1, bk, d2), lambda g, t, r, i, j, e: (g, j[t], 0), **vm)
     shared_slab = pl.BlockSpec(
         (1, T, d2), lambda g, t, r, i, j, e: (g, 0, 0), **vm)
-    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype)
     operands = _given(mask, (q, k, v), mask, (do, lse, delta, *shared))
     return tuple(_call(
         functools.partial(_bwd_kernel, scale=scale, bk=bk,
@@ -607,14 +614,16 @@ def _one_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
             *(((bq, d2), (T, d2)) if shared else ()))],
         "sparse_attention_bwd", interpret, operands,
         # q, k, v and the shared pair, the last two operands, die with
-        # this call: their gradients are written in their places
-        aliases=dict(zip((0, 1, 2, len(operands) - 2, len(operands) - 1),
-                         range(3 + len(shared)))),
+        # this call: their gradients are written in their places (not so
+        # where `dtype` is asked: a ring's hop passes k and v on)
+        aliases=None if dtype else dict(zip(
+            (0, 1, 2, len(operands) - 2, len(operands) - 1),
+            range(3 + len(shared)))),
         vmem_limit_bytes=BWD_VMEM))
 
 
 def _two_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
-              window, shared):
+              window, shared, causal, dtype):
     BH, T, d = q.shape
     BKV, dv = k.shape[0], v.shape[-1]
     B = 1 if mask is None else mask.shape[0]     # only the mask's specs ask
@@ -627,8 +636,8 @@ def _two_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
     more = [d2] if shared else []       # the width of a third accumulator
     q_spec, k_spec, mask_spec, row_spec, v_spec, o_spec, *shared_specs = \
         _specs(bq, bk, d, R, H, dv, *((R2, d2) if shared else ()))
-    sched = tile_schedule(T, bq, bk, window=window)
-    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    sched = tile_schedule(T, bq, bk, window=window, causal=causal)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype)
     dq = _call(
         functools.partial(_dq_kernel, scale=scale, band=band,
                           shared=bool(shared)),
@@ -644,7 +653,7 @@ def _two_pass(q, k, v, mask, do, lse, delta, scale, bq, bk, interpret,
     # read g, query block i[t] (`tile_schedule`'s `by_key`); a key/value
     # head's own blocks are those of query head g * R2 + r[t]
     sched = tile_schedule(T, bq, bk, heads=R2, window=window,
-                          own=R if shared else None)
+                          own=R if shared else None, causal=causal)
     of_query = lambda w: pl.BlockSpec(
         (1, bq, w), lambda g, t, j, r, i, e: (g * R2 + r[t], i[t], 0), **vm)
     of_key = lambda w: pl.BlockSpec(
@@ -675,7 +684,7 @@ def _blocks(T, block_q, block_k, window=None):
     measured for the schedule (the causal walk, or the band under a
     window), each side cut to a divisor of T."""
     bq, bk = (BLOCK, BLOCK) if window is None else WINDOW_BLOCK
-    return _divisor_block(T, block_q or bq), _divisor_block(T, block_k or bk)
+    return divisor_block(T, block_q or bq), divisor_block(T, block_k or bk)
 
 
 def grid_steps_per_tile(T, block_q=None, block_k=None, window=None):
@@ -691,9 +700,9 @@ def _flat(a):
     return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def masked_attention(q, k, v, mask, scale, block_q=None, block_k=None,
-                     interpret=None, window=None):
+                     interpret=None, window=None, causal=True):
     """softmax over the keys `mask` keeps of q k^T * scale, times v.
     q [B, H, T, d]; k [B, KV, T, d], v [B, KV, T, dv] (H a multiple of KV;
     the summed width dv need not be the scored width d: o is dv wide and
@@ -701,18 +710,24 @@ def masked_attention(q, k, v, mask, scale, block_q=None, block_k=None,
     [B, T, T], nonzero where query t reads key s, causal (s <= t) and with
     at least one key a query; or None: query t reads the keys s <= t and,
     under a `window`, t - s < window, the mask made inside the kernels
-    from positions. A T no longer than a block is one tile. Returns
+    from positions. With `causal` False every tile is walked: query t
+    reads every key (mask None) or the keys `mask` keeps, whatever their
+    place. A T no longer than a block is one tile. Returns
     (o [B, H, T, d], lse [B, H, T] f32)."""
     return _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret,
-                       window)[0]
+                       window, causal)[0]
 
 
-def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret, window):
+def _masked_fwd(q, k, v, mask, scale, block_q, block_k, interpret, window,
+                causal):
     if mask is not None and window is not None:
         raise ValueError("a mask operand carries its own window")
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     bq, bk = _blocks(q.shape[2], block_q, block_k, window)
     out = _kept(q, v, *_fwd(_flat(q), _flat(k), _flat(v), mask, scale, bq, bk,
-                            _resolve_interpret(interpret), window))
+                            _resolve_interpret(interpret), window,
+                            causal=causal))
     return out, (q, k, v, mask, *out)
 
 
@@ -725,17 +740,52 @@ def _kept(q, v, o, lse):
             checkpoint_name(lse.reshape(q.shape[:3]), KEEP))
 
 
-def _masked_bwd(scale, block_q, block_k, interpret, window, res, g):
+def _masked_bwd(scale, block_q, block_k, interpret, window, causal, res, g):
     q, k, v, mask, o, lse = res
     B, H, T, d = q.shape
     bq, bk = _blocks(T, block_q, block_k, window)
     dq, dk, dv = _bwd(_flat(q), _flat(k), _flat(v), mask, _flat(o),
                       lse.reshape(B * H, T, 1), _flat(g[0]), scale, bq, bk,
-                      _resolve_interpret(interpret), window)
+                      _resolve_interpret(interpret), window, causal=causal)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), None
 
 
 masked_attention.defvjp(_masked_fwd, _masked_bwd)
+
+
+def flash_attention(q, k, v, causal=True, scale=None, block_q=1024,
+                    block_k=1024, interpret=None):
+    """Causal (or, `causal` False, full) attention over [B, T, H, d]: q, k
+    and v as `parallel/ring_attention.py` lays them out, scale 1/sqrt(d)
+    unless given; `masked_attention` without a mask, its heads moved to
+    the second axis and back."""
+    heads = lambda a: jnp.swapaxes(a, 1, 2)
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+    o, _ = masked_attention(heads(q), heads(k), heads(v), None, scale,
+                            block_q, block_k, interpret, None, causal)
+    return heads(o)
+
+
+def ring_hop(q, k, v, scale, causal, interpret=None):
+    """One hop of ring attention (`parallel/ring_attention.py`): a chunk's
+    queries against the keys of the chunk the ring has brought, q, k, v
+    [BH, T, d], a key/value head a query head. `causal` True for the hop of
+    the chunk itself (key j seen by query i iff j <= i); False for a chunk
+    wholly before it, whose every pair is seen. (o [BH, T, d], lse [BH, T,
+    1]), both float32: the ring folds its hops by lse and rounds once."""
+    bq, bk = _blocks(q.shape[1], None, None)
+    return _fwd(q, k, v, None, scale, bq, bk, _resolve_interpret(interpret),
+                causal=causal, dtype=jnp.float32)
+
+
+def ring_hop_bwd(q, k, v, o, lse, do, scale, causal, interpret=None):
+    """The hop's parts of dq, dk, dv, float32, at the ring's o [BH, T, d]
+    and lse [BH, T, 1]: those of all hops folded, which make each hop's
+    probabilities exact. Shapes and `causal` as `ring_hop`."""
+    bq, bk = _blocks(q.shape[1], None, None)
+    return _bwd(q, k, v, None, o, lse, do, scale, bq, bk,
+                _resolve_interpret(interpret), causal=causal,
+                dtype=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -825,8 +875,8 @@ def _sub_tile(bq, bk):
     where the block takes it, else no more than a block, and keys no more
     than the 128 lanes."""
     rows, keys = HEAD_SUM_SUB
-    return (_divisor_block(bq, rows),
-            keys if bk % keys == 0 else _divisor_block(bk, 128))
+    return (divisor_block(bq, rows),
+            keys if bk % keys == 0 else divisor_block(bk, 128))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bq", "bk", "rows",
@@ -851,8 +901,8 @@ def _head_sum(q, k, lse, mask, *, scale, bq, bk, rows, keys, interpret):
         scratch_shapes=[pltpu.VMEM((H, bq, 128), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, T, T), jnp.float32),
         interpret=interpret, name="sparse_attention_head_sum",
-        **_params(interpret, ("parallel", "parallel", "arbitrary"),
-                  vmem_limit_bytes=HEAD_SUM_VMEM))(q, k, mask, lse[..., None])
+        **compiler_params(interpret, ("parallel", "parallel", "arbitrary"),
+                          HEAD_SUM_VMEM))(q, k, mask, lse[..., None])
 
 
 def head_summed_probs(q, k, lse, mask, scale, block_q=None, block_k=None,
@@ -862,8 +912,8 @@ def head_summed_probs(q, k, lse, mask, scale, block_q=None, block_k=None,
     `masked_attention`; not differentiated (the indexer's target). Blocks
     `HEAD_SUM_BLOCK` unless asked, each side cut to a divisor of T."""
     T = q.shape[2]
-    bq = _divisor_block(T, block_q or HEAD_SUM_BLOCK[0])
-    bk = _divisor_block(T, block_k or HEAD_SUM_BLOCK[1])
+    bq = divisor_block(T, block_q or HEAD_SUM_BLOCK[0])
+    bk = divisor_block(T, block_k or HEAD_SUM_BLOCK[1])
     rows, keys = _sub_tile(bq, bk)
     return _head_sum(q, k, lse, mask, scale=scale, bq=bq, bk=bk, rows=rows,
                      keys=keys, interpret=_resolve_interpret(interpret))
@@ -887,7 +937,7 @@ def at_least_kth(scores, k, block_rows=64, interpret=None):
     r and is not -inf (a row with fewer than k entries above -inf keeps them
     all). scores float32 [R, S], -inf where a pair is not to be chosen."""
     R, S = scores.shape
-    br = _divisor_block(R, block_rows)
+    br = divisor_block(R, block_rows)
     interpret = _resolve_interpret(interpret)
     spec = pl.BlockSpec((br, S), lambda i: (i, 0), memory_space=pltpu.VMEM)
     return pl.pallas_call(
@@ -895,7 +945,7 @@ def at_least_kth(scores, k, block_rows=64, interpret=None):
         in_specs=[spec], out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((R, S), jnp.int8),
         interpret=interpret, name="sparse_attention_select",
-        **_params(interpret, ("parallel",)))(scores)
+        **compiler_params(interpret, ("parallel",)))(scores)
 
 
 # ------------------------------------------- the index scores' backward
@@ -990,7 +1040,7 @@ def index_scores_bwd(qi, ki, w, g, block_k=None, interpret=None):
                         pltpu.VMEM((bk, DI), jnp.float32),
                         pltpu.VMEM((C, HI), jnp.float32)],
         interpret=interpret, name="sparse_attention_index_bwd",
-        **_params(interpret, ("arbitrary",), vmem_limit_bytes=INDEX_VMEM))(
+        **compiler_params(interpret, ("arbitrary",), INDEX_VMEM))(
             jnp.moveaxis(qi, 1, 0), ki, w.astype(jnp.float32),
             g.astype(jnp.float32))
     return jnp.moveaxis(dqi, 0, 1), dki, dw

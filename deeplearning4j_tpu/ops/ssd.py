@@ -65,7 +65,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _resolve_interpret
+from .backend import compiler_params
+from .backend import resolve_interpret as _resolve_interpret
 
 KEEP = "ssd_states"     # checkpoint name of the states entering each chunk
 BLOCK = 32              # chunks whose inside is computed at once (None: all;
@@ -377,12 +378,6 @@ def _kernel_operands(x, dt, A, B, C, D):
             jnp.repeat(D.astype(f32), P)[:, None])
 
 
-def _params(interpret):
-    return {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=KERNEL_VMEM)}
-
-
 def _scan_fwd_call(x, dt, A, B, C, D, L, interpret):
     """(y [b, T, H, P] in x's dtype, the last state [b, H, P, N], the state
     entering each chunk [b, T / L, H, P, N]; both float32)."""
@@ -399,7 +394,8 @@ def _scan_fwd_call(x, dt, A, B, C, D, L, interpret):
                    jax.ShapeDtypeStruct((b, T // L, H * P, N), f32),
                    jax.ShapeDtypeStruct((b, H * P, N), f32)],
         scratch_shapes=[pltpu.VMEM((H // G * P, N), f32)],
-        interpret=interpret, name="ssd_fwd", **_params(interpret))(
+        interpret=interpret, name="ssd_fwd", **compiler_params(
+            interpret, ("parallel", "parallel", "arbitrary"), KERNEL_VMEM))(
             *_kernel_operands(x, dt, A, B, C, D))
     return (_across(y).reshape(b, T, H, P), last.reshape(b, H, P, N),
             enter.reshape(b, T // L, H, P, N))
@@ -434,7 +430,8 @@ def _scan_kernels_bwd(L, interpret, res, g):
                    jax.ShapeDtypeStruct((b, G * N, T), B.dtype),
                    jax.ShapeDtypeStruct((b, G * N, T), C.dtype)],
         scratch_shapes=[pltpu.VMEM((H // G * P, N), f32)],
-        interpret=interpret, name="ssd_bwd", **_params(interpret))(
+        interpret=interpret, name="ssd_bwd", **compiler_params(
+            interpret, ("parallel", "parallel", "arbitrary"), KERNEL_VMEM))(
             *operands, enter.reshape(b, T // L, H * P, N),
             _across(dy.astype(x.dtype).reshape(b, T, H * P)),
             d_last.astype(f32).reshape(b, H * P, N))

@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _resolve_interpret
+from .backend import compiler_params
+from .backend import resolve_interpret as _resolve_interpret
 
 LANES = 128
 CONV_ROWS = 256         # channels a convolution block, and
@@ -254,11 +255,6 @@ def _conv_bwd_kernel(x_ref, halo_ref, w_ref, b_ref, d_ref, dx_ref, dw_ref,
         db_ref[...] = jnp.sum(db_acc[...], 1, keepdims=True)
 
 
-def _params(interpret, semantics):
-    return {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=KERNEL_VMEM)}
-
-
 def _conv_operands(w, b):
     """w [C, K] as [K, C, 1] and b [C] as [C, 1], float32: a tap a column."""
     f32 = jnp.float32
@@ -281,7 +277,8 @@ def _conv_fwd_call(x, w, b, lo, interpret):
         out_shape=jax.ShapeDtypeStruct((n, C, T), x.dtype),
         scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.float32)],
         interpret=interpret, name="ssm_conv_fwd",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")))(
+        **compiler_params(interpret, ("parallel", "parallel", "arbitrary"),
+                          KERNEL_VMEM))(
             _across(x), *_conv_operands(w, b))
     return _across(out)
 
@@ -322,7 +319,8 @@ def _conv_kernels_bwd(lo, interpret, res, dout):
                         pltpu.VMEM((K, rows, LANES), f32),
                         pltpu.VMEM((rows, LANES), f32)],
         interpret=interpret, name="ssm_conv_bwd",
-        **_params(interpret, ("parallel", "arbitrary", "arbitrary")))(
+        **compiler_params(interpret, ("parallel", "arbitrary", "arbitrary"),
+                          KERNEL_VMEM))(
             xt, xt, *_conv_operands(w, b), _across(dout.astype(x.dtype)))
     # the channels read are [lo, lo + C) of x's; the others get nothing
     dx = jnp.pad(_across(dx), ((0, 0), (0, 0), (lo, width - lo - C)))
@@ -409,7 +407,8 @@ def _norm_fwd_call(y, z, w, groups, eps, interpret):
         grid=(n, groups, T // lanes), in_specs=[block, block, column],
         out_specs=block, out_shape=jax.ShapeDtypeStruct((n, width, T), y.dtype),
         interpret=interpret, name="ssm_norm_fwd",
-        **_params(interpret, ("parallel", "parallel", "parallel")))(
+        **compiler_params(interpret, ("parallel", "parallel", "parallel"),
+                          KERNEL_VMEM))(
             _across(y), _across(z), w.astype(jnp.float32)[:, None])
     return _across(out)
 
@@ -439,7 +438,8 @@ def _norm_kernels_bwd(groups, eps, interpret, res, dout):
                    jax.ShapeDtypeStruct((width, 1), f32)],
         scratch_shapes=[pltpu.VMEM((run, LANES), f32)],
         interpret=interpret, name="ssm_norm_bwd",
-        **_params(interpret, ("parallel", "arbitrary", "arbitrary")))(
+        **compiler_params(interpret, ("parallel", "arbitrary", "arbitrary"),
+                          KERNEL_VMEM))(
             _across(y), _across(z), w.astype(f32)[:, None],
             _across(dout.astype(y.dtype)))
     # the gate is z's first `width` channels; the others get nothing

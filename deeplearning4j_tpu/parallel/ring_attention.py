@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.sparse_attention import FLOOR, ring_hop, ring_hop_bwd
+
 NEG_INF = -1e30
 
 
@@ -51,6 +53,15 @@ def _flash_fold(o, m, l, s, v):
     return o_new, m_new, l_new
 
 
+def _hop_kind(src, my, causal):
+    """Which of a hop's three kinds the visiting chunk `src` is for chunk
+    `my`: 0 the rectangle (a chunk before, or any chunk when not causal),
+    1 the chunk itself (the causal diagonal), 2 one after (nothing seen)."""
+    if not causal:
+        return 0
+    return jnp.where(src < my, 0, jnp.where(src == my, 1, 2))
+
+
 def ring_attention_kernel(q, k, v, kv_mask, axis_name, causal=False,
                           scale=None, use_flash=False, return_lse=False):
     """Per-device ring attention body (run under shard_map).
@@ -60,60 +71,43 @@ def ring_attention_kernel(q, k, v, kv_mask, axis_name, causal=False,
     Rotates K/V around `axis_name` N times, folding each block with the
     running-softmax accumulators. Causal masking uses global chunk offsets.
 
-    use_flash: compute each hop's partial with the Pallas flash kernel
-    (`ops/flash_attention.flash_attention_partial`) instead of the einsum
-    block — the full long-context stack: sequence parallelism across
-    devices x flash attention within each device. Requires an all-ones
-    kv_mask (ring_self_attention enforces this).
+    use_flash: compute each hop with the Pallas kernels of
+    `ops/sparse_attention.py` (`ring_hop`: the whole rectangle, the causal
+    diagonal, or nothing for a chunk after this one) and fold the hops by
+    their lse in float32 — sequence parallelism across devices x the tile
+    kernels within each. Requires an all-ones kv_mask
+    (ring_self_attention enforces this).
     """
     n = lax.psum(1, axis_name)
     my = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    acc_dt = jnp.float32 if use_flash else q.dtype
-    if not use_flash:
-        q = q * scale
+    if use_flash:
+        return _kernel_ring(q, k, v, axis_name, causal, scale, return_lse)
+    q = q * scale
 
-    o0 = jnp.zeros((B, H, Tq, D), acc_dt)
-    m0 = jnp.full((B, H, Tq), NEG_INF, acc_dt)
-    l0 = jnp.zeros((B, H, Tq), acc_dt)
+    o0 = jnp.zeros((B, H, Tq, D), q.dtype)
+    m0 = jnp.full((B, H, Tq), NEG_INF, q.dtype)
+    l0 = jnp.zeros((B, H, Tq), q.dtype)
     o0, m0, l0 = _mark_varying((o0, m0, l0), axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
 
     qpos = my * Tq + jnp.arange(Tq)                    # global q positions
-    q_flat = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
 
     def body(i, carry):
         o, m, l, k_blk, v_blk, km_blk = carry
         src = (my - i) % n                             # origin chunk of k_blk
-        if use_flash:
-            from ..ops.flash_attention import flash_attention_partial
-            flat = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-            acc_b, m_b, l_b = flash_attention_partial(
-                q_flat, flat(k_blk), flat(v_blk), my * Tq, src * Tq,
-                causal=causal, scale=scale)
-            acc_b = acc_b.reshape(B, H, Tq, D)
-            m_b = m_b.reshape(B, H, Tq)
-            l_b = l_b.reshape(B, H, Tq)
-            m_new = jnp.maximum(m, m_b)
-            a_run = jnp.exp(m - m_new)
-            a_blk = jnp.exp(m_b - m_new)
-            o = o * a_run[..., None] + acc_b * a_blk[..., None]
-            l = l * a_run + l_b * a_blk
-            m = m_new
+        kpos = src * Tq + jnp.arange(Tq)
+        if causal:
+            bias = jnp.where(qpos[:, None] >= kpos[None, :], 0.0, NEG_INF)
         else:
-            kpos = src * Tq + jnp.arange(Tq)
-            if causal:
-                bias = jnp.where(qpos[:, None] >= kpos[None, :], 0.0,
-                                 NEG_INF)
-            else:
-                bias = jnp.zeros((Tq, Tq))
-            s = _attend_block(q, k_blk, v_blk, bias.astype(q.dtype))
-            # invalid keys: -inf for every query, per batch element
-            s = s + jnp.where(km_blk > 0, 0.0,
-                              NEG_INF)[:, None, None, :].astype(q.dtype)
-            o, m, l = _flash_fold(o, m, l, s, v_blk)
+            bias = jnp.zeros((Tq, Tq))
+        s = _attend_block(q, k_blk, v_blk, bias.astype(q.dtype))
+        # invalid keys: -inf for every query, per batch element
+        s = s + jnp.where(km_blk > 0, 0.0,
+                          NEG_INF)[:, None, None, :].astype(q.dtype)
+        o, m, l = _flash_fold(o, m, l, s, v_blk)
         k_blk = lax.ppermute(k_blk, axis_name, perm)
         v_blk = lax.ppermute(v_blk, axis_name, perm)
         km_blk = lax.ppermute(km_blk, axis_name, perm)
@@ -123,20 +117,56 @@ def ring_attention_kernel(q, k, v, kv_mask, axis_name, causal=False,
     out = o / jnp.maximum(l, 1e-30)[..., None]         # [B,H,Tq,D]
     out_t = jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)  # [B,Tq,H,D]
     if return_lse:
-        # GLOBAL per-row logsumexp (all hops folded) — the only extra
-        # residual the fused ring backward needs
+        # GLOBAL per-row logsumexp (all hops folded)
         lse = m + jnp.log(jnp.maximum(l, 1e-30))       # [B,H,Tq]
         return out_t, jnp.transpose(lse, (0, 2, 1)).astype(jnp.float32)
     return out_t
+
+
+def _kernel_ring(q, k, v, axis_name, causal, scale, return_lse):
+    """`ring_attention_kernel`'s `use_flash` path: each hop's (o, lse) from
+    `ring_hop`, or zeros and FLOOR for a chunk no query sees, folded by lse
+    in float32. `return_lse` adds the GLOBAL per-row logsumexp (all hops
+    folded) [B, Tq, H] — the only extra residual the fused ring backward
+    needs."""
+    n = lax.psum(1, axis_name)
+    my = lax.axis_index(axis_name)
+    B, Tq, H, D = q.shape
+    perm = [(j, (j + 1) % n) for j in range(n)]
+    flat = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
+    q_flat = flat(q)
+    hop = lambda diagonal: lambda k_blk, v_blk: ring_hop(
+        q_flat, k_blk, v_blk, scale, diagonal)
+    skip = lambda k_blk, v_blk: (jnp.zeros((B * H, Tq, D), jnp.float32),
+                                 jnp.full((B * H, Tq, 1), FLOOR, jnp.float32))
+    hops = (hop(False), hop(True), skip) if causal else (hop(False),)
+
+    def body(i, carry):
+        o, lse, k_blk, v_blk = carry
+        o_b, lse_b = lax.switch(_hop_kind((my - i) % n, my, causal), hops,
+                                k_blk, v_blk)
+        new = jnp.logaddexp(lse, lse_b)
+        o = o * jnp.exp(lse - new) + o_b * jnp.exp(lse_b - new)
+        k_blk = lax.ppermute(k_blk, axis_name, perm)
+        v_blk = lax.ppermute(v_blk, axis_name, perm)
+        return o, new, k_blk, v_blk
+
+    o0, lse0 = _mark_varying(skip(None, None), axis_name)
+    o, lse, _, _ = lax.fori_loop(0, n, body, (o0, lse0, flat(k), flat(v)))
+    out = o.reshape(B, H, Tq, D).transpose(0, 2, 1, 3).astype(q.dtype)
+    if return_lse:
+        return out, lse.reshape(B, H, Tq).transpose(0, 2, 1)
+    return out
 
 
 def ring_attention_bwd_kernel(q, k, v, o, lse, do, axis_name, causal=False,
                               scale=None):
     """Per-device FUSED ring backward (run under shard_map): the reverse
     of the forward rotation, every hop's contribution computed by the
-    Pallas backward grid passes (`flash_attention_bwd_partial`).
+    Pallas backward of `ops/sparse_attention.py` (`ring_hop_bwd`, of the
+    same three kinds as the forward's hops).
 
-    Per hop, the device holds its own (q, o, lse, do, delta) and the
+    Per hop, the device holds its own (q, o, lse, do) and the
     visiting (k, v) block: the dQ contribution accumulates locally; the
     dK/dV partials accumulate into buffers that ROTATE WITH the block, so
     after n hops each block's gradient arrives back at its home device
@@ -148,7 +178,6 @@ def ring_attention_bwd_kernel(q, k, v, o, lse, do, axis_name, causal=False,
 
     q,k,v,o,do: [B, Tq, H, D] local chunks; lse: [B, Tq, H] f32 (from
     the forward's return_lse). Returns (dq, dk, dv) local chunks."""
-    from ..ops.flash_attention import flash_attention_bwd_partial
     n = lax.psum(1, axis_name)
     my = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
@@ -157,22 +186,22 @@ def ring_attention_bwd_kernel(q, k, v, o, lse, do, axis_name, causal=False,
     flat = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
     qf, kf, vf, of, dof = flat(q), flat(k), flat(v), flat(o), flat(do)
     lse_f = lse.transpose(0, 2, 1).reshape(B * H, Tq, 1)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    -1, keepdims=True)
     perm = [(j, (j + 1) % n) for j in range(n)]
 
     z = jnp.zeros((B * H, Tq, D), jnp.float32)
+    hop = lambda diagonal: lambda k_blk, v_blk: ring_hop_bwd(
+        qf, k_blk, v_blk, of, lse_f, dof, scale, diagonal)
+    hops = ((hop(False), hop(True), lambda k_blk, v_blk: (z, z, z))
+            if causal else (hop(False),))
     dq0, zk, zv = _mark_varying((z, z, z), axis_name)
 
     def body(i, carry):
         dq, dk_rot, dv_rot, k_blk, v_blk = carry
-        src = (my - i) % n
-        dq_p, dk_p, dv_p = flash_attention_bwd_partial(
-            qf, k_blk, v_blk, delta, dof, lse_f, my * Tq, src * Tq,
-            causal=causal, scale=scale)
-        # partials arrive f32 by flash_attention_bwd_partial's out_dtype
-        # contract — bf16 inputs are rounded ONCE after the ring, never
-        # per hop (the accumulators below stay f32 end to end)
+        dq_p, dk_p, dv_p = lax.switch(_hop_kind((my - i) % n, my, causal),
+                                      hops, k_blk, v_blk)
+        # partials arrive f32 by ring_hop_bwd's contract — bf16 inputs are
+        # rounded ONCE after the ring, never per hop (the accumulators
+        # below stay f32 end to end)
         assert dq_p.dtype == dk_p.dtype == dv_p.dtype == jnp.float32
         dq = dq + dq_p
         dk_rot = dk_rot + dk_p
@@ -216,8 +245,8 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=False,
 
     q,k,v: GLOBAL [B,T,H,D] arrays (or already sharded); T must divide by
     the axis size. kv_mask: [B,T] key validity. Returns global [B,T,H,D].
-    use_flash: per-hop compute via the Pallas flash kernel (kv_mask not
-    supported on that path)."""
+    use_flash: per-hop compute via the Pallas kernels of
+    `ops/sparse_attention.py` (kv_mask not supported on that path)."""
     from jax.sharding import PartitionSpec as P
 
     if use_flash and kv_mask is not None:
@@ -248,9 +277,9 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=False,
 
     # Fused ring backward: the forward additionally saves the global
     # per-row logsumexp; the backward is its own reverse ring with the
-    # Pallas dQ/dK+dV grid passes per hop and dK/dV partials rotating
+    # Pallas backward of each hop and dK/dV partials rotating
     # home with their blocks (`ring_attention_bwd_kernel`) — long-context
-    # TRAINING keeps the flash memory/compute profile across devices
+    # TRAINING keeps the kernels' memory/compute profile across devices
     # (the r3 design recomputed the backward through the einsum ring,
     # materializing per-hop [T/n, T/n] score panels).
     @jax.custom_vjp

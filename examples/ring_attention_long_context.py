@@ -1,6 +1,8 @@
 """Long-context attention: the sequence axis sharded over the mesh (ring
 attention — K/V blocks rotate over ICI while an online softmax folds each
-block), with the Pallas flash kernel as each device's block compute.
+block), with the Pallas kernels of ops/sparse_attention.py as each
+device's block compute (a hop is the causal diagonal, the whole rectangle
+or nothing).
 
 No reference equivalent (the 2016 stack predates attention; its only
 long-sequence tool is truncated BPTT) — TPU-first extension.
@@ -27,8 +29,8 @@ full = blockwise_attention(q, q, q, causal=True)
 print("ring == full:", bool(jnp.allclose(ring, full, atol=1e-4)),
       " ring+flash == full:", bool(jnp.allclose(flash, full, atol=1e-4)))
 
-# TRAINING on the flash path: gradients come from the fused ring backward
-# (a reverse ring over the Pallas dQ/dK+dV passes — no score panel is
+# TRAINING on the kernel path: gradients come from the fused ring backward
+# (a reverse ring over each hop's Pallas backward — no score panel is
 # ever materialized, forward or backward)
 g_flash = jax.grad(lambda q: jnp.mean(ring_self_attention(
     q, q, q, mesh, axis="seq", causal=True, use_flash=True) ** 2))(q)

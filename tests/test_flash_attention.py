@@ -1,10 +1,10 @@
-"""Pallas flash-attention kernel tests (interpreter mode on the CPU mesh;
-the same kernel compiles via Mosaic on TPU — PERF.md records the on-chip
-numbers).
+"""`ops.flash_attention`: the tile kernels of ops/sparse_attention.py over
+[B, T, H, D] (interpreter mode on the CPU mesh; the same kernels compile via
+Mosaic on TPU).
 
 Pinned against `blockwise_attention` (the ring-attention single-device
-reference): forward exact in f32, causal masking, block-size obliviousness,
-and the FUSED Pallas backward (dQ / dK+dV kernels) == autodiff of the
+reference): forward exact in f32, causal masking and the whole rectangle,
+block-size obliviousness, and the FUSED Pallas backward == autodiff of the
 reference — both masks, any divisor tiling, uneven T, bf16 inputs.
 """
 import jax

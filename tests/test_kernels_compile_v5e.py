@@ -269,3 +269,28 @@ def test_a_step_builds_each_grouped_kernel_once(one_chip, as_on_the_chip,
     assert gm.inside_kernel(1024, 128, 256, 4) == 1.0
     assert kernels(1) == 6
     assert kernels(2) == kernels(1)
+
+
+def test_a_ring_hops_rectangle_compiles_at_a_hop_of_8192(one_chip,
+                                                          as_on_the_chip):
+    """A ring's hop of a chunk before its own at T_local = 8,192 and
+    128-wide heads, bfloat16 in: the whole rectangle's forward and its
+    one-pass backward, float32 out, fit at the module's blocks, one kernel
+    each."""
+    T, BH, d = 8192, 8, 128
+    assert sa.backward_passes(T, d, d) == 1
+    shape = lambda: jax.ShapeDtypeStruct((BH, T, d), jnp.bfloat16,
+                                         sharding=one_chip)
+
+    def hop(q, k, v, do):
+        o, lse = sa.ring_hop(q, k, v, d ** -0.5, False, interpret=False)
+        return o, lse, sa.ring_hop_bwd(q, k, v, o, lse, do, d ** -0.5,
+                                       False, interpret=False)
+
+    operands = [shape() for _ in range(4)]
+    assert {a.dtype for a in jax.tree.leaves(jax.eval_shape(
+        hop, *operands))} == {jnp.dtype(jnp.float32)}
+    text = jax.jit(hop).lower(*operands).compile().as_text()
+    assert "sparse_attention_fwd" in text and "sparse_attention_bwd" in text
+    assert "sparse_attention_dq" not in text
+    assert "sparse_attention_dkv" not in text

@@ -147,9 +147,9 @@ class TestSelfAttentionLayer:
 
 
 class TestRingFlashPath:
-    """use_flash=True: per-hop compute via the Pallas partial kernel
-    (interpreter on CPU, Mosaic on TPU) — the full long-context stack
-    (sequence parallelism x flash attention)."""
+    """use_flash=True: per-hop compute via the Pallas kernels of
+    ops/sparse_attention.py (interpreter on CPU, Mosaic on TPU) — the full
+    long-context stack (sequence parallelism x tile kernels)."""
 
     @pytest.mark.slow
     @pytest.mark.parametrize("causal", [False, True])
@@ -170,6 +170,27 @@ class TestRingFlashPath:
         ring = ring_self_attention(q, k, v, mesh, axis="seq", causal=True,
                                    use_flash=True)
         assert np.allclose(np.asarray(full), np.asarray(ring), atol=1e-5)
+
+    def test_two_device_causal_ring_of_tiles(self, monkeypatch):
+        """Two devices, causal, chunks of four 8-row blocks: hop 0 of each
+        device is the diagonal's schedule, device 1's second hop the whole
+        rectangle, device 0's the hop skipped; forward and gradients as the
+        single-device reference's."""
+        from deeplearning4j_tpu.ops import sparse_attention as sa
+        monkeypatch.setattr(sa, "BLOCK", 8)
+        q, k, v = _qkv(B=1, T=64, seed=12)
+        mesh = _seq_mesh(2)
+        ring = lambda q, k, v: ring_self_attention(
+            q, k, v, mesh, axis="seq", causal=True, use_flash=True)
+        full = lambda q, k, v: blockwise_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(ring(q, k, v)),
+                                   np.asarray(full(q, k, v)), atol=1e-5)
+        loss = lambda f: lambda q, k, v: jnp.mean(f(q, k, v) ** 2)
+        gf = jax.grad(loss(ring), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(full), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
 
     def test_kv_mask_rejected(self):
         q, k, v = _qkv(seed=5)

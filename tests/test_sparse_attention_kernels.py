@@ -1,7 +1,8 @@
 """The masked-attention kernels of ops/sparse_attention.py on their own
 (tests/test_keye_vl.py has them inside the layer only): forward and backward
 against a dense float32 reference under a random causal selection, tiled
-against one tile, and the tile schedule the kernels build their grid from;
+against one tile, and the tile schedule the kernels build their grid from,
+causal and (`causal=False`) the whole rectangle;
 the index scores' hand-written backward (`index_scores_bwd`, PR 35) against
 `jax.vjp` of the expression it replaced, alone and through the layer.
 The one-pass backward (PR 37) against the two kernels it took the place of,
@@ -26,7 +27,8 @@ sys.path.insert(0, ROOT)
 from deeplearning4j_tpu.nn.conf.layers import decoder             # noqa: E402
 from deeplearning4j_tpu.ops import sparse_attention as sa         # noqa: E402
 from deeplearning4j_tpu.ops.sparse_attention import (             # noqa: E402
-    FIRST, LAST, OWN_FIRST, OWN_LAST, backward_passes, grid_steps_per_tile,
+    CROSSED, FIRST, LAST, OWN_FIRST, OWN_LAST, backward_passes,
+    grid_steps_per_tile,
     index_scores_bwd, masked_attention, shared_key_attention, tile_schedule)
 
 D = 16
@@ -92,6 +94,23 @@ def test_kernels_against_dense(T, bq, bk, H, KV):
                                    err_msg=name + " tiled against one tile")
 
 
+def test_the_rectangle_against_dense():
+    """`causal=False` without a mask: every query reads every key, forward
+    and gradients as dense attention's, at a T that is no whole number of
+    the blocks asked for (the largest divisors under them, 10 x 20, are
+    taken)."""
+    T, H, KV = 40, 4, 2
+    q, k, v, _, do = inputs(T, H, KV, gap=T, seed=5)
+    scale = D ** -0.5
+    every = jnp.ones((1, T, T), jnp.int8)
+    want = results(lambda *a: dense(*a, scale), q, k, v, every, do)
+    got = results(lambda q, k, v, _: masked_attention(
+        q, k, v, None, scale, 16, 32, causal=False), q, k, v, every, do)
+    for name, w, g in zip(("o", "lse", "dq", "dk", "dv"), want, got):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
 def test_a_sequence_under_the_default_block_is_one_tile():
     q, k, v, mask, do = inputs(64, 4, 2, gap=16)
     scale = 0.25
@@ -113,30 +132,38 @@ def closed_form(T, bq, bk):
     return sum(i // 2 + 1 for i in range(nq))
 
 
-@pytest.mark.parametrize("T,bq,bk", [(8192, 512, 512), (8192, 1024, 512),
-                                     (8192, 512, 1024), (256, 32, 32),
-                                     (256, 64, 32), (256, 32, 64),
-                                     (64, 64, 64)])
+SCHEDULES = [(8192, 512, 512), (8192, 1024, 512), (8192, 512, 1024),
+             (256, 32, 32), (256, 64, 32), (256, 32, 64), (64, 64, 64)]
+
+
+# each geometry causal and (`causal=False`, `-rectangle`) the whole rectangle
+@pytest.mark.parametrize("T,bq,bk,causal", [
+    pytest.param(*c, causal, id="-".join(map(str, c))
+                 + ("" if causal else "-rectangle"))
+    for causal in (True, False) for c in SCHEDULES])
 @pytest.mark.parametrize("heads", [1, 3])
-def test_tile_schedule(T, bq, bk, heads):
-    sched = tile_schedule(T, bq, bk, heads=heads)
-    causal = {(i, j) for i in range(T // bq) for j in range(T // bk)
-              if j * bk <= i * bq + bq - 1}
-    n = closed_form(T, bq, bk)
-    assert len(causal) == n
+def test_tile_schedule(T, bq, bk, causal, heads):
+    sched = tile_schedule(T, bq, bk, heads=heads, causal=causal)
+    tiles = {(i, j) for i in range(T // bq) for j in range(T // bk)
+             if j * bk <= i * bq + bq - 1 or not causal}
+    n = closed_form(T, bq, bk) if causal else (T // bq) * (T // bk)
+    assert len(tiles) == n
     assert sched["grid_steps"] == sched["computing_steps"] == n
 
     i, j, edge = sched["by_query"]
     assert all(a.dtype == np.int32 for a in (i, j, edge))
-    assert sorted(zip(i.tolist(), j.tolist())) == sorted(causal)  # each once
+    assert sorted(zip(i.tolist(), j.tolist())) == sorted(tiles)  # each once
     runs(list(i), edge)
 
     j, r, i, edge = sched["by_key"]
     assert len(j) == heads * n
     for h in range(heads):
         assert sorted(zip(i[r == h].tolist(), j[r == h].tolist())) == sorted(
-            causal)
+            tiles)
     runs(list(j), edge)
+    if not causal:      # no tile of the rectangle masks a pair
+        assert not any((sched[table][-1] & CROSSED).any()
+                       for table in ("by_query", "by_key", "one_pass"))
 
 
 def runs(owner, edge):
